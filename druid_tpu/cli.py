@@ -115,12 +115,19 @@ def cmd_server(args) -> int:
 # ---------------------------------------------------------------------------
 
 def build_historical(name: str, segments_dir=None, port: int = 8083,
-                     tier: str = "_default_tier"):
+                     tier: str = "_default_tier", mesh=None):
     """DataNode + its HTTP query endpoint; optionally preload every
-    persisted segment under segments_dir."""
+    persisted segment under segments_dir. `mesh` (a jax.sharding.Mesh,
+    parallel.make_mesh()) makes the node ONE server over several chips:
+    each aggregate runs as one sharded program over all its segments.
+    Such a node carries no per-segment result cache — the sharded program
+    merges on the device and has no per-segment partials to cache, and
+    with the cache on the node would run every segment alone on the mesh;
+    the broker's result cache still fronts it."""
     import os
     from druid_tpu.cluster import DataNode, DataNodeServer, LruCache
-    node = DataNode(name, tier=tier, cache=LruCache())
+    node = DataNode(name, tier=tier, mesh=mesh,
+                    cache=None if mesh is not None else LruCache())
     loaded = 0
     if segments_dir and os.path.isdir(segments_dir):
         from druid_tpu.storage.format import load_segment
@@ -142,8 +149,12 @@ def build_historical(name: str, segments_dir=None, port: int = 8083,
 
 
 def cmd_historical(args) -> int:
+    mesh = None
+    if args.mesh:
+        from druid_tpu.parallel import make_mesh
+        mesh = make_mesh()
     node, server, loaded = build_historical(
-        args.name, args.segments_dir, args.port, args.tier)
+        args.name, args.segments_dir, args.port, args.tier, mesh=mesh)
     print(f"historical [{args.name}] listening on :{server.port} "
           f"({loaded} segments preloaded)", flush=True)
     try:
@@ -439,6 +450,9 @@ def main(argv=None) -> int:
     s.add_argument("--tier", default="_default_tier")
     s.add_argument("--segments-dir", default=None,
                    help="preload persisted segments from this directory")
+    s.add_argument("--mesh", action="store_true",
+                   help="serve as one node over ALL local chips: segments "
+                        "shard over a device mesh, one program per query")
     s.set_defaults(fn=cmd_historical)
 
     s = sub.add_parser("broker", help="run the scatter-gather broker")

@@ -18,6 +18,7 @@ wire — shapes and dtypes are all plain host arrays by construction.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import random
 import threading
@@ -172,6 +173,23 @@ class DataNode:
         is uninterruptible once launched."""
         if not self.alive:
             raise ConnectionError(f"server [{self.name}] is down")
+        with self._mesh_scope():
+            return self._run_partials(query, segment_ids, check)
+
+    def _mesh_scope(self):
+        """The node's mesh as the engine's active mesh on THIS thread. The
+        active mesh is thread-local and request threads start with none,
+        so without the scope a node built with a mesh serves every
+        aggregate per segment on its first device and the mesh is never
+        used."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        from druid_tpu.parallel import use_mesh
+        return use_mesh(self.mesh)
+
+    def _run_partials(self, query: Query, segment_ids: Sequence[str],
+                      check: Optional[Callable[[], None]]
+                      ) -> Tuple[AggregatePartials, Set[str]]:
         segs, served = self._select(segment_ids)
         use_cache = self._segment_cache_active(query)
         if not use_cache:

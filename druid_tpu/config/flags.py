@@ -61,10 +61,6 @@ FLAGS = {
         default="1", semantics="latch",
         doc="Cascaded-encoding execution opt-out; 0 decodes to flat "
             "codes at staging time (data/cascade.py)."),
-    "DRUID_TPU_COMPILE_CACHE": Flag(
-        default="", semantics="latch",
-        doc="XLA persistent compilation cache: 0 disables, a path "
-            "overrides the default directory (engine/__init__.py)."),
     "DRUID_TPU_DEVICE_BITMAP": Flag(
         default="1", semantics="latch",
         doc="Device-side filter bitmap construction opt-out "

@@ -11,42 +11,17 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-# persistent XLA compilation cache: repeated-shape queries skip the 20-40s
-# cold compile across PROCESSES (the reference's warm JVM + code cache have
-# no cold-start; this is our equivalent). Opt out with
-# DRUID_TPU_COMPILE_CACHE=0; override the directory by setting it to a path.
-def _host_fingerprint() -> str:
-    """CPU-feature fingerprint: a shared home directory must not feed one
-    machine AOT executables compiled for another's instruction set (XLA
-    loads mismatched CPU AOT results with only a warning — SIGILL risk)."""
-    import hashlib
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                # x86 lists ISA extensions under "flags", aarch64 under
-                # "Features" — either distinguishes incompatible hosts
-                if line.startswith(("flags", "Features")):
-                    return hashlib.sha1(line.encode()).hexdigest()[:12]
-    except OSError:
-        pass
-    import platform
-    ident = f"{platform.machine()}-{platform.processor()}"
-    return hashlib.sha1(ident.encode()).hexdigest()[:12]
-
-
-_cc = os.environ.get("DRUID_TPU_COMPILE_CACHE", "")
-if _cc != "0":
-    cache_dir = _cc if _cc not in ("", "1") else os.path.expanduser(
-        f"~/.cache/druid_tpu/xla-{_host_fingerprint()}")
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # cache is an optimization, never a failure
-        import logging
-        logging.getLogger(__name__).debug(
-            "persistent XLA compile cache unavailable at %s", cache_dir,
-            exc_info=True)
+# persistent XLA compilation cache: repeated-shape queries skip the cold
+# compile across PROCESSES (the reference's warm JVM + code cache have no
+# cold start; this is our equivalent). Placement belongs to the deployment:
+# JAX itself reads JAX_COMPILATION_CACHE_DIR, and when that is set no
+# directory is set here. Otherwise ONE fixed, git-ignored directory inside
+# the checkout — a directory that moves with the machine never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
 
 from druid_tpu.engine.executor import QueryExecutor  # noqa: E402
 

@@ -513,8 +513,8 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
             _JIT_CACHE.move_to_end(sig)
 
     from druid_tpu.obs import dispatch as dispatch_mod
-    with trace_span("engine/batch/dispatch", segments=K, rows=R,
-                    compile=compiled), \
+    with trace_span("engine/batch/dispatch", strategy=strategy, segments=K,
+                    rows=R, compile=compiled), \
             trace_span_when(compiled, "engine/compile", kind="batched",
                             strategy=strategy):
         outs = fn(tuple(arrs_per_slot), time0s, iv_rel,

@@ -42,12 +42,28 @@ MAX_PALLAS_FIELDS = 8
 #: (the int32 lo/hi limb pair) across MAX_PALLAS_FIELDS ops.
 MAX_PALLAS_SLOTS = 1 + 2 * MAX_PALLAS_FIELDS
 
-#: per-core VMEM (v4/v5e/v5p class chips) and the budget the declared tiles
-#: must fit in. The budget is deliberately below the physical size: pallas
-#: double-buffers input tiles and Mosaic needs scratch headroom.
+#: Mosaic's DEFAULT scoped-VMEM limit and the budget the declared tiles
+#: (counted once each) must fit in. The pipeline double-buffers every
+#: blocked operand, the resident output grids included, so at the group ×
+#: slot caps the real need passes the default limit: the pallas_call states
+#: its own limit (engine/pallas_agg.py grouped_reduce) as twice the declared
+#: tiles plus VMEM_SCRATCH_BYTES.
 #: Override per-repo via [tool.druidlint] vmem-cap-bytes.
 VMEM_BYTES = 16 * 1024 * 1024
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+#: headroom on top of the double-buffered tiles for Mosaic's internal
+#: scratch: the kernel's [R, 128, 128] one-hot temporaries (1 MiB each at
+#: R = 16) do not fit the vector registers and spill there.
+VMEM_SCRATCH_BYTES = 16 * 1024 * 1024
+
+#: rows of a word tile (bit-packed value words, megakernel mask words) as
+#: the kernel's BlockSpecs declare it: Mosaic refuses a block whose
+#: second-minor dim is neither a multiple of the sublane count nor the
+#: array's own extent, so sub-sublane word slices (R // vpw ∈ {1, 2, 4}
+#: rows, the mask's single row) ride in whole tiles and the kernel picks
+#: its rows out of the resident tile.
+WORD_TILE_ROWS = SUBLANE
 
 #: widest element the pallas kernel ever tiles: ops accept int32/float32
 #: only (pallas_op eligibility) and pallas-accum-dtype bans 64-bit inside
@@ -126,10 +142,14 @@ MEGA_MASK_VPW = PACK_WORD_BITS // MEGA_MASK_WIDTH
 #: (rows/4096, 128) tiles; every pallas block (BLK ∈ {1024, 2048} rows,
 #: R = BLK/128 ∈ {8, 16} tile rows) then sits inside ONE word row because
 #: MEGA_MASK_VPW % R == 0 — the in-kernel unpack is a pure sub-lane shift
-#: at bit base (block % (MEGA_MASK_VPW / R)) · R, no gather, (1, 128) of
-#: word VMEM per block instead of an (R, 128) int32 row mask (the 32x mask
-#: VMEM cut).
+#: at bit base (block % (MEGA_MASK_VPW / R)) · R, no gather, one word row
+#: per block instead of an (R, 128) int32 row mask (the 32x mask VMEM cut).
 MEGA_MASK_ROW_ALIGN = MEGA_MASK_VPW * LANE
+
+#: rows covered by one WHOLE (WORD_TILE_ROWS, 128) mask-word tile: staged
+#: mask/leaf word arrays pad to a multiple of this, so the kernel's mask
+#: operand is always whole tiles.
+MEGA_MASK_TILE_ALIGN = WORD_TILE_ROWS * MEGA_MASK_ROW_ALIGN
 
 # ---- device filter bitmaps (engine/filters.py device-bitmap algebra) ------
 
@@ -253,7 +273,10 @@ SYMBOL_BOUNDS = {
     "vpw": (2, 8, 2),
     "Rw": (1, 8, 1),
     "len(dense_fields)": (0, MAX_PALLAS_FIELDS, 1),
+    "n_dense": (0, MAX_PALLAS_FIELDS, 1),
     "len(packed_rws)": (0, MAX_PALLAS_FIELDS, 1),
+    # the megakernel's mask-word operand: present (1) or absent (0)
+    "n_mask": (0, 1, 1),
     # device filter-bitmap words (engine/filters.py): word rows per block,
     # bounded by FILTER_WORDS_PER_BLOCK — covers the bitmap words' worst-
     # case tile should a kernel ever stream them in.

@@ -52,7 +52,7 @@ from druid_tpu.data import cascade as cascade_mod
 from druid_tpu.data import packed as packed_mod
 from druid_tpu.data.segment import DeviceBlock, Segment
 from druid_tpu.engine import filters as filters_mod
-from druid_tpu.engine import megakernel
+from druid_tpu.engine import megakernel, pallas_agg
 from druid_tpu.engine.filters import (ConstNode, FilterNode, plan_filter,
                                       simplify_node)
 from druid_tpu.obs import dispatch as dispatch_mod
@@ -436,7 +436,6 @@ def fuse_filter_update(arrays: Dict, mask, key, it,
         return mm_reduce(arrays, mask, key, kernels, plans, num_total)
 
     if strategy == "pallas":
-        from druid_tpu.engine import pallas_agg
         return pallas_agg.pallas_reduce(arrays, mask, key, kernels,
                                         num_total, window,
                                         packed_cols=packed_cols)
@@ -671,10 +670,9 @@ def _projection_strategy(proj: Projection, kernels: Sequence[AggKernel],
                          col_dtypes: Dict, num_total: int) -> Tuple[str, int]:
     """Inner reduction over the sorted compacted layout: the fused pallas
     kernel on TPU, the XLA windowed path elsewhere, scatter as last resort."""
-    from druid_tpu.engine import pallas_agg
     span = proj.max_span
     if pallas_agg.usable(kernels, col_dtypes, span, num_total):
-        return "pallas", span
+        return "pallas", pallas_agg.canonical_span(span)
     for w in WINDOW_CHOICES:
         if span <= w:
             return "windowed", w
@@ -1326,12 +1324,15 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                         int(getattr(a, "nbytes", 0))
                         for a in carried) if donated else 0
                     try:
+                        _build_kernel_program(fn, arrays, aux,
+                                              tuple(carried))
                         counts, states, raw = fn(arrays, aux,
                                                  tuple(carried))
                     except BaseException:
-                        # the take popped ownership; a dispatch failure
-                        # (Mosaic compile error below) may have already
-                        # invalidated the donated buffers mid-flight, so
+                        # the take popped ownership; a failed dispatch may
+                        # have already invalidated the donated buffers
+                        # mid-flight (and a failed build latches the
+                        # program off, so its carries are dead), so
                         # discharge them explicitly — the pool's resident
                         # bytes stay truthful and the next tick rebuilds
                         # fresh zeros (donorguard take-without-repark)
@@ -1344,26 +1345,32 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                 elif spec.strategy == "megakernel":
                     # no donation support: parking grids in the budgeted
                     # pool would only evict useful entries — run carryless
+                    _build_kernel_program(fn, arrays, aux, ())
                     counts, states, _raw = fn(arrays, aux, ())
+                elif spec.strategy == "pallas":
+                    _build_kernel_program(fn, arrays, aux)
+                    counts, states = fn(arrays, aux)
                 else:
                     counts, states = fn(arrays, aux)
             # count the SUCCESSFUL program only (a Mosaic-failure retry
             # must not double-bill the query's dispatch scoreboard)
             dispatch_mod.record("segment")
             break
-        except Exception as e:
-            if spec.strategy not in ("pallas", "megakernel"):
-                raise
-            # Mosaic compile failure: latch pallas off for the process and
-            # retry on the XLA windowed/mixed path — a kernel bug must not
-            # fail user queries (reference queries never depend on which
-            # engine strategy runs). A megakernel tree keeps working: its
-            # mega nodes expand to row masks in XLA (MegaBitmapNode.build).
-            from druid_tpu.engine import pallas_agg
-            pallas_agg.mark_broken(e)
+        except pallas_agg.KernelBuildError as e:
+            # the kernel did not BUILD (trace → Pallas lowering → Mosaic
+            # compile): latch pallas off for the process and retry on the
+            # XLA windowed/mixed path — a shape the compiler refuses must
+            # not fail user queries (reference queries never depend on
+            # which engine strategy runs). Nothing that fails while the
+            # program RUNS is caught here. The latch is loud: the reason
+            # is pallas_agg.broken_reason(), and chip_smoke.py / bench.py
+            # treat a latched process as failed. A megakernel tree keeps
+            # working: its mega nodes expand to row masks in XLA
+            # (MegaBitmapNode.build).
+            pallas_agg.mark_broken(e.__cause__ or e)
             logging.getLogger(__name__).warning(
-                "pallas groupBy kernel failed to compile; falling back to "
-                "XLA path: %s", e)
+                "pallas %s kernel failed to build; falling back to the "
+                "XLA path: %s", spec.strategy, e)
             spec.strategy, spec.window = next(
                 (("windowed", w) for w in WINDOW_CHOICES
                  if spec.window and spec.window <= w),
@@ -1374,6 +1381,21 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
     return SegmentPartial(segment=segment, spec=spec,
                           counts=np.asarray(counts, dtype=np.int64),
                           states=host_states, kernels=kernels)
+
+
+def _build_kernel_program(fn, *args) -> None:
+    """Build a pallas-class jitted program for `args` WITHOUT running it:
+    trace, Pallas lowering and the Mosaic compile happen here, so the
+    latch in run_grouped_aggregate catches exactly the failures of the
+    BUILD (pallas_agg.KernelBuildError) and never one of the run. The jit
+    call that follows reuses the executable this leaves in jit's own
+    cache (nothing compiles twice); on a warm cache the call costs a
+    signature lookup."""
+    try:
+        fn.lower(*args).compile()
+    except Exception as e:
+        raise pallas_agg.KernelBuildError(
+            f"{type(e).__name__}: {e}") from e
 
 
 def _pad_device(arr: np.ndarray, padded: int, fill) -> object:

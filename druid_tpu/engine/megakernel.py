@@ -24,9 +24,9 @@ The fused path, per bitmap-eligible filter subtree:
     unpack per VMEM tile (engine/pallas_agg.py discipline), and the row
     mask arrives AS WORDS too — the interval/validity mask packs to words
     in-program, ANDs with the filter word algebra, and the kernel performs
-    a Mosaic-safe sub-lane unpack per block ((1, 128) of word VMEM instead
-    of an (R, 128) int32 row mask — ~32x less mask VMEM traffic). No
-    decoded column and no row-width mask ever hits HBM.
+    a sub-lane unpack per block (one word row out of a resident word tile
+    instead of an (R, 128) int32 row mask — ~32x less mask VMEM traffic).
+    No decoded column and no row-width mask ever hits HBM.
   * Per-group partial buffers DONATE across executions (`donate_argnums`,
     the pjit plumbing of SNIPPETS.md [1]/[2]): the raw accumulator grids
     of one run park in the device pool and are handed back — donated — to
@@ -54,8 +54,8 @@ import numpy as np
 
 from druid_tpu.engine import pallas_agg
 from druid_tpu.engine.contracts import (BLK_SMALL_W, MEGA_MASK_ROW_ALIGN,
-                                        MEGA_MASK_VPW, MEGA_MASK_WIDTH,
-                                        donation_supported)
+                                        MEGA_MASK_TILE_ALIGN, MEGA_MASK_VPW,
+                                        MEGA_MASK_WIDTH, donation_supported)
 from druid_tpu.engine.filters import (AndNode, DeviceBitmapNode, FilterNode,
                                       NotNode, OrNode, _leaf_digest,
                                       bitmap_pool_key, collect_bitmap_nodes,
@@ -373,8 +373,9 @@ _LANE = 128
 def staged_mask_rows(padded_rows: int) -> int:
     """Row count mask/leaf word arrays are sized for: covers every pallas
     row padding (n2 = round_up(max(rows, BLK), BLK) for BLK ≤ BLK_SMALL_W)
-    rounded to whole 128-lane word rows."""
-    return _round_up(max(padded_rows, BLK_SMALL_W), MEGA_MASK_ROW_ALIGN)
+    rounded to whole mask-word TILES — exactly mega_reduce's n2m, so the
+    resident leaf words AND into the fused mask without a pad."""
+    return _round_up(max(padded_rows, BLK_SMALL_W), MEGA_MASK_TILE_ALIGN)
 
 
 def expand_mask_words(words, rows: int):
@@ -511,38 +512,18 @@ def mega_reduce(arrays: Dict, mask, key, mega_nodes: Sequence[MegaBitmapNode],
 
     pallas_agg.pallas_reduce's contract plus the fused-mask inputs: the
     base row mask packs to words in-program, ANDs with each mega node's
-    inline word algebra, and the kernel unpacks ONE (1, 128) word tile per
-    block (sub-lane shifts at bit base (block % (32/R))·R) instead of
-    receiving a row mask — masked rows read the key sentinel exactly as
-    the staged kernel's keyx fold does, so results are bit-identical. The
-    raw grids ride back so the caller can park them as donated carries."""
-    import jax
+    inline word algebra, and the shared kernel (pallas_agg.grouped_reduce)
+    reads ONE word row per block out of the resident mask-word tile
+    (sub-lane shifts at bit base (block % (32/R))·R) instead of receiving a
+    row mask — masked rows read the key sentinel exactly as the staged
+    strategy's keyx fold does, so results are bit-identical. The raw grids
+    ride back so the caller can park them as donated carries."""
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    col_dtypes = {c: a.dtype for c, a in arrays.items()}
-    ops = [k.pallas_op(col_dtypes) for k in kernels]
-    assert all(o is not None for o in ops), \
-        "megakernel strategy selected but a kernel has no pallas op"
-
-    BLK, W = pallas_agg.plan_window(span)
-    assert BLK, f"span {span} too wide for the pallas window"
-    R = BLK // 128
-    Wr = W // 128
-    BPW = MEGA_MASK_VPW // R            # blocks per mask word row
-    SENTINEL = jnp.int32(2**31 - 1)     # host-side key padding only
-
+    # mask words cover every block padding the kernel can choose
+    # (staged_mask_rows: whole word tiles past round_up(n, BLK))
     n = mask.shape[0]
-    n2 = _round_up(max(n, BLK), BLK)
-    n2m = _round_up(n2, MEGA_MASK_ROW_ALIGN)
-    G2 = _round_up(num_total, 128) + W
-    nblk = n2 // BLK
-
-    def pad_rows(a, fill):
-        if n2 == n:
-            return a
-        return jnp.concatenate([a, jnp.full((n2 - n,), fill, a.dtype)])
+    n2m = staged_mask_rows(n)
 
     # the fused mask: base row mask (validity ∧ intervals ∧ residual
     # filter) packs to words in-program; each top-level mega conjunct ANDs
@@ -552,215 +533,22 @@ def mega_reduce(arrays: Dict, mask, key, mega_nodes: Sequence[MegaBitmapNode],
         maskp = jnp.concatenate(
             [mask, jnp.zeros((n2m - n,), jnp.bool_)])
     mwords = pack_mask_words_traced(maskp)
-    need_w = n2m // 32
+    need_w = n2m // MEGA_MASK_VPW
     for node in mega_nodes:
         w = node.words_traced(arrays)
         if w.shape[0] > need_w:
             w = w[:need_w]
         elif w.shape[0] < need_w:
-            # staged arrays cover staged_mask_rows(padded) ≥ n2m by
+            # staged arrays cover staged_mask_rows(padded) = n2m by
             # construction; zero-fill is the safe (masked) default anyway
             w = jnp.concatenate(
                 [w, jnp.zeros((need_w - w.shape[0],), w.dtype)])
         mwords = mwords & w
-    mwords2 = mwords.reshape(n2m // MEGA_MASK_ROW_ALIGN, 128)
 
     # keys stage RAW (no mask fold): the kernel sentinels masked rows from
     # the word bits, reproducing the staged keyx = where(mask, key,
     # SENTINEL) exactly
-    keyx = pad_rows(key.astype(jnp.int32), SENTINEL).reshape(n2 // 128, 128)
-
-    uniq_fields = pallas_agg.op_fields(ops)
-    pcs = {}
-    if packed_cols:
-        for f in uniq_fields:
-            pc = packed_cols.get(f)
-            # no decode-counter record: split_resident counted each
-            # packed column once at the program top (pallas_agg's rule)
-            if pc is not None and R % pc.vpw == 0 and pc.rows == n:
-                pcs[f] = pc
-    dense_fields = [f for f in uniq_fields if f not in pcs]
-    packed_fields = [f for f in uniq_fields if f in pcs]
-    field_ix = {f: i for i, f in enumerate(dense_fields + packed_fields)}
-    vals2 = [pad_rows(arrays[f], np.array(0, arrays[f].dtype))
-             .reshape(n2 // 128, 128) for f in dense_fields]
-    packed_desc = []
-    packed_rws = []
-    for f in packed_fields:
-        pc = pcs[f]
-        words = pc.words
-        pad_w = n2 // pc.vpw - words.shape[0]
-        if pad_w:
-            words = jnp.concatenate(
-                [words, jnp.zeros((pad_w,), words.dtype)])
-        vals2.append(words.reshape(n2 // pc.vpw // 128, 128))
-        packed_desc.append((pc.width, pc.vpw, pc.base))
-        packed_rws.append(R // pc.vpw)
-
-    K = None
-    for op in ops:
-        if op[0] == "sum_i32":
-            k_op = max(op[2] // BLK, 1)
-            K = k_op if K is None else min(K, k_op)
-
-    out_defs = pallas_agg.build_out_defs(ops)
-    slot_ix = {name: j for j, (name, _) in enumerate(out_defs)}
-    assert len(out_defs) == pallas_agg.op_slots(ops), \
-        "out_defs drifted from op_slots — update pallas_agg.build_out_defs"
-
-    def kernel(key_ref, mw_ref, *refs):
-        vrefs = refs[:len(uniq_fields)]
-        orefs = refs[len(uniq_fields):]
-        i = pl.program_id(0)
-
-        @pl.when(i == jnp.int32(0))
-        def _init():
-            for j, (name, dt) in enumerate(out_defs):
-                if name.startswith("m"):
-                    op = ops[int(name[1:])]
-                    if op[0] == "min_i32":
-                        ident = jnp.int32(2**31 - 1)
-                    elif op[0] == "max_i32":
-                        ident = jnp.int32(-(2**31))
-                    elif op[0] == "min_f32":
-                        ident = jnp.float32(jnp.inf)
-                    else:
-                        ident = jnp.float32(-jnp.inf)
-                    orefs[j][:, :] = jnp.full((G2 // 128, 128), ident)
-                else:
-                    orefs[j][:, :] = jnp.zeros((G2 // 128, 128), dt)
-
-        # sub-lane mask unpack: this block's R tile rows live in ONE word
-        # row at bit base (i % BPW)·R — a (1, 128) word tile expands to the
-        # (R, 128) bit tile with shifts along the sublane axis, no gather
-        wt = mw_ref[:, :]                          # (1, 128) int32
-        bit0 = (i % jnp.int32(BPW)) * jnp.int32(R)
-        sh = bit0 + jax.lax.broadcasted_iota(jnp.int32, (1, R, 128), 1)
-        mbit = ((wt[:, None, :] >> sh) & jnp.int32(1)).reshape(R, 128)
-
-        kb = key_ref[:, :]                         # (R, 128) int32
-        # the key sentinel is built INSIDE the kernel: a closure-captured
-        # jnp scalar is rejected as a captured tracer (the BENCH_r04
-        # constant-capture class)
-        kb = jnp.where(mbit > jnp.int32(0), kb, jnp.int32(2**31 - 1))
-        base = jnp.min(kb)
-        c128 = jnp.int32(128)
-        abase = (base // c128) * c128
-        abase = jnp.maximum(jnp.minimum(abase, jnp.int32(G2 - W)),
-                            jnp.int32(0))
-        local = kb - abase
-        r0 = abase // c128
-        lane = jax.lax.broadcasted_iota(jnp.int32, (R, 128, 128), 2)
-
-        vals_t = [vrefs[j][:, :] for j in range(len(dense_fields))]
-        for j, (wd, vpw, vbase) in enumerate(packed_desc):
-            pwt = vrefs[len(dense_fields) + j][:, :]
-            psh = jnp.int32(wd) * jax.lax.broadcasted_iota(
-                jnp.int32, (R // vpw, vpw, 128), 1)
-            pv = (pwt[:, None, :] >> psh) & jnp.int32((1 << wd) - 1)
-            if vbase:
-                pv = pv + jnp.int32(vbase)
-            vals_t.append(pv.reshape(R, 128))
-
-        for wr in range(Wr):
-            match = ((local - wr * 128)[:, :, None] == lane)
-            row = r0 + wr
-            cnt = jnp.sum(match.astype(jnp.int32), axis=(0, 1),
-                          dtype=jnp.int32)
-            cref = orefs[slot_ix["count"]]
-            cref[row, :] = cref[row, :] + cnt
-            for oi, op in enumerate(ops):
-                if op[0] in ("count", "zero", "empty"):
-                    continue
-                v = vals_t[field_ix[op[1]]]
-                if op[0] == "sum_i32":
-                    part = jnp.sum(jnp.where(match, v[:, :, None],
-                                             jnp.int32(0)),
-                                   axis=(0, 1), dtype=jnp.int32)
-                    ref = orefs[slot_ix[f"lo{oi}"]]
-                    ref[row, :] = ref[row, :] + part
-                elif op[0] == "sum_f32":
-                    part = jnp.sum(jnp.where(match, v[:, :, None],
-                                             jnp.float32(0)), axis=(0, 1),
-                                   dtype=jnp.float32)
-                    ref = orefs[slot_ix[f"f{oi}"]]
-                    ref[row, :] = ref[row, :] + part
-                else:
-                    kind = op[0]
-                    if kind == "min_i32":
-                        ident, red = jnp.int32(2**31 - 1), jnp.min
-                        comb = jnp.minimum
-                    elif kind == "max_i32":
-                        ident, red = jnp.int32(-(2**31)), jnp.max
-                        comb = jnp.maximum
-                    elif kind == "min_f32":
-                        ident, red = jnp.float32(jnp.inf), jnp.min
-                        comb = jnp.minimum
-                    else:
-                        ident, red = jnp.float32(-jnp.inf), jnp.max
-                        comb = jnp.maximum
-                    part = red(jnp.where(match, v[:, :, None], ident),
-                               axis=(0, 1))
-                    ref = orefs[slot_ix[f"m{oi}"]]
-                    ref[row, :] = comb(ref[row, :], part)
-
-        if K is not None:
-            @pl.when((i % jnp.int32(K)) == jnp.int32(K - 1))
-            def _flush():
-                for oi, op in enumerate(ops):
-                    if op[0] != "sum_i32":
-                        continue
-                    lo_ref = orefs[slot_ix[f"lo{oi}"]]
-                    hi_ref = orefs[slot_ix[f"hi{oi}"]]
-                    lo = lo_ref[:, :]
-                    hi_ref[:, :] = hi_ref[:, :] + (lo >> 16)
-                    lo_ref[:, :] = lo & 0xFFFF
-
-    out_shapes = [jax.ShapeDtypeStruct((G2 // 128, 128), dt)
-                  for _, dt in out_defs]
-    # index-map constants built typed inside the lambdas (the BENCH_r04
-    # Mosaic (i32, i64) func.return class; tracecheck guards it). The mask
-    # word tile's index map OVERLAPS deliberately: BPW consecutive blocks
-    # read the same (1, 128) word row at different bit bases.
-    grid_spec = pl.GridSpec(
-        grid=(nblk,),
-        in_specs=([pl.BlockSpec((R, 128), lambda i: (i, jnp.int32(0)),
-                                memory_space=pltpu.VMEM)]
-                  + [pl.BlockSpec((1, 128),
-                                  lambda i: (i // BPW, jnp.int32(0)),
-                                  memory_space=pltpu.VMEM)]
-                  + [pl.BlockSpec((R, 128), lambda i: (i, jnp.int32(0)),
-                                  memory_space=pltpu.VMEM)]
-                  * len(dense_fields)
-                  + [pl.BlockSpec((Rw, 128), lambda i: (i, jnp.int32(0)),
-                                  memory_space=pltpu.VMEM)
-                     for Rw in packed_rws]),
-        out_specs=[pl.BlockSpec((G2 // 128, 128),
-                                lambda i: (jnp.int32(0), jnp.int32(0)),
-                                memory_space=pltpu.VMEM)] * len(out_defs),
-    )
-    outs = pl.pallas_call(
-        kernel, out_shape=out_shapes, grid_spec=grid_spec,
-        interpret=pallas_agg._interpret(),
-    )(keyx, mwords2, *vals2)
-    flat = [o.reshape(-1)[:num_total] for o in outs]
-
-    counts = flat[slot_ix["count"]]
-    states = []
-    for oi, (k, op) in enumerate(zip(kernels, ops)):
-        if op[0] == "count":
-            states.append(counts)
-        elif op[0] == "sum_i32":
-            lo = flat[slot_ix[f"lo{oi}"]].astype(jnp.int64)
-            hi = flat[slot_ix[f"hi{oi}"]].astype(jnp.int64)
-            states.append((hi << 16) + lo)
-        elif op[0] == "sum_f32":
-            states.append(flat[slot_ix[f"f{oi}"]])
-        elif op[0] in ("min_i32", "max_i32", "min_f32", "max_f32"):
-            states.append(flat[slot_ix[f"m{oi}"]])
-        elif op[0] in ("zero", "empty"):
-            states.append(jnp.asarray(
-                np.broadcast_to(k.empty_state(1), (num_total,)).copy()))
-        else:  # pragma: no cover
-            raise AssertionError(f"unknown pallas op {op}")
-    return counts, tuple(states), tuple(outs)
+    return pallas_agg.grouped_reduce(
+        arrays, key.astype(jnp.int32),
+        mwords.reshape(n2m // MEGA_MASK_ROW_ALIGN, _LANE), kernels,
+        num_total, span, packed_cols)

@@ -18,30 +18,43 @@ fused TPU kernel over the sorted, key-compacted projection
     exact int64 semantics outside the kernel (the same chunking bound as
     SumKernel.chunk_rows).
 
-Stock-XLA strategies measured 21-77M rows/s on this chip for G≈131k; the
-windowed XLA path needs a sorted layout plus an L2 scatter pass. This kernel
-fuses the whole reduction.
+The windowed XLA path needs a sorted layout plus an L2 scatter pass; this
+kernel fuses the whole reduction. (No rate of either is measured on this
+installation — PERF.md.)
 
 Value columns that staged bit-packed (data/packed.py) stream into the
-kernel AS WORDS: an R//vpw-row tile per block that unpacks to the [R, 128]
-value tile with int32 shifts/masks in VMEM — the compressed-domain
-execution of the ROADMAP's HBM-wall item. The decoded column never exists
-in HBM; unpack is exact, so packed and dense runs are bit-identical.
+kernel AS WORDS: R // vpw word rows per block, read out of a resident
+(WORD_TILE_ROWS, 128) word tile and unpacked to the [R, 128] value tile
+with int32 shifts/masks in VMEM — the compressed-domain execution of the
+ROADMAP's HBM-wall item. The decoded column never exists in HBM; unpack is
+exact, so packed and dense runs are bit-identical.
 
-Off-TPU the projection falls back to the XLA windowed path
-(grouping._windowed_reduce); tests exercise this kernel via the pallas
-interpreter (force_interpret()).
+ONE kernel (grouped_reduce) serves both the staged strategy (pallas_reduce)
+and the fused one (megakernel.mega_reduce, which adds the mask-word
+operand). Every shape usable() admits compiles through Mosaic on a v5e
+(jax 0.9.0 / libtpu 0.0.34: both block sizes, all PACK_WIDTHS, with and
+without the mask operand, the slot cap at the group cap). Off-TPU the
+projection takes the XLA windowed path (grouping._windowed_reduce); tests
+exercise this kernel via the pallas interpreter (force_interpret()).
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from druid_tpu.engine.contracts import (BLK_SMALL_W, BLK_WIDE_W,
+from druid_tpu.engine.contracts import (BLK_SMALL_W, BLK_WIDE_W, LANE,
                                         MAX_PALLAS_FIELDS, MAX_PALLAS_GROUPS,
-                                        MAX_PALLAS_SLOTS, MAX_W, SPAN_BLOCK)
+                                        MAX_PALLAS_SLOTS, MAX_W,
+                                        MEGA_MASK_VPW, SPAN_BLOCK,
+                                        VMEM_SCRATCH_BYTES, WORD_TILE_ROWS)
+
+#: log2(WORD_TILE_ROWS): every in-kernel divide/modulo by a tile, word or
+#: block count is a shift/mask on int32 operands (all are powers of two)
+_TILE_SHIFT = WORD_TILE_ROWS.bit_length() - 1
+assert 1 << _TILE_SHIFT == WORD_TILE_ROWS
 
 _FORCE_INTERPRET = False
 _BROKEN: Optional[str] = None
@@ -53,12 +66,29 @@ def force_interpret(on: bool = True):
     _FORCE_INTERPRET = on
 
 
+class KernelBuildError(Exception):
+    """A pallas-class program failed to BUILD — trace, Pallas lowering or
+    the Mosaic compile (grouping._build_kernel_program raises it with the
+    original exception as __cause__). The one failure the strategy latch
+    in grouping.run_grouped_aggregate catches; nothing raised while a
+    built program RUNS is ever this."""
+
+
 def mark_broken(exc: BaseException) -> None:
-    """Latch the pallas path off for this process after a Mosaic compile
+    """Latch the pallas path off for this process after a kernel build
     failure — the caller already fell back to an XLA strategy; retrying a
-    known-broken compile on every query would cost seconds each time."""
+    known-broken compile on every query would cost seconds each time. The
+    reason stays readable (broken_reason) so no caller has to guess
+    whether a result came from the kernel or from its fallback."""
     global _BROKEN
-    _BROKEN = repr(exc)
+    _BROKEN = f"{type(exc).__name__}: {exc}"
+
+
+def broken_reason() -> Optional[str]:
+    """Why the pallas path is latched off in this process, or None while
+    it is live. chip_smoke.py and bench.py fail on a non-None value: a
+    result computed after the latch is an XLA result under another name."""
+    return _BROKEN
 
 
 def _round_up(x: int, m: int) -> int:
@@ -70,20 +100,19 @@ def backend_ok() -> bool:
     blessed by `donorguard-platform-gate` (the other is
     contracts.donation_supported): backend comparisons anywhere else in
     the tree fail the donate-platform-gate rule, so strategy and
-    donation decisions cannot scatter into inline checks."""
+    donation decisions cannot scatter into inline checks. Only the
+    platform decides: on a TPU a failed import of the TPU pallas modules
+    raises — a broken installation must not read as "no Pallas here"."""
     if _FORCE_INTERPRET or os.environ.get("DRUID_TPU_PALLAS") == "interpret":
         return True
     if os.environ.get("DRUID_TPU_PALLAS") == "0" or _BROKEN is not None:
         return False
-    try:
-        import jax
-        from jax.experimental import pallas as pl  # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-        return jax.default_backend() == "tpu"
-    except Exception:  # druidlint: disable=swallowed-exception
-        # availability probe: any import/backend failure just means "no
-        # pallas here" — the XLA strategies serve every query regardless
+    import jax
+    if jax.default_backend() != "tpu":
         return False
+    from jax.experimental import pallas as pl  # noqa: F401
+    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+    return True
 
 
 def _interpret() -> bool:
@@ -100,6 +129,17 @@ def plan_window(span: int) -> Tuple[int, int]:
         if w <= MAX_W:
             return blk, w
     return 0, 0
+
+
+def canonical_span(span: int) -> int:
+    """The widest span that plans exactly like `span` (same block rows,
+    same window). The kernel depends on a span only through plan_window,
+    while the span a projection measures is data (389, 392, 393 on three
+    12.5M-row headline segments): keying programs on the canonical span
+    lets every segment of a query share ONE compiled kernel instead of
+    compiling one per segment."""
+    blk, w = plan_window(span)
+    return (w - LANE) // max(blk // SPAN_BLOCK, 1) if blk else span
 
 
 #: ops that read one value column (a VMEM input tile each)
@@ -163,17 +203,114 @@ def usable(kernels: Sequence, col_dtypes: Dict, span: int,
         and op_slots(ops) <= MAX_PALLAS_SLOTS
 
 
-def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
-                  span: int, packed_cols: Optional[Dict] = None):
-    """Traced: (counts int32 [num_total], per-kernel states), the same
-    contract as grouping's scatter/blocked paths.
+def _word_tile_index(word_rows_per_block: int, i):
+    """Index map of a packed field's (WORD_TILE_ROWS, 128) word tile: block
+    i's word rows start at array row i · word_rows_per_block, which lies in
+    tile (i · word_rows_per_block) // WORD_TILE_ROWS. A named function bound
+    with functools.partial, not a lambda: a comprehension's lambdas would
+    all close over the LAST field's row count. Typed int32 shift/multiply
+    only — under the repo-global x64 flag `i // 8` passes the Python int as
+    an i64 operand, and Mosaic's conversion helper recurses without end on
+    it (RecursionError at lowering, seen on the chip)."""
+    import jax.numpy as jnp
+    return ((i * jnp.int32(word_rows_per_block)) >> jnp.int32(_TILE_SHIFT),
+            jnp.int32(0))
 
-    `arrays` is the dense view; `packed_cols` (data/packed.py
-    PackedColumns) supplies bit-packed words for value fields that staged
-    compressed — those stream into the kernel AS WORDS (an R//vpw-row tile
-    per block instead of R) and unpack per tile in VMEM, so the decoded
-    column never materializes in HBM. Unpack is exact, so results stay
-    bit-identical to the dense path."""
+
+def _stage_fields(arrays: Dict, ops: Sequence, BLK: int, n: int, n2: int,
+                  packed_cols: Optional[Dict] = None):
+    """Traced: the kernel's value operands for a plan's ops —
+    (vals2, field_ix, n_dense, packed_desc). Dense fields lead as
+    [n2 // 128, 128] tiles; fields that staged bit-packed (data/packed.py)
+    trail AS WORDS, their rows padded to whole WORD_TILE_ROWS tiles (zero
+    words decode to `base` on padding rows; padding rows are masked, so no
+    op ever matches them). packed_desc = ((width, vpw, base), ...) per
+    packed field, in operand order."""
+    import jax.numpy as jnp
+
+    R = BLK // LANE
+    uniq_fields = op_fields(ops)
+    assert len(uniq_fields) <= MAX_PALLAS_FIELDS, \
+        f"{len(uniq_fields)} value columns exceed the pallas field cap"
+    pcs = {}
+    for f in uniq_fields:
+        pc = (packed_cols or {}).get(f)
+        # vpw divides R by the PACK_WIDTHS contract; a descriptor that
+        # violates it (or a row-count mismatch) falls back to the dense
+        # view of that field — correctness never depends on packing. No
+        # decode-counter record here: split_resident already counted each
+        # packed column once at the program top (XLA dead-code-eliminates
+        # that unpack when the kernel consumes the words instead).
+        if pc is not None and R % pc.vpw == 0 and pc.rows == n:
+            pcs[f] = pc
+    dense_fields = [f for f in uniq_fields if f not in pcs]
+    packed_fields = [f for f in uniq_fields if f in pcs]
+    field_ix = {f: i for i, f in enumerate(dense_fields + packed_fields)}
+
+    def pad_to(a, rows, fill):
+        if a.shape[0] == rows:
+            return a
+        return jnp.concatenate(
+            [a, jnp.full((rows - a.shape[0],), fill, a.dtype)])
+
+    vals2 = [pad_to(arrays[f], n2, np.array(0, arrays[f].dtype))
+             .reshape(n2 // LANE, LANE) for f in dense_fields]
+    packed_desc = []
+    for f in packed_fields:
+        pc = pcs[f]
+        word_rows = _round_up(n2 // pc.vpw // LANE, WORD_TILE_ROWS)
+        vals2.append(pad_to(pc.words, word_rows * LANE,
+                            np.array(0, pc.words.dtype))
+                     .reshape(word_rows, LANE))
+        packed_desc.append((pc.width, pc.vpw, pc.base))
+    return vals2, field_ix, len(dense_fields), tuple(packed_desc)
+
+
+def _finish_states(outs: Sequence, kernels: Sequence, ops: Sequence,
+                   num_total: int):
+    """Traced: (counts, per-kernel states) from the kernel's raw grids —
+    the contract of grouping's scatter/blocked paths. int32 limb pairs
+    widen to exact int64 sums HERE, outside the kernel."""
+    import jax.numpy as jnp
+
+    slot_ix = {name: j for j, (name, _) in enumerate(build_out_defs(ops))}
+    flat = [o.reshape(-1)[:num_total] for o in outs]
+    counts = flat[slot_ix["count"]]
+    states = []
+    for oi, (k, op) in enumerate(zip(kernels, ops)):
+        if op[0] == "count":
+            states.append(counts)
+        elif op[0] == "sum_i32":
+            lo = flat[slot_ix[f"lo{oi}"]].astype(jnp.int64)
+            hi = flat[slot_ix[f"hi{oi}"]].astype(jnp.int64)
+            states.append((hi << 16) + lo)
+        elif op[0] == "sum_f32":
+            states.append(flat[slot_ix[f"f{oi}"]])
+        elif op[0] in ("min_i32", "max_i32", "min_f32", "max_f32"):
+            states.append(flat[slot_ix[f"m{oi}"]])
+        elif op[0] in ("zero", "empty"):
+            states.append(jnp.asarray(
+                np.broadcast_to(k.empty_state(1), (num_total,)).copy()))
+        else:  # pragma: no cover
+            raise AssertionError(f"unknown pallas op {op}")
+    return counts, tuple(states)
+
+
+def grouped_reduce(arrays: Dict, key, mwords2, kernels: Sequence,
+                   num_total: int, span: int,
+                   packed_cols: Optional[Dict] = None):
+    """Traced: THE pallas_call — (counts, per-kernel states, raw accumulator
+    grids in build_out_defs order). Both the staged strategy
+    (pallas_reduce: mask folded into the keys outside the kernel) and the
+    fused one (megakernel.mega_reduce: mask arrives as words) dispatch this
+    one kernel, so a Mosaic repair lands once.
+
+    key      int32 [n] keys; masked rows carry the int32-max sentinel when
+             `mwords2` is None
+    mwords2  optional int32 [rows, 128] width-1 mask words covering at
+             least the block-padded rows (rows a multiple of
+             WORD_TILE_ROWS): the kernel sentinels rows whose bit is 0
+    """
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -183,68 +320,30 @@ def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
     ops = [k.pallas_op(col_dtypes) for k in kernels]
     assert all(o is not None for o in ops), \
         "pallas strategy selected but a kernel has no pallas op"
-
     BLK, W = plan_window(span)
     assert BLK, f"span {span} too wide for the pallas window"
+    n = key.shape[0]
+    n2 = _round_up(max(n, BLK), BLK)
+    keyx = key
+    if n2 != n:
+        keyx = jnp.concatenate(
+            [key, jnp.full((n2 - n,), jnp.int32(2**31 - 1), jnp.int32)])
+    keyx = keyx.reshape(n2 // LANE, LANE)
+    vals2, field_ix, n_dense, packed_desc = _stage_fields(
+        arrays, ops, BLK, n, n2, packed_cols)
+
     assert num_total <= MAX_PALLAS_GROUPS, \
         f"num_total {num_total} above the pallas group cap (vmem contract)"
-    R = BLK // 128
-    Wr = W // 128
-    SENTINEL = jnp.int32(2**31 - 1)
-
-    n = mask.shape[0]
-    n2 = _round_up(max(n, BLK), BLK)
-    G2 = _round_up(num_total, 128) + W
+    R = BLK // LANE
+    Wr = W // LANE
+    G2 = _round_up(num_total, LANE) + W
     nblk = n2 // BLK
-
-    def pad_rows(a, fill):
-        if n2 == n:
-            return a
-        return jnp.concatenate(
-            [a, jnp.full((n2 - n,), fill, a.dtype)])
-
-    keyx = jnp.where(mask, key.astype(jnp.int32), SENTINEL)
-    keyx = pad_rows(keyx, SENTINEL).reshape(n2 // 128, 128)
-
-    # kernel inputs: key + one value column per op that reads one (the
-    # same layout helper usable() sized the plan with). Dense fields lead,
-    # packed fields trail — their word tiles have a different shape, and a
-    # stable operand order keeps the in_specs expression analyzable.
-    uniq_fields = op_fields(ops)
-    assert len(uniq_fields) <= MAX_PALLAS_FIELDS, \
-        f"{len(uniq_fields)} value columns exceed the pallas field cap"
-    pcs = {}
-    if packed_cols:
-        for f in uniq_fields:
-            pc = packed_cols.get(f)
-            # vpw divides R by the PACK_WIDTHS contract; a descriptor that
-            # violates it (or a row-count mismatch) falls back to the dense
-            # view of that field — correctness never depends on packing.
-            # No decode-counter record here: split_resident already
-            # counted each packed column once at the program top (the XLA
-            # unpack XLA dead-code-eliminates when this kernel consumes
-            # the words instead) — recording again would double-count.
-            if pc is not None and R % pc.vpw == 0 and pc.rows == n:
-                pcs[f] = pc
-    dense_fields = [f for f in uniq_fields if f not in pcs]
-    packed_fields = [f for f in uniq_fields if f in pcs]
-    field_ix = {f: i for i, f in enumerate(dense_fields + packed_fields)}
-    vals2 = [pad_rows(arrays[f], np.array(0, arrays[f].dtype))
-             .reshape(n2 // 128, 128) for f in dense_fields]
-    packed_desc = []                 # (width, vpw, base) per packed field
-    packed_rws = []                  # word rows per block, per packed field
-    for f in packed_fields:
-        pc = pcs[f]
-        words = pc.words
-        pad_w = n2 // pc.vpw - words.shape[0]
-        if pad_w:
-            # zero words decode to `base` on padding rows; padding rows
-            # carry the key SENTINEL, so no op ever matches them
-            words = jnp.concatenate(
-                [words, jnp.zeros((pad_w,), words.dtype)])
-        vals2.append(words.reshape(n2 // pc.vpw // 128, 128))
-        packed_desc.append((pc.width, pc.vpw, pc.base))
-        packed_rws.append(R // pc.vpw)
+    BPW = MEGA_MASK_VPW // R            # blocks per mask word row
+    bpw_shift = BPW.bit_length() - 1
+    assert 1 << bpw_shift == BPW
+    has_mask = mwords2 is not None
+    n_mask = int(has_mask)
+    packed_rws = [R // vpw for _, vpw, _ in packed_desc]
 
     # flush period for int32 limb sums: lo grows ≤ BLK·max_abs per block and
     # chunk_rows·max_abs ≤ 2^30 by SumKernel's analysis, so chunk_rows // BLK
@@ -255,12 +354,10 @@ def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
             k_op = max(op[2] // BLK, 1)
             K = k_op if K is None else min(K, k_op)
 
-    # per-op output slots: (op index, slot kind) — the shared builder, so
-    # the megakernel's carry allocator sees exactly this layout
+    # the shared builder is authoritative; op_slots() (which usable() sized
+    # the plan with) must agree, so a new op kind cannot drift between them
     out_defs = build_out_defs(ops)
     slot_ix = {name: j for j, (name, _) in enumerate(out_defs)}
-    # the builder above is authoritative; op_slots() (which usable() sized
-    # the plan with) must agree, so a new op kind cannot drift between them
     assert len(out_defs) == op_slots(ops), \
         f"out_defs {len(out_defs)} != op_slots {op_slots(ops)} — a new " \
         f"pallas op kind updated one layout but not the other"
@@ -268,8 +365,10 @@ def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
         f"{len(out_defs)} output slots exceed the pallas slot cap"
 
     def kernel(key_ref, *refs):
-        vrefs = refs[:len(uniq_fields)]
-        orefs = refs[len(uniq_fields):]
+        mw_ref = refs[0] if has_mask else None
+        refs = refs[n_mask:]
+        vrefs = refs[:len(vals2)]
+        orefs = refs[len(vals2):]
         i = pl.program_id(0)
 
         @pl.when(i == jnp.int32(0))
@@ -289,7 +388,23 @@ def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
                 else:
                     orefs[j][:, :] = jnp.zeros((G2 // 128, 128), dt)
 
+        # tile row index of every cell of this block's [R, 128] tiles
+        rows = jax.lax.broadcasted_iota(jnp.int32, (R, 128), 0)
+
         kb = key_ref[:, :]                       # [R, 128] int32
+        if has_mask:
+            # this block's R tile rows live in ONE word row (MEGA_MASK_VPW
+            # % R == 0) at bit base (i % BPW)·R: read that row out of the
+            # resident word tile and shift along the sublane axis — no
+            # reshape, no gather. Masked rows read the key sentinel, built
+            # INSIDE the kernel (a closure-captured jnp scalar is rejected
+            # as a captured tracer).
+            wrow = (i >> jnp.int32(bpw_shift)) \
+                & jnp.int32(WORD_TILE_ROWS - 1)
+            wt = mw_ref[pl.ds(wrow, 1), :]       # (1, 128) int32
+            bit0 = (i & jnp.int32(BPW - 1)) * jnp.int32(R)
+            mbit = (wt >> (bit0 + rows)) & jnp.int32(1)
+            kb = jnp.where(mbit > jnp.int32(0), kb, jnp.int32(2**31 - 1))
         base = jnp.min(kb)
         # all-scalar int32 math: mixed weak-type promotion recurses forever
         # in the Mosaic conversion helper
@@ -302,20 +417,29 @@ def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
         lane = jax.lax.broadcasted_iota(jnp.int32, (R, 128, 128), 2)
 
         # materialize every field's [R, 128] value tile once per block.
-        # Packed fields arrive as [R // vpw, 128] word tiles and unpack
-        # here — int32 shift/mask on the VPU, then a reshape that restores
-        # exactly the tile-planar row order pack_padded encoded (value row
-        # q*vpw + s lives in word row q at bit slot s); arithmetic >> is
-        # safe because the mask cuts the sign-extension bits
-        vals_t = [vrefs[j][:, :] for j in range(len(dense_fields))]
-        for j, (wd, vpw, base) in enumerate(packed_desc):
-            wt = vrefs[len(dense_fields) + j][:, :]      # [R // vpw, 128]
-            sh = jnp.int32(wd) * jax.lax.broadcasted_iota(
-                jnp.int32, (R // vpw, vpw, 128), 1)
-            pv = (wt[:, None, :] >> sh) & jnp.int32((1 << wd) - 1)
-            if base:
-                pv = pv + jnp.int32(base)
-            vals_t.append(pv.reshape(R, 128))
+        # A packed field's Rw = R // vpw word rows sit inside the resident
+        # (WORD_TILE_ROWS, 128) word tile at row (i·Rw) % WORD_TILE_ROWS;
+        # value row r reads word row r // vpw at bit slot r % vpw — exactly
+        # the tile-planar order pack_padded encoded. The gather over at
+        # most WORD_TILE_ROWS word rows is a select chain on static
+        # single-row reads (sub-(8, 128) blocks and the (Rw, vpw, 128) →
+        # (R, 128) reshape do not lower); arithmetic >> is safe because
+        # the mask cuts the sign-extension bits.
+        vals_t = [vrefs[j][:, :] for j in range(n_dense)]
+        for j, (wd, vpw, vbase) in enumerate(packed_desc):
+            wref = vrefs[n_dense + j]
+            lg = vpw.bit_length() - 1            # vpw is a power of two
+            src = ((i * jnp.int32(R // vpw))
+                   & jnp.int32(WORD_TILE_ROWS - 1)) + (rows >> jnp.int32(lg))
+            words = jnp.zeros((R, 128), jnp.int32)
+            for q in range(WORD_TILE_ROWS):
+                words = jnp.where(src == jnp.int32(q), wref[q:q + 1, :],
+                                  words)
+            pv = (words >> ((rows & jnp.int32(vpw - 1)) * jnp.int32(wd))) \
+                & jnp.int32((1 << wd) - 1)
+            if vbase:
+                pv = pv + jnp.int32(vbase)
+            vals_t.append(pv)
 
         # per window-row matches, shared across every op
         for wr in range(Wr):
@@ -328,9 +452,7 @@ def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
             cref = orefs[slot_ix["count"]]
             cref[row, :] = cref[row, :] + cnt
             for oi, op in enumerate(ops):
-                if op[0] == "count":
-                    continue
-                if op[0] in ("zero", "empty"):
+                if op[0] in ("count", "zero", "empty"):
                     continue
                 v = vals_t[field_ix[op[1]]]
                 if op[0] == "sum_i32":
@@ -381,47 +503,61 @@ def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
     # index-map constants must be typed AND built inside the lambda: under
     # the repo-global x64 flag a Python-int 0 promotes to i64 and Mosaic
     # fails to legalize the (i32, i64) func.return of the index map, while a
-    # closure-captured jnp scalar is rejected as a captured tracer (the
-    # BENCH_r04 failure class; tracecheck pallas-accum-dtype guards it).
-    # Packed word tiles declare (Rw, 128) = (R // vpw, 128) blocks — the
-    # index map is still block-granular, so (i, 0) addresses word rows
+    # closure-captured jnp scalar is rejected as a captured tracer
+    # (tracecheck pallas-accum-dtype guards it). Word tiles — mask and
+    # packed values alike — are whole (WORD_TILE_ROWS, 128) blocks whose
+    # index maps OVERLAP deliberately: consecutive blocks read the same
+    # resident tile at different rows/bit bases (Mosaic refuses blocks
+    # whose second-minor dim is neither a multiple of 8 nor the array's).
     grid_spec = pl.GridSpec(
         grid=(nblk,),
         in_specs=([pl.BlockSpec((R, 128), lambda i: (i, jnp.int32(0)),
                                 memory_space=pltpu.VMEM)]
-                  * (1 + len(dense_fields))
-                  + [pl.BlockSpec((Rw, 128), lambda i: (i, jnp.int32(0)),
+                  + [pl.BlockSpec((WORD_TILE_ROWS, 128),
+                                  lambda i: (i >> jnp.int32(bpw_shift
+                                                            + _TILE_SHIFT),
+                                             jnp.int32(0)),
+                                  memory_space=pltpu.VMEM)] * n_mask
+                  + [pl.BlockSpec((R, 128), lambda i: (i, jnp.int32(0)),
+                                  memory_space=pltpu.VMEM)] * n_dense
+                  + [pl.BlockSpec((WORD_TILE_ROWS, 128),
+                                  functools.partial(_word_tile_index, Rw),
                                   memory_space=pltpu.VMEM)
                      for Rw in packed_rws]),
         out_specs=[pl.BlockSpec((G2 // 128, 128),
                                 lambda i: (jnp.int32(0), jnp.int32(0)),
                                 memory_space=pltpu.VMEM)] * len(out_defs),
     )
+    # the pipeline double-buffers every blocked operand — the resident
+    # output grids included — and the [R, 128, 128] one-hot temporaries
+    # spill to Mosaic's internal scratch; at the group/slot caps that
+    # passes the default scoped-VMEM limit, so the limit is stated
+    tile_bytes = 4 * (G2 * len(out_defs) + BLK * (1 + n_dense)
+                      + WORD_TILE_ROWS * 128
+                      * (n_mask + len(packed_rws)))
     outs = pl.pallas_call(
         kernel, out_shape=out_shapes, grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * tile_bytes + VMEM_SCRATCH_BYTES),
         interpret=_interpret(),
-    )(keyx, *vals2)
-    outs = [o.reshape(-1)[:num_total] for o in outs]
+    )(keyx, *([mwords2] if has_mask else []), *vals2)
+    counts, states = _finish_states(outs, kernels, ops, num_total)
+    return counts, states, tuple(outs)
 
-    counts = outs[slot_ix["count"]]
-    states = []
-    for oi, (k, op) in enumerate(zip(kernels, ops)):
-        if op[0] == "count":
-            states.append(counts)
-        elif op[0] == "sum_i32":
-            lo = outs[slot_ix[f"lo{oi}"]].astype(jnp.int64)
-            hi = outs[slot_ix[f"hi{oi}"]].astype(jnp.int64)
-            states.append((hi << 16) + lo)
-        elif op[0] == "sum_f32":
-            states.append(outs[slot_ix[f"f{oi}"]])
-        elif op[0] in ("min_i32", "max_i32", "min_f32", "max_f32"):
-            states.append(outs[slot_ix[f"m{oi}"]])
-        elif op[0] == "zero":
-            states.append(jnp.asarray(
-                np.broadcast_to(k.empty_state(1), (num_total,)).copy()))
-        elif op[0] == "empty":
-            states.append(jnp.asarray(
-                np.broadcast_to(k.empty_state(1), (num_total,)).copy()))
-        else:  # pragma: no cover
-            raise AssertionError(f"unknown pallas op {op}")
-    return counts, tuple(states)
+
+def pallas_reduce(arrays: Dict, mask, key, kernels: Sequence, num_total: int,
+                  span: int, packed_cols: Optional[Dict] = None):
+    """Traced: (counts int32 [num_total], per-kernel states), the same
+    contract as grouping's scatter/blocked paths.
+
+    `arrays` is the dense view; `packed_cols` (data/packed.py
+    PackedColumns) supplies bit-packed words for value fields that staged
+    compressed — those stream into the kernel AS WORDS and unpack per tile
+    in VMEM, so the decoded column never materializes in HBM. Unpack is
+    exact, so results stay bit-identical to the dense path."""
+    import jax.numpy as jnp
+
+    keyx = jnp.where(mask, key.astype(jnp.int32), jnp.int32(2**31 - 1))
+    counts, states, _raw = grouped_reduce(arrays, keyx, None, kernels,
+                                          num_total, span, packed_cols)
+    return counts, states

@@ -3,13 +3,19 @@
 The reference's storage hot path rides JVM-native mechanics (lz4-java block
 codec, off-heap ByteBuffers — reference:
 processing/.../segment/data/CompressionStrategy.java:48). Here it is a real
-C++ shared library: built on demand with g++ the first time it's needed,
-cached beside the source. Everything degrades gracefully — callers check
-`available()` and fall back to zlib/numpy paths if the toolchain is absent.
+C++ shared library, ALWAYS built from the tracked source with g++ the first
+time it is needed: the binary is never committed, and it is named after a
+hash of the source, so a stale build can never be chosen over the source (a
+copy of the tree preserves content, not mtimes). Callers that can live
+without it check `available()` and fall back to zlib/numpy paths; callers
+that cannot (chip_smoke.py — the pure-python LZ4 codec at 100M rows looks
+like a hang) call `require()`, which raises with the compiler's message.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,40 +27,56 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 _SRC = os.path.join(_NATIVE_DIR, "druid_native.cpp")
-_SO = os.path.join(_NATIVE_DIR, "libdruid_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_error: Optional[str] = None
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_NATIVE_DIR, f"libdruid_native-{digest}.so")
+
+
+def _build(so: str) -> None:
+    """Compile the source to `so`. Builds beside the target and renames, so
+    a concurrent process (peons fork beside the server) never loads a
+    half-written library; earlier sources' builds are swept."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-pthread",
-             "-o", _SO, _SRC],
+             "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        # no toolchain / compile failure: callers fall back to numpy paths
-        return False
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_NATIVE_DIR, "libdruid_native*.so")):
+        if stale != so:
+            os.unlink(stale)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _error
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not (os.path.exists(_SRC) and _build()):
-                if not os.path.exists(_SO):
-                    return None
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            so = _so_path()
+            if not os.path.exists(so):
+                _build(so)
+            lib = ctypes.CDLL(so)
+        except subprocess.CalledProcessError as e:
+            _error = f"g++ failed: {e.stderr.decode(errors='replace')[-2000:]}"
+            return None
+        except (OSError, subprocess.SubprocessError) as e:
+            # no source / no toolchain / unloadable: callers fall back to
+            # numpy paths, or fail loudly through require()
+            _error = f"{type(e).__name__}: {e}"
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -81,6 +103,14 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def require() -> None:
+    """Raise unless the native library built and loaded — for paths where
+    the pure-python fallbacks are not an option."""
+    if _load() is None:
+        raise RuntimeError(
+            f"native library unavailable (built from {_SRC}): {_error}")
 
 
 def _u8(a: np.ndarray):

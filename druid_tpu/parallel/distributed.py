@@ -313,9 +313,10 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
             _FN_CACHE.move_to_end(sig)
     from druid_tpu.obs import dispatch as dispatch_mod
     dispatch_mod.record("sharded")
-    with trace_span("engine/sharded/dispatch", segments=K, devices=n_dev,
-                    compile=compiled), \
-            trace_span_when(compiled, "engine/compile", kind="sharded"):
+    with trace_span("engine/sharded/dispatch", strategy=spec0.strategy,
+                    segments=K, devices=n_dev, compile=compiled), \
+            trace_span_when(compiled, "engine/compile", kind="sharded",
+                            strategy=spec0.strategy):
         counts, states = fn(stacked, time0s, iv_rel, bucket_off, aux)
     _SHARDED_STATS.record(len(segments))
 
@@ -620,33 +621,6 @@ def _merge_states(kernel: AggKernel, stacked_state, axis: str, n_dev: int,
     from jax import lax
 
     kind = kernel.reduce_kind
-    # On a 1-device mesh every collective is the identity; use psum for all
-    # kinds — it satisfies the replication (vma) check and is the one
-    # collective every TPU transport lowers (some support only Sum
-    # all-reduce). Bool states must go through int for psum.
-    if n_dev == 1:
-        if kind != "sum":
-            if kind == "max":
-                st = jax.tree.map(lambda x: x.max(axis=0), stacked_state)
-            elif kind == "min":
-                st = jax.tree.map(lambda x: x.min(axis=0), stacked_state)
-            else:
-                parts = [jax.tree.map(lambda x, i=i: x[i], stacked_state)
-                         for i in range(k_local)]
-                st = functools.reduce(kernel.device_combine, parts)
-        else:
-            # cross-segment integer sums widen to int64 before the fold —
-            # exactness contract, x64 globally on (engine/__init__)
-            st = jax.tree.map(
-                lambda x: (x.astype(jnp.int64)  # druidlint: disable=x64-dtype
-                           if jnp.issubdtype(x.dtype, jnp.integer)
-                           else x).sum(axis=0), stacked_state)
-
-        def ident_psum(x):
-            if x.dtype == jnp.bool_:
-                return lax.psum(x.astype(jnp.int32), axis) > 0
-            return lax.psum(x, axis)
-        return jax.tree.map(ident_psum, st)
     if kind == "sum":
         def local(x):
             if jnp.issubdtype(x.dtype, jnp.integer):
@@ -677,12 +651,7 @@ def _build_sharded_fn(mesh, axis: str, n_dev: int, spec: GroupSpec,
                       layout: "speclayout.SpecLayout", stacked):
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map          # jax >= 0.5
-        _check_kw = "check_vma"
-    except ImportError:                    # 0.4.x: experimental home,
-        from jax.experimental.shard_map import shard_map
-        _check_kw = "check_rep"            # and the old replication-check kw
+    from jax import shard_map
 
     seg_body = make_stacked_segment_fn(spec, kds, filter_node, kernels,
                                        vc_plans)
@@ -708,10 +677,10 @@ def _build_sharded_fn(mesh, axis: str, n_dev: int, spec: GroupSpec,
     # fold-merged states go through all_gather, whose output the vma system
     # conservatively marks varying even though it is replicated by
     # construction — turn the static replication check off for those.
-    has_fold = any(k.reduce_kind == "fold" for k in kernels) and n_dev > 1
+    has_fold = any(k.reduce_kind == "fold" for k in kernels)
     f = shard_map(body, mesh=mesh,
                   in_specs=layout.in_specs(stacked),
-                  out_specs=layout.out_specs(), **{_check_kw: not has_fold})
+                  out_specs=layout.out_specs(), check_vma=not has_fold)
     return jax.jit(f)
 
 

@@ -1,12 +1,12 @@
-"""Bench smoke gate: `python bench.py` must exit 0 on CPU and print ONE
-valid JSON line with the headline + batch-comparison fields.
+"""Bench smoke gate: `python bench.py`, EXPLICITLY pinned to the CPU, must
+exit 0 and print ONE valid JSON line with the headline + comparison fields
+and the device it ran on; without a TPU and without that pin it must exit
+non-zero and print no number, as it must when a section raises.
 
-The benchmark zeroing a whole trajectory because of an environment wedge
-(every BENCH_r0*.json rc=1, "backend init hung") is exactly the silent
-breakage this tier-1 test exists to catch: tiny row counts keep it fast,
-the CPU pin keeps it hermetic, and the assertion is on CONTRACT (rc=0,
-parseable one-line JSON, fields present) — not on throughput, which this
-shared CI hardware cannot promise."""
+Tiny row counts keep it fast, the explicit CPU pin keeps it hermetic, and
+the assertions are on CONTRACT (exit code, parseable one-line JSON, fields
+present, counts) — never on a rate or a ratio of rates: a CPU timing says
+nothing about the device the benchmark exists to measure."""
 import json
 import os
 import subprocess
@@ -24,7 +24,6 @@ BENCH_ENV = {
     "DRUID_TPU_BENCH_ITERS": "1",
     "DRUID_TPU_BENCH_BATCH_SEGMENTS": "4",
     "DRUID_TPU_BENCH_BATCH_ROWS": "1024",
-    "DRUID_TPU_BENCH_INIT_TIMEOUT": "120",
     "DRUID_TPU_BENCH_CASCADE_SEGMENTS": "4",
     "DRUID_TPU_BENCH_CASCADE_ROWS": "2048",
     "DRUID_TPU_BENCH_SEGIO_ROWS": "4096",
@@ -38,7 +37,7 @@ BENCH_ENV = {
 }
 
 
-def _run_bench(extra_env=None):
+def _run_bench(extra_env=None, drop=()):
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)       # the bench must pin its own
     # conftest forces an 8-virtual-device CPU fleet for the mesh tests;
@@ -47,6 +46,8 @@ def _run_bench(extra_env=None):
     env.pop("XLA_FLAGS", None)
     env.update(BENCH_ENV)
     env.update(extra_env or {})
+    for k in drop:
+        env.pop(k, None)
     return subprocess.run(
         [sys.executable, str(REPO_ROOT / "bench.py")],
         cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=420)
@@ -62,6 +63,11 @@ def test_bench_exits_zero_with_one_json_line():
     out = json.loads(lines[0])
     assert out["metric"] == "groupby+topn_scan_rate"
     assert out["value"] > 0 and "error" not in out
+    # every line names the device it ran on — here the explicit CPU pin
+    assert out["platform"] == "cpu"
+    assert out["device_kind"] and out["device_count"] >= 1
+    assert not [k for k in out if k.endswith("_error")]
+    assert "pallas_broken" not in out
     # the batch-comparison fields the perf gate reads
     assert out["per_segment_rate"] > 0
     assert out["batched_rate"] > 0
@@ -91,16 +97,11 @@ def test_bench_exits_zero_with_one_json_line():
     assert out["filter_device_rate"] > 0
     assert out["filter_speedup"] > 0
     assert out["filter_cache_hit_rate"] > 0
-    # the megakernel comparison. The HARD contract is the dispatch count:
-    # a cold fused query is exactly ONE device dispatch, the staged path
-    # pays the bitmap fill wave too. The rate gate is a noise floor only:
-    # on shared-CI CPU the fill dispatch costs ~1% of a cold iteration, so
-    # strict fused ≥ staged ordering is within timing noise — the ordering
-    # is asserted on real hardware (BENCH_r*), the same discipline as the
-    # filter-bench fields above.
+    # the megakernel comparison. The contract is the dispatch count: a
+    # cold fused query is exactly ONE device dispatch, the staged path
+    # pays the bitmap fill wave too.
     assert out["fused_rate"] > 0
     assert out["staged_rate"] > 0
-    assert out["fused_rate"] >= 0.9 * out["staged_rate"]
     assert out["dispatch_count_fused"] == 1
     assert out["dispatch_count_staged"] >= 2
     assert out["donated_tick_rate"] > 0
@@ -124,7 +125,7 @@ def test_bench_exits_zero_with_one_json_line():
     assert 0 < out["wire_bytes_v2"] < out["wire_bytes_v1"]
     # the non-default-register sketch shape (log2m=12 rider)
     assert out["hll_log2m12_rate"] > 0
-    # the qtrace-overhead fields tracked across BENCH_r* runs
+    # the qtrace-overhead fields
     assert out["traced_rate"] > 0
     assert out["untraced_rate"] > 0
     # the concurrent-client scheduler comparison (contract only: this
@@ -156,13 +157,30 @@ def test_bench_exits_zero_with_one_json_line():
     assert isinstance(out["soak_rss_drift_kb"], int)
 
 
-def test_bench_falls_back_to_cpu_on_bad_backend():
-    """An unavailable accelerator backend must not zero the run: the bench
-    re-execs once on the CPU backend and still produces numbers."""
-    proc = _run_bench({"DRUID_TPU_BENCH_PLATFORM": "nosuchplatform"})
-    assert proc.returncode == 0, (
-        f"rc={proc.returncode}\nstderr:{proc.stderr[-2000:]}")
+def test_bench_fails_without_its_device():
+    """No TPU is an error, not a CPU run under the chip's name: with
+    JAX held to the CPU and no explicit platform pin — or with a platform
+    that does not exist — the bench exits non-zero and prints no number."""
+    for extra, drop in (({"JAX_PLATFORMS": "cpu"},
+                         ("DRUID_TPU_BENCH_PLATFORM",)),
+                        ({"DRUID_TPU_BENCH_PLATFORM": "nosuchplatform"}, ())):
+        proc = _run_bench(extra, drop=drop)
+        assert proc.returncode != 0, proc.stderr[-2000:]
+        assert proc.stdout.strip() == "", proc.stdout
+        assert "bench:" in proc.stderr
+
+
+def test_bench_section_failure_exits_nonzero():
+    """A section that raises keeps the ONE JSON line (with its *_error
+    field and every other section's numbers) and fails the process."""
+    # the segment-io section parses its row count first: a bad value makes
+    # exactly that section raise
+    proc = _run_bench({"DRUID_TPU_BENCH_SEGIO_ROWS": "not-a-number"})
+    assert proc.returncode != 0, proc.stderr[-2000:]
     lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
-    out = json.loads(lines[-1])
-    assert out["value"] > 0 and "error" not in out
-    assert "retrying once on the cpu backend" in proc.stderr
+    assert len(lines) == 1, lines
+    out = json.loads(lines[0])
+    assert out["segio_error"].startswith("ValueError")
+    assert [k for k in out if k.endswith("_error")] == ["segio_error"]
+    assert out["value"] > 0 and out["hll_log2m12_rate"] > 0
+    assert "segio_error" in proc.stderr

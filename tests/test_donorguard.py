@@ -505,12 +505,15 @@ def test_prefix_dispatch_shape_fires_read_after_donate_and_repark():
                         int(getattr(a, "nbytes", 0))
                         for a in carried) if donated else 0
                     try:
+                        _build_kernel_program(fn, arrays, aux,
+                                              tuple(carried))
                         counts, states, raw = fn(arrays, aux,
                                                  tuple(carried))
                     except BaseException:
-                        # the take popped ownership; a dispatch failure
-                        # (Mosaic compile error below) may have already
-                        # invalidated the donated buffers mid-flight, so
+                        # the take popped ownership; a failed dispatch may
+                        # have already invalidated the donated buffers
+                        # mid-flight (and a failed build latches the
+                        # program off, so its carries are dead), so
                         # discharge them explicitly — the pool's resident
                         # bytes stay truthful and the next tick rebuilds
                         # fresh zeros (donorguard take-without-repark)
@@ -565,12 +568,14 @@ def test_inline_platform_gate_mutation_fires():
 def test_missing_step0_init_mutation_fires():
     # break the PR 11 bit-identity discipline: the init block no longer
     # runs at grid step 0, so donated reuse replays stale aggregates
+    # (the kernel the donating megakernel program reaches lives in
+    # pallas_agg.grouped_reduce, shared with the staged strategy)
     sources = _mutate(
-        _tree_sources(), "druid_tpu/engine/megakernel.py",
+        _tree_sources(), "druid_tpu/engine/pallas_agg.py",
         "@pl.when(i == jnp.int32(0))",
         "@pl.when(i == jnp.int32(1))")
     data = _tree_findings(sources)
-    assert "druid_tpu/engine/megakernel.py" in data.get("carry-grid-init",
+    assert "druid_tpu/engine/pallas_agg.py" in data.get("carry-grid-init",
                                                         {})
 
 

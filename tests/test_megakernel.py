@@ -279,7 +279,7 @@ def test_mega_carry_ticks_no_pool_growth_and_parity(monkeypatch):
 
 
 def test_mega_carry_failed_dispatch_discards_ownership(monkeypatch):
-    """A dispatch failure AFTER the carry take (the Mosaic-compile window)
+    """A build failure AFTER the carry take (the Mosaic-compile window)
     must DISCARD the popped grids, not re-park them — donation may have
     invalidated the buffers mid-flight — and leave the pool's byte
     accounting truthful: resident bytes must equal the entries actually
@@ -314,10 +314,12 @@ def test_mega_carry_failed_dispatch_discards_ownership(monkeypatch):
                 if spec.strategy != "megakernel":
                     return fn
 
-                def boom(arrays, aux, carries=()):
-                    raise RuntimeError("synthetic Mosaic failure")
+                class Boom:
+                    # the BUILD fails (the one failure the latch catches)
+                    def lower(self, *a, **k):
+                        raise RuntimeError("synthetic Mosaic failure")
 
-                return boom
+                return Boom()
 
             monkeypatch.setattr(grouping, "_build_device_fn", broken_build)
             fallback = ex.run_json(q)       # fails mid-carry, falls back

@@ -308,11 +308,39 @@ def test_pallas_compile_failure_falls_back(monkeypatch):
         raise RuntimeError("Mosaic failed to compile TPU kernel")
     monkeypatch.setattr(pallas_agg, "pallas_reduce", boom)
     got = _run(segments, AGGS, ["dimA", "dimB"])
-    assert pallas_agg._BROKEN is not None
+    assert "Mosaic failed to compile" in pallas_agg.broken_reason()
     monkeypatch.setattr(pallas_agg, "_FORCE_INTERPRET", False)
     want = _run(segments, AGGS, ["dimA", "dimB"], force="mixed",
                 monkeypatch=monkeypatch)
     _compare(got, want)
+
+
+def test_pallas_run_failure_propagates_unlatched(monkeypatch):
+    """The latch covers the kernel's BUILD only: a program that built and
+    then fails while it RUNS raises to the caller and leaves pallas live —
+    a run-time fault must never be hidden behind an XLA re-run."""
+    from druid_tpu.engine import pallas_agg
+    segments = _gen(sort_by_dims=False)
+    monkeypatch.setattr(grouping, "PROJECTION_MIN_ROWS", 0)
+    monkeypatch.setattr(pallas_agg, "_FORCE_INTERPRET", True)
+    monkeypatch.setattr(pallas_agg, "_BROKEN", None)
+    monkeypatch.setattr(grouping, "_JIT_CACHE", collections.OrderedDict())
+    real_build = grouping._build_device_fn
+
+    class RunFails:
+        def __init__(self, fn):
+            self.lower = fn.lower           # builds fine
+
+        def __call__(self, *a, **k):
+            raise RuntimeError("device fault while running")
+
+    def build(spec, *a, **k):
+        fn = real_build(spec, *a, **k)
+        return RunFails(fn) if spec.strategy == "pallas" else fn
+    monkeypatch.setattr(grouping, "_build_device_fn", build)
+    with pytest.raises(RuntimeError, match="device fault while running"):
+        _run(segments, AGGS, ["dimA", "dimB"])
+    assert pallas_agg.broken_reason() is None
 
 
 def test_mm_double_sum_falls_back(monkeypatch):

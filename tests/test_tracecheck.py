@@ -672,13 +672,15 @@ MUTATIONS = {
         "preferred_element_type=jnp.int32)", "),",
         "preferred-element-type"),
     "mega-mask-tile-unaligned": (
-        # the megakernel's (1, 128) mask word tile: an unaligned last dim
-        # compiles on the interpreter but fails on-chip — lint must catch
-        "druid_tpu/engine/megakernel.py", "pl.BlockSpec((1, 128),",
-        "pl.BlockSpec((1, 120),", "pallas-tile-shape"),
+        # the megakernel's mask word tile (the shared kernel's first word
+        # tile): an unaligned last dim compiles on the interpreter but
+        # fails on-chip — lint must catch
+        "druid_tpu/engine/pallas_agg.py",
+        "pl.BlockSpec((WORD_TILE_ROWS, 128),",
+        "pl.BlockSpec((WORD_TILE_ROWS, 120),", "pallas-tile-shape"),
     "mega-key-sentinel-dtype": (
         # the in-kernel masked-key sentinel must stay the int32 identity
-        "druid_tpu/engine/megakernel.py", "kb, jnp.int32(2**31 - 1))",
+        "druid_tpu/engine/pallas_agg.py", "kb, jnp.int32(2**31 - 1))",
         "kb, jnp.float32(2**31 - 1))", "pallas-accum-dtype"),
 }
 
@@ -896,10 +898,10 @@ def test_real_tree_spec_literals_only_in_layout():
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
-# ---- pallas-accum-dtype: index-map i64 regression (BENCH_r04) -------------
+# ---- pallas-accum-dtype: index-map i64 regression -------------
 
 def test_untyped_index_map_constant_flagged():
-    """REGRESSION for the BENCH_r04 on-TPU break: the offending kernel
+    """REGRESSION for an on-TPU-only break: the offending kernel
     shape — a BlockSpec index_map returning a bare Python int — promotes
     that constant to i64 under the repo-global x64 flag, and Mosaic fails
     to legalize the lowered `func.return (i32, i64)`. The rule must flag
@@ -918,7 +920,7 @@ def test_untyped_index_map_constant_flagged():
     """
     hits = check_source(textwrap.dedent(src), PALLAS, cfg())
     matches = [f for f in hits if f.rule == "pallas-accum-dtype"]
-    assert matches, "the BENCH_r04 index-map shape must be flagged"
+    assert matches, "the untyped index-map shape must be flagged"
     assert any("i64" in f.message and "func.return" in f.message
                for f in matches)
 
@@ -1001,7 +1003,7 @@ def test_megakernel_full_program_shape_within_budget():
     """The megakernel's whole in/out spec shape — key tile + (1, 128) mask
     word tile + dense value tiles + packed word tiles + the full accum
     grids — must fit the VMEM budget with every dim statically bounded
-    (the gate that made the BENCH_r04 class unrepeatable covers the new
+    (the gate that made the index-map i64 class unrepeatable covers the new
     kernel too)."""
     src = """
     import jax

@@ -3,8 +3,8 @@
 The north star is a service absorbing heavy traffic for months: every
 thread, timer, executor, socket, file handle, HTTP server, temp dir and
 device-pool entry acquired per start()/query/stop() cycle must be provably
-released, or the process bleeds until a wedged run (the BENCH_r05 /
-MULTICHIP_r05 rc=124 shape) or an OOM. Every recent PR found this bug
+released, or the process bleeds until a run killed at its time limit or
+an OOM. Every recent PR found this bug
 class BY HAND — the FileEmitter handle leak, the devicepool finalizer
 self-deadlock, the emitter-vs-shutdown race, the stop() un-chaining bugs
 in both server types. leakguard closes the static-analysis triad's missing

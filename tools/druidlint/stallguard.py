@@ -533,8 +533,7 @@ def check_unbounded_blocking_call(ctx: ModuleContext) -> Iterable[Finding]:
     connect) reachable from an HTTP handler or a configured request root
     (`stallguard-request-roots`) parks with no timeout argument and no
     enclosing bounded-retry loop. One such park is one handler thread
-    gone for as long as the peer cares to stall — the exact failure mode
-    of the wedged-tunnel bench hangs. Bound the park with the query's
+    gone for as long as the peer cares to stall. Bound the park with the query's
     remaining budget (`deadline.clamp(...)`) or take a rationale
     suppression for parks that provably complete (e.g. `.result()` on an
     already-done future)."""

@@ -634,7 +634,7 @@ def check_pallas_accum_dtype(ctx: ModuleContext) -> Iterable[Finding]:
                           f"cannot lower 64-bit element types; widen "
                           f"outside the kernel (lo/hi limbs inside)")
 
-    # BENCH_r04 regression class: a BlockSpec index_map returning a BARE
+    # index-map i64 regression class: a BlockSpec index_map returning a BARE
     # Python int promotes to i64 under the repo-global x64 flag, and Mosaic
     # fails to legalize the lowered index map's mixed `func.return
     # (i32, i64)` — an on-TPU-only compile failure the CPU interpreter
@@ -656,7 +656,7 @@ def check_pallas_accum_dtype(ctx: ModuleContext) -> Iterable[Finding]:
                     r, f"untyped int constant {r.value} in a BlockSpec "
                        f"index_map — promotes to i64 under x64 and Mosaic "
                        f"fails to legalize the (i32, i64) func.return "
-                       f"(the BENCH_r04 on-TPU break); build it typed "
+                       f"(an on-TPU-only break); build it typed "
                        f"inside the lambda: jnp.int32({r.value})")
 
 
