@@ -50,6 +50,11 @@ from druid_tpu.utils.emitter import (QueryCountStatsMonitor,
                                      ServiceEmitter)
 
 
+#: response header carrying the spans the node finished AFTER it handed its
+#: collected spans to the payload (today: `datanode/encode`) — a JSON list
+LATE_SPANS_HEADER = "X-Druid-Late-Spans"
+
+
 class RemoteQueryError(RuntimeError):
     """A data node answered with a query error (HTTP 4xx/5xx). Distinct from
     ConnectionError on purpose: the broker retries unreachable servers on
@@ -129,8 +134,8 @@ class DataNodeServer:
                            json.dumps(body, default=_json_value).encode(),
                            headers=headers)
 
-            def _reply_bytes(self, data: bytes):
-                self._send(200, wire.CONTENT_TYPE, data)
+            def _reply_bytes(self, data: bytes, headers=None):
+                self._send(200, wire.CONTENT_TYPE, data, headers=headers)
 
             def _body(self) -> dict:
                 n = int(self.headers.get("Content-Length", 0))
@@ -196,9 +201,10 @@ class DataNodeServer:
                                      {"error": f"{type(e).__name__}: {e}"})
 
             def _run(self, payload, rows_mode: bool):
-                """Returns ((result, served), spans): the request's finished
-                qtrace spans ride back in the response so the broker can
-                assemble one end-to-end trace."""
+                """Returns ((result, served), root): the request's root
+                span (None untraced) — its finished spans (`collected()`)
+                ride back in the response so the broker can assemble one
+                end-to-end trace."""
                 query = query_from_json(payload["query"])
                 sids = payload.get("segments") or []
                 qid = query.context_map.get("queryId")
@@ -241,8 +247,7 @@ class DataNodeServer:
                                                           check=check)
                         check()
                     ok = True
-                    return out, (root.collected()
-                                 if root is not None else [])
+                    return out, root
                 finally:
                     if qid:
                         outer.query_manager.unregister(qid)
@@ -252,8 +257,25 @@ class DataNodeServer:
                         dataSource=query.datasource, type=query.query_type,
                         id=qid or "", success=str(ok).lower())
 
+            def _encoded(self, root, encode, **attrs):
+                """(body bytes, late-span headers) of one answer.
+                `datanode/encode` is timed AFTER the request's collected
+                spans were handed to the payload it encodes, so it cannot
+                ride in that payload: it goes in a response header,
+                written after encoding and before the body, and the
+                client ingests it like the payload's spans."""
+                spans = root.collected() if root is not None else []
+                with qtrace.late_span(root, "datanode/encode", sibling=True,
+                                      **attrs) as enc:
+                    data, facts = encode(spans)
+                    if enc is not None:
+                        enc.attrs.update(facts)
+                if enc is None:
+                    return data, None
+                return data, {LATE_SPANS_HEADER: json.dumps([enc.to_json()])}
+
             def _partials(self, payload):
-                (ap, served), spans = self._run(payload, rows_mode=False)
+                (ap, served), root = self._run(payload, rows_mode=False)
                 # the explicit wire half of the partial-result contract:
                 # requested-but-unserved ids (the broker degrades on them
                 # when the query allows partials)
@@ -264,16 +286,33 @@ class DataNodeServer:
                 ctx = (payload.get("query") or {}).get("context") or {}
                 compress = bool(payload.get("wireCompress")) \
                     and ctx.get("wireCompress", True) is not False
-                self._reply_bytes(wire.dumps_partials(ap, served,
-                                                      trace=spans,
-                                                      missing=missing,
-                                                      compress=compress))
+
+                def encode(spans):
+                    facts: dict = {}
+                    data = wire.dumps_partials(ap, served, trace=spans,
+                                               missing=missing,
+                                               compress=compress,
+                                               facts=facts)
+                    return data, facts
+
+                data, late = self._encoded(root, encode,
+                                           partials=len(ap.partials))
+                self._reply_bytes(data, headers=late)
 
             def _rows(self, payload):
-                (rows, served), spans = self._run(payload, rows_mode=True)
-                self._reply_json(200, {"rows": rows,
+                (rows, served), root = self._run(payload, rows_mode=True)
+
+                def encode(spans):
+                    data = json.dumps({"rows": rows,
                                        "served": sorted(served),
-                                       "trace": spans})
+                                       "trace": spans},
+                                      default=_json_value).encode()
+                    return data, {"logicalBytes": len(data),
+                                  "wireBytes": len(data),
+                                  "compressed": False}
+
+                data, late = self._encoded(root, encode, rows=len(rows))
+                self._send(200, "application/json", data, headers=late)
 
             def do_DELETE(self):
                 qid = cancel_path_id(self.path)
@@ -455,7 +494,8 @@ class RemoteDataNodeClient:
             try:
                 with urllib.request.urlopen(
                         req, timeout=max(0.1, deadline.remaining())) as r:
-                    return r.headers.get_content_type(), r.read()
+                    return (r.headers.get_content_type(), r.read(),
+                            r.headers.get(LATE_SPANS_HEADER))
             except urllib.error.HTTPError as e:
                 detail = e.read().decode(errors="replace")
                 if e.code == 429:
@@ -507,20 +547,41 @@ class RemoteDataNodeClient:
                 raise ConnectionError(
                     f"server [{self.name}] unreachable: {e}")
 
+    def _read(self, path: str, query: Query, segment_ids: Sequence[str]):
+        """`_post` under a `broker/node/read` span: request build, socket,
+        the node's whole run and `r.read()`. Late spans (the response
+        header) are ingested here like the payload's."""
+        with qtrace.span("broker/node/read") as sp:
+            ctype, data, late = self._post(path, query, segment_ids)
+            if sp is not None:
+                sp.attrs["bytes"] = len(data)
+        if late:
+            try:
+                self._ingest_trace(json.loads(late))
+            except ValueError:
+                pass     # a mangled header loses one span, not the answer
+        return ctype, data
+
     def run_partials(self, query: Query, segment_ids: Sequence[str]
                      ) -> Tuple[object, Set[str]]:
-        ctype, data = self._post("/druid/v2/partials", query, segment_ids)
+        ctype, data = self._read("/druid/v2/partials", query, segment_ids)
         if ctype != wire.CONTENT_TYPE:
             raise ConnectionError(
                 f"server [{self.name}] returned {ctype}, expected partials")
-        ap, served, spans = wire.loads_partials(data)
+        with qtrace.span("broker/node/decode", bytes=len(data)) as sp:
+            ap, served, spans = wire.loads_partials(data)
+            if sp is not None:
+                sp.attrs["partials"] = len(ap.partials)
         self._ingest_trace(spans)
         return ap, served
 
     def run_rows(self, query: Query, segment_ids: Sequence[str]
                  ) -> Tuple[List[dict], Set[str]]:
-        _, data = self._post("/druid/v2/rows", query, segment_ids)
-        out = json.loads(data)
+        _, data = self._read("/druid/v2/rows", query, segment_ids)
+        with qtrace.span("broker/node/decode", bytes=len(data)) as sp:
+            out = json.loads(data)
+            if sp is not None:
+                sp.attrs["rows"] = len(out["rows"])
         self._ingest_trace(out.get("trace"))
         return out["rows"], set(out["served"])
 

@@ -321,7 +321,8 @@ def rebuild_kernels(agg_jsons: Sequence[dict]):
 def dumps_partials(ap, served: Sequence[str] = (),
                    trace: Sequence[dict] = (),
                    missing: Sequence[str] = (),
-                   compress: bool = False) -> bytes:
+                   compress: bool = False,
+                   facts: Optional[dict] = None) -> bytes:
     """Serialize AggregatePartials (+ the served-segment-id set the node is
     acknowledging, and the node's finished trace spans — plain JSON dicts —
     so the broker can assemble one end-to-end trace per query; both ride in
@@ -333,7 +334,11 @@ def dumps_partials(ap, served: Sequence[str] = (),
 
     compress=True enables the bit-exact per-tensor wire encodings; emit
     it only for peers that advertised support ("wireCompress") — the
-    payload then carries wire version 2 when any tensor benefits."""
+    payload then carries wire version 2 when any tensor benefits.
+
+    `facts` (a dict the caller owns) receives what was encoded —
+    logicalBytes (raw tensor bytes), wireBytes (the whole body), compressed
+    — the attributes of the caller's `datanode/encode` span."""
     tt = _TensorTable()
     partials = []
     for p in ap.partials:
@@ -360,6 +365,9 @@ def dumps_partials(ap, served: Sequence[str] = (),
     version = VERSION_COMPRESSED if any_enc else VERSION
     body = MAGIC + struct.pack("<BI", version, len(hj)) + hj + payload
     _WIRE_STATS.record(logical, len(payload), any_enc)
+    if facts is not None:
+        facts.update(logicalBytes=int(logical), wireBytes=len(body),
+                     compressed=any_enc)
     return body
 
 

@@ -1034,7 +1034,8 @@ def _build_run_fn(dim_cols: Tuple, has_remap: Tuple, filter_node,
                                    num_total, it) for rk in rkernels)
         return counts, states
 
-    return jax.jit(fn)
+    from druid_tpu.engine.contracts import named_program
+    return jax.jit(named_program(fn, "run_domain_agg"))
 
 
 def run_domain_probe(segment, intervals, granularity, spec, kernels,
@@ -1265,7 +1266,8 @@ def try_run_domain(segment, intervals, granularity, spec, kernels, flt,
     from druid_tpu.obs.trace import span as trace_span
     from druid_tpu.obs.trace import span_when as trace_span_when
     with trace_span("engine/dispatch", strategy="runDomain",
-                    rows=segment.n_rows, runs=nr, compile=compiled), \
+                    rows=segment.n_rows, runs=nr, compile=compiled,
+                    program="run_domain_agg"), \
             trace_span_when(compiled, "engine/compile", kind="segment",
                             strategy="runDomain"):
         counts, states = fn(arrays, tuple(aux))

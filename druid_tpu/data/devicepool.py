@@ -35,6 +35,18 @@ from druid_tpu.utils.emitter import Monitor
 STACKED_KIND = "shardStack"
 
 
+#: entries built (pool misses) per thread — see thread_builds()
+_BUILDS = threading.local()
+
+
+def thread_builds() -> int:
+    """How many pool entries THIS thread has built so far (misses of
+    get_or_build on any pool). A caller brackets a phase with two reads to
+    learn how many arrays it built rather than found resident — the
+    `built` attribute of the `engine/filter/words` span."""
+    return getattr(_BUILDS, "n", 0)
+
+
 def _default_budget() -> int:
     # capacity bound only: the budget sizes the pool and its eviction,
     # it never reaches a traced program (catalog: live, no key_member)
@@ -319,6 +331,7 @@ class DeviceSegmentPool:
                 self._hits += 1
                 return hit[0]
             self._misses += 1
+        _BUILDS.n = getattr(_BUILDS, "n", 0) + 1
         # cold miss: the H2D staging cost a warm pool hides. The span times
         # the whole build (host prep + device_put) at its existing boundary
         with trace_span("pool/h2d",

@@ -41,12 +41,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from druid_tpu.data import cascade
+from druid_tpu.data.devicepool import entry_bytes
 from druid_tpu.data.segment import DEFAULT_ROW_ALIGN, Segment
 from druid_tpu.engine import filters as filters_mod
 from druid_tpu.engine import grouping
 from druid_tpu.engine.contracts import (BATCH_MAX_SEGMENT_ROWS,
                                         BATCH_MAX_SEGMENTS,
-                                        BATCH_MIN_SEGMENTS, BATCH_ROW_ALIGN)
+                                        BATCH_MIN_SEGMENTS, BATCH_ROW_ALIGN,
+                                        named_program, program_name)
 from druid_tpu.engine.filters import ConstNode
 from druid_tpu.engine.grouping import (GroupPlan, GroupSpec, KeyDim,
                                        SegmentPartial, assemble_stacked_aux,
@@ -416,7 +418,8 @@ def _build_batched_fn(spec: GroupSpec, kds: Tuple[KeyDim, ...], filter_node,
         return tuple(body(blocks[i], time0s[i], iv_rel[i], bucket_off[i], aux)
                      for i in range(K))
 
-    return jax.jit(fn)
+    return jax.jit(named_program(fn, program_name("batch_agg",
+                                                  spec.strategy)))
 
 
 def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
@@ -463,8 +466,9 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
     # path; each plan stages ITS OWN words — query filter AND filtered
     # aggregators — so chunk-mates from different queries may carry
     # entirely different bitmap filters under one shared program structure)
-    bmp_per_slot = filters_mod.stage_device_bitmaps_multi(
-        [(p.segment, p.filter_node, p.kernels) for p in chunk], R)
+    with filters_mod.words_span(segments=K):
+        bmp_per_slot = filters_mod.stage_device_bitmaps_multi(
+            [(p.segment, p.filter_node, p.kernels) for p in chunk], R)
     arrs_per_slot = []
     for p, b, bmp in zip(chunk, blocks, bmp_per_slot):
         arrs = dict(b.arrays)
@@ -514,7 +518,8 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
 
     from druid_tpu.obs import dispatch as dispatch_mod
     with trace_span("engine/batch/dispatch", strategy=strategy, segments=K,
-                    rows=R, compile=compiled), \
+                    rows=R, compile=compiled,
+                    program=program_name("batch_agg", strategy)), \
             trace_span_when(compiled, "engine/compile", kind="batched",
                             strategy=strategy):
         outs = fn(tuple(arrs_per_slot), time0s, iv_rel,
@@ -524,14 +529,19 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
     dispatch_mod.record("batched")
 
     out: List[SegmentPartial] = []
-    for p, (counts, states) in zip(chunk, outs):
-        states_h = jax.tree.map(lambda x: np.asarray(x), states)
-        host_states = {k.name: k.host_post(s, p.segment)
-                       for k, s in zip(p.kernels, states_h)}
-        out.append(SegmentPartial(
-            segment=p.segment, spec=p.spec,
-            counts=np.asarray(counts, dtype=np.int64),
-            states=host_states, kernels=p.kernels))
+    # engine/fetch: the host conversion is where this thread blocks for the
+    # enqueued program (wait-for-device + D2H + host_post) — no added sync
+    with trace_span("engine/fetch", segments=K) as fetch_span:
+        if fetch_span is not None:
+            fetch_span.attrs["bytes"] = entry_bytes(outs)
+        for p, (counts, states) in zip(chunk, outs):
+            states_h = jax.tree.map(lambda x: np.asarray(x), states)
+            host_states = {k.name: k.host_post(s, p.segment)
+                           for k, s in zip(p.kernels, states_h)}
+            out.append(SegmentPartial(
+                segment=p.segment, spec=p.spec,
+                counts=np.asarray(counts, dtype=np.int64),
+                states=host_states, kernels=p.kernels))
     _STATS.record_batch(K, sum(p.segment.n_rows for p in chunk), K * R)
     return out
 

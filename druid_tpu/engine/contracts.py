@@ -214,6 +214,52 @@ def donation_supported() -> bool:
         return False
 
 
+# ---- program and kernel names ---------------------------------------------
+
+#: reduction strategies a device program is BUILT for (grouping.
+#: select_strategy / _projection_strategy; "projection" is resolved to one of
+#: these before any program exists)
+STRATEGIES = ("mm", "blocked", "mixed", "windowed", "pallas", "megakernel")
+
+#: aggregation program families: per-segment (engine/grouping.py), batched
+#: multi-segment (engine/batching.py), sharded mesh (parallel/distributed.py)
+AGG_PROGRAM_FAMILIES = ("seg_agg", "batch_agg", "sharded_agg")
+
+#: THE closed set of names a jitted query-path callable may carry (its
+#: `__name__`, hence the `jit_<name>` module the profiler records and the
+#: dispatch spans' `program` attribute). Chosen by path and strategy only:
+#: never a shape, a segment id or a digest, so kernel time sums by a stable
+#: name across segments, queries and processes. Listed in PERF.md §3.
+PROGRAM_NAMES = frozenset(
+    [f"{family}_{strategy}" for family in AGG_PROGRAM_FAMILIES
+     for strategy in STRATEGIES]
+    + ["run_domain_agg",        # data/cascade.py code-domain program
+       "bitmap_fill",           # engine/filters.py one filter's fill
+       "bitmap_fill_wave"])     # engine/filters.py a staging wave's fill
+
+#: `pl.pallas_call` names (engine/pallas_agg.py grouped_reduce): the sorted
+#: projection's group reduce, and its megakernel variant that takes the
+#: filter mask as words
+PALLAS_KERNEL_NAMES = ("proj_group_reduce", "proj_group_reduce_mega")
+
+
+def program_name(family: str, strategy: str) -> str:
+    """`<family>_<strategy>`, refused unless it is in PROGRAM_NAMES."""
+    name = f"{family}_{strategy}"
+    if name not in PROGRAM_NAMES:
+        raise ValueError(f"no program name for {family!r} × {strategy!r}")
+    return name
+
+
+def named_program(fn, name: str):
+    """`fn` carrying `name` (one of PROGRAM_NAMES) as its `__name__`, for
+    `jax.jit` to name the module after."""
+    if name not in PROGRAM_NAMES:
+        raise ValueError(f"{name!r} is not a documented program name")
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 # ---- dtype lattice --------------------------------------------------------
 
 DTYPE_BYTES = {

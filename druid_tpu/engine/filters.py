@@ -23,6 +23,7 @@ TPU-first design:
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import hashlib
 import os
@@ -35,7 +36,10 @@ import numpy as np
 from druid_tpu.data.bitmap import (AnyBitmap, Bitmap, SparseBitmap,
                                    bitmap_and, bitmap_or, device_repr)
 from druid_tpu.data.dictionary import Dictionary, merge_dictionaries
+from druid_tpu.data.devicepool import thread_builds
 from druid_tpu.data.segment import Segment, ValueType
+from druid_tpu.engine.contracts import named_program
+from druid_tpu.obs.trace import span as trace_span
 from druid_tpu.query import filters as F
 from druid_tpu.utils.emitter import Monitor
 from druid_tpu.utils.expression import parse_expression
@@ -971,8 +975,11 @@ def _eval_structure(structure, kinds: Tuple, leaves: Tuple, Rw: int):
 def _build_fill_fn(structure, kinds: Tuple, Rw: int):
     """One filter's fill program (unit-testable single case)."""
     import jax
-    return jax.jit(lambda leaves: _eval_structure(structure, kinds, leaves,
-                                                  Rw))
+
+    def fn(leaves):
+        return _eval_structure(structure, kinds, leaves, Rw)
+
+    return jax.jit(named_program(fn, "bitmap_fill"))
 
 
 def _build_fill_multi(structures: Tuple, kinds_per: Tuple, Rw: int):
@@ -987,7 +994,7 @@ def _build_fill_multi(structures: Tuple, kinds_per: Tuple, Rw: int):
         return tuple(_eval_structure(s, k, l, Rw)
                      for s, k, l in zip(structures, kinds_per, leaves_per))
 
-    return jax.jit(fn)
+    return jax.jit(named_program(fn, "bitmap_fill_wave"))
 
 
 def _leaf_digest(lut: np.ndarray) -> str:
@@ -1074,6 +1081,19 @@ def _item_nodes(filter_node: Optional[FilterNode],
         for tree in k.filter_trees():
             nodes.extend(collect_bitmap_nodes(tree))
     return nodes
+
+
+@contextlib.contextmanager
+def words_span(**attrs):
+    """The `engine/filter/words` span every execution path opens around
+    its staging of filter words (and grouping around the megakernel
+    conversion that decides WHICH words stage). `built` = pool entries
+    this thread built inside it rather than found resident."""
+    with trace_span("engine/filter/words", **attrs) as sp:
+        built0 = thread_builds()
+        yield
+        if sp is not None:
+            sp.attrs["built"] = thread_builds() - built0
 
 
 def stage_device_bitmaps_multi(items: Sequence[Tuple],

@@ -43,6 +43,7 @@ import logging
 import math
 import os
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -51,8 +52,10 @@ import numpy as np
 from druid_tpu.data import cascade as cascade_mod
 from druid_tpu.data import packed as packed_mod
 from druid_tpu.data.segment import DeviceBlock, Segment
+from druid_tpu.data.devicepool import entry_bytes
 from druid_tpu.engine import filters as filters_mod
 from druid_tpu.engine import megakernel, pallas_agg
+from druid_tpu.engine.contracts import named_program, program_name
 from druid_tpu.engine.filters import (ConstNode, FilterNode, plan_filter,
                                       simplify_node)
 from druid_tpu.obs import dispatch as dispatch_mod
@@ -181,6 +184,12 @@ def build_projection(segment: Segment, intervals: Sequence[Interval],
                        for d in spec.dims))
 
     def _compute():
+        # runs only when the projection is BUILT (a miss of the segment's
+        # aux cache): the sort that dominates a cold process's set-up
+        with trace_span("engine/projection/build", rows=segment.n_rows):
+            return _sort_projection()
+
+    def _sort_projection():
         raw = _fused_raw_keys(segment, spec.bucket_mode, spec.bucket_starts,
                               spec.uniform_period, spec.num_buckets,
                               spec.host_bucket_ids, spec.dims)
@@ -842,6 +851,9 @@ def _build_device_fn(spec: GroupSpec, n_intervals: int,
                                   window=spec.window,
                                   packed_cols=packed_cols or None)
 
+    # the program's stable name: the profiler's modules and the dispatch
+    # spans' `program` read `seg_agg_<strategy>`, never a shape
+    named_program(fn, program_name("seg_agg", spec.strategy))
     if spec.strategy == "megakernel":
         if megakernel.donation_enabled():
             return jax.jit(fn, keep_unused=True, donate_argnums=(2,))
@@ -1086,110 +1098,171 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
     """Execute the grouped aggregation for one segment; returns host
     partials. `plan` (a GroupPlan from plan_grouped_aggregate over the SAME
     arguments) skips re-planning — the batched path's straggler fallback
-    passes the plan it already built for bucket grouping."""
+    passes the plan it already built for bucket grouping.
+
+    Traced, the segment's time lies under one `engine/segment` span whose
+    children are consecutive phases (at most 8 spans a warm segment):
+    `engine/plan` (group spec, the code-domain probe, strategy, projection
+    lookup — with an `engine/projection/build` child when the projection
+    is built),
+    `engine/filter/words` (megakernel conversion, then the staging of
+    filter words; `built` = arrays built rather than found resident),
+    `engine/stage` (columns and derived keys; `pool/h2d` nests here),
+    `engine/build` (aux, signature, program cache, the kernel build),
+    `engine/dispatch` (the ENQUEUE: dispatch is asynchronous) and
+    `engine/fetch` (see `_fetch_partial`: the wait for the device)."""
+    with trace_span("engine/segment", rows=segment.n_rows) as seg_span:
+        partial, strategy = _run_grouped_aggregate(
+            segment, intervals, granularity, dims, aggs, flt, extra_columns,
+            virtual_columns, plan)
+        if seg_span is not None:
+            # formatted only when traced: untraced segments pay no str()
+            seg_span.attrs.update(segment=str(segment.id), strategy=strategy)
+        return partial
+
+
+def _fetch_partial(segment: Segment, spec: "GroupSpec",
+                   kernels: List[AggKernel], counts, states
+                   ) -> SegmentPartial:
+    """Device results → host partial, under `engine/fetch`. This is where
+    the host already blocks for the device (`np.asarray` of an enqueued
+    program's outputs), so the span adds no sync: its duration is
+    wait-for-device plus D2H plus the kernels' host_post. `bytes` is what
+    comes back."""
+    with trace_span("engine/fetch") as sp:
+        if sp is not None:
+            sp.attrs["bytes"] = entry_bytes((counts, states))
+        host_states = {k.name: k.host_post(st, segment)
+                       for k, st in zip(kernels, states)}
+        return SegmentPartial(segment=segment, spec=spec,
+                              counts=np.asarray(counts, dtype=np.int64),
+                              states=host_states, kernels=kernels)
+
+
+def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
+                           extra_columns, virtual_columns, plan):
+    """run_grouped_aggregate's body: (partial, the strategy that ran)."""
     from druid_tpu.utils.expression import parse_expression
 
-    if plan is None:
-        plan = plan_grouped_aggregate(segment, intervals, granularity, dims,
-                                      aggs, flt, virtual_columns)
-    spec = plan.spec
-    filter_node = plan.filter_node
-    kernels = plan.kernels
-    vc_plans, vc_luts = plan.vc_plans, plan.vc_luts
+    run_domain = False
+    with trace_span("engine/plan") as plan_span:
+        if plan is None:
+            plan = plan_grouped_aggregate(segment, intervals, granularity,
+                                          dims, aggs, flt, virtual_columns)
+        spec = plan.spec
+        filter_node = plan.filter_node
+        kernels = plan.kernels
+        vc_plans, vc_luts = plan.vc_plans, plan.vc_luts
 
-    if isinstance(filter_node, ConstNode) and not filter_node.value:
-        # constant-false filter: nothing matches — skip the device entirely
-        return SegmentPartial(
-            segment=segment, spec=spec,
-            counts=np.zeros(spec.num_total, dtype=np.int64),
-            states={k.name: k.empty_state(spec.num_total) for k in kernels},
-            kernels=kernels)
+        if isinstance(filter_node, ConstNode) and not filter_node.value:
+            # constant-false filter: nothing matches — skip the device
+            return SegmentPartial(
+                segment=segment, spec=spec,
+                counts=np.zeros(spec.num_total, dtype=np.int64),
+                states={k.name: k.empty_state(spec.num_total)
+                        for k in kernels},
+                kernels=kernels), "constFalse"
 
-    # code-domain fast path (data/cascade.py): when every referenced
-    # column is constant within one shared run partition and the query
-    # shape allows it, the whole aggregation executes over run metadata —
-    # no row-width column stages, nothing decodes, and the results are
-    # bit-identical to the row program (exact int arithmetic, identical
-    # identities). batching._plan_for routes eligible segments here.
-    if cascade_mod.enabled():
-        rd = cascade_mod.try_run_domain(segment, intervals, granularity,
-                                        spec, kernels, flt, virtual_columns)
-        if rd is not None:
-            counts, states = rd
-            host_states = {k.name: k.host_post(st, segment)
-                           for k, st in zip(kernels, states)}
-            return SegmentPartial(segment=segment, spec=spec,
-                                  counts=np.asarray(counts, dtype=np.int64),
-                                  states=host_states, kernels=kernels)
+        # code-domain fast path (data/cascade.py): when every referenced
+        # column is constant within one shared run partition and the query
+        # shape allows it, the whole aggregation executes over run metadata
+        # — no row-width column stages, nothing decodes, and the results
+        # are bit-identical to the row program (exact int arithmetic,
+        # identical identities). batching._plan_for routes eligible
+        # segments here. The probe plans it (memoized on the spec); on a
+        # cold segment it computes the columns' run tables, seconds of
+        # set-up a 5M-row segment: the span says how long (`runDomainMs`)
+        if cascade_mod.enabled():
+            t0 = time.monotonic()
+            run_domain = cascade_mod.run_domain_probe(
+                segment, intervals, granularity, spec, kernels, flt,
+                virtual_columns)
+            if plan_span is not None:
+                plan_span.attrs["runDomainMs"] = round(
+                    (time.monotonic() - t0) * 1000.0, 3)
 
-    vc_names = {v.name for v in virtual_columns}
-    base_needed = set(extra_columns)
-    if filter_node is not None:
-        # the PLANNED tree's column needs, not the raw filter's: subtrees
-        # compiled to device bitmaps read resident words, not columns
-        base_needed |= filter_node.required_device_columns()
-    for a, k in zip(aggs, kernels):
-        # the PLANNED kernel's needs where narrower: a filtered agg whose
-        # filter compiled to bitmap words reads words, not filter columns
-        kc = k.required_device_columns()
-        base_needed |= a.required_columns() if kc is None else kc
-    for v in virtual_columns:
-        base_needed |= parse_expression(v.expression).required_columns()
-    base_needed -= vc_names
-    base_needed = {c for c in base_needed
-                   if c in segment.dims or c in segment.metrics}
-    needed = set(base_needed)
-    for d in spec.dims:
-        if spec.key_mode == "dense" and d.column is not None \
-                and d.host_ids is None:
-            needed.add(d.column)
+        if not run_domain:
+            vc_names = {v.name for v in virtual_columns}
+            base_needed = set(extra_columns)
+            if filter_node is not None:
+                # the PLANNED tree's column needs, not the raw filter's:
+                # subtrees compiled to device bitmaps read resident words, not
+                # columns
+                base_needed |= filter_node.required_device_columns()
+            for a, k in zip(aggs, kernels):
+                # the PLANNED kernel's needs where narrower: a filtered agg
+                # whose filter compiled to bitmap words reads words, not filter
+                # columns
+                kc = k.required_device_columns()
+                base_needed |= a.required_columns() if kc is None else kc
+            for v in virtual_columns:
+                base_needed |= parse_expression(
+                    v.expression).required_columns()
+            base_needed -= vc_names
+            base_needed = {c for c in base_needed
+                           if c in segment.dims or c in segment.metrics}
+            needed = set(base_needed)
+            for d in spec.dims:
+                if spec.key_mode == "dense" and d.column is not None \
+                        and d.host_ids is None:
+                    needed.add(d.column)
 
-    # strategy BEFORE staging: the projection path stages a permuted layout,
-    # so dtypes come from staged_dtype, not from a staged block
-    from druid_tpu.data.segment import DEFAULT_ROW_ALIGN
-    padded_rows = max(DEFAULT_ROW_ALIGN,
-                      -(-segment.n_rows // DEFAULT_ROW_ALIGN)
-                      * DEFAULT_ROW_ALIGN)
-    col_dtypes = {"__time_offset": np.dtype(np.int32),
-                  "__valid": np.dtype(bool)}
-    for c in needed:
-        col_dtypes[c] = np.dtype(np.int32) if c in segment.dims \
-            else np.dtype(segment.staged_dtype(c))
-    if spec.key_mode == "dense":
-        for d in spec.dims:
-            if d.host_ids is not None:
-                col_dtypes[d.column] = np.dtype(np.int32)
-    if spec.key_mode == "host":
-        col_dtypes["__key"] = np.dtype(np.int32)
-    elif spec.bucket_mode == "host":
-        col_dtypes["__bucket"] = np.dtype(np.int32)
-    spec.strategy, spec.window = select_strategy(
-        spec, kernels, col_dtypes, padded_rows,
-        lambda: windowed_window(segment, intervals, granularity, spec))
+            # strategy BEFORE staging: the projection path stages a permuted
+            # layout, so dtypes come from staged_dtype, not from a staged block
+            from druid_tpu.data.segment import DEFAULT_ROW_ALIGN
+            padded_rows = max(DEFAULT_ROW_ALIGN,
+                              -(-segment.n_rows // DEFAULT_ROW_ALIGN)
+                              * DEFAULT_ROW_ALIGN)
+            col_dtypes = {"__time_offset": np.dtype(np.int32),
+                          "__valid": np.dtype(bool)}
+            for c in needed:
+                col_dtypes[c] = np.dtype(np.int32) if c in segment.dims \
+                    else np.dtype(segment.staged_dtype(c))
+            if spec.key_mode == "dense":
+                for d in spec.dims:
+                    if d.host_ids is not None:
+                        col_dtypes[d.column] = np.dtype(np.int32)
+            if spec.key_mode == "host":
+                col_dtypes["__key"] = np.dtype(np.int32)
+            elif spec.bucket_mode == "host":
+                col_dtypes["__bucket"] = np.dtype(np.int32)
+            spec.strategy, spec.window = select_strategy(
+                spec, kernels, col_dtypes, padded_rows,
+                lambda: windowed_window(segment, intervals, granularity, spec))
 
-    perm, perm_key = None, None
-    if spec.strategy == "projection":
-        proj = build_projection(segment, intervals, granularity, spec)
-        spec.key_mode = "host"
-        spec.host_keys = proj.keys
-        spec.host_unique = proj.unique
-        spec.num_total = pad_pow2(max(len(proj.unique), 1))
-        col_dtypes.pop("__bucket", None)
-        col_dtypes["__key"] = np.dtype(np.int32)
-        spec.strategy, spec.window = _projection_strategy(
-            proj, kernels, col_dtypes, spec.num_total)
-        perm = proj.order
-        perm_key = ("projection", str(granularity),
-                    tuple((iv.start, iv.end) for iv in intervals),
-                    tuple((d.column, d.cardinality,
-                           None if d.remap is None else d.remap.tobytes())
-                          for d in spec.dims))
-        spec.host_keys_cache = perm_key
-        needed = base_needed  # key prefused: dim columns stay host-side
-        # bitmap subtrees STAY on the words path: the projection's permuted
-        # row layout stages its own words under a permutation-digest pool
-        # key (filters.bitmap_pool_key), so the bit test aligns with the
-        # permuted columns instead of forcing a column-path re-plan
+            perm, perm_key = None, None
+            if spec.strategy == "projection":
+                proj = build_projection(segment, intervals, granularity, spec)
+                spec.key_mode = "host"
+                spec.host_keys = proj.keys
+                spec.host_unique = proj.unique
+                spec.num_total = pad_pow2(max(len(proj.unique), 1))
+                col_dtypes.pop("__bucket", None)
+                col_dtypes["__key"] = np.dtype(np.int32)
+                spec.strategy, spec.window = _projection_strategy(
+                    proj, kernels, col_dtypes, spec.num_total)
+                perm = proj.order
+                perm_key = ("projection", str(granularity),
+                            tuple((iv.start, iv.end) for iv in intervals),
+                            tuple((d.column, d.cardinality,
+                                   None if d.remap is None
+                                   else d.remap.tobytes())
+                                  for d in spec.dims))
+                spec.host_keys_cache = perm_key
+                # key prefused: dim columns stay host-side
+                needed = base_needed
+                # bitmap subtrees STAY on the words path: the projection's
+                # permuted row layout stages its own words under a
+                # permutation-digest pool key (filters.bitmap_pool_key), so the
+                # bit test aligns with the permuted columns instead of forcing
+                # a column-path re-plan
+
+    if run_domain:
+        counts, states = cascade_mod.try_run_domain(
+            segment, intervals, granularity, spec, kernels, flt,
+            virtual_columns)
+        return _fetch_partial(segment, spec, kernels, counts,
+                              states), "runDomain"
 
     # megakernel conversion (engine/megakernel.py): bitmap subtrees whose
     # combined words are not already resident fuse INLINE — per-leaf words
@@ -1198,52 +1271,60 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
     # keep the cached bit-test path (also one dispatch). Opt-out:
     # DRUID_TPU_MEGAKERNEL=0.
     pdg = filters_mod.perm_digest(perm_key)
-    if megakernel.enabled():
-        filter_node = megakernel.megaize(filter_node, segment, padded_rows,
-                                         pdg)
-        megakernel.megaize_kernels(kernels, segment, padded_rows, pdg)
-    else:
-        megakernel.record_disabled_fallback(filter_node, kernels)
+    with filters_mod.words_span():
+        # conversion only: nothing stages here, the words stage below
+        if megakernel.enabled():
+            filter_node = megakernel.megaize(filter_node, segment,
+                                             padded_rows, pdg)
+            megakernel.megaize_kernels(kernels, segment, padded_rows, pdg)
+        else:
+            megakernel.record_disabled_fallback(filter_node, kernels)
 
-    # cascade + pack descriptors of the staged column set: must be derived
-    # IDENTICALLY to device_block's own planning (cascade.plan_pair, the
-    # one shared derivation), and both join the jit-cache signature — a
-    # cascade-encoded, packed, and decoded staging of the same structure
-    # are different programs
-    cascades, packs = cascade_mod.plan_pair(segment, sorted(needed),
-                                            permuted=perm is not None)
-    block = segment.device_block(sorted(needed), perm=perm, perm_key=perm_key)
+    with trace_span("engine/stage"):
+        # cascade + pack descriptors of the staged column set: must be
+        # derived IDENTICALLY to device_block's own planning
+        # (cascade.plan_pair, the one shared derivation), and both join the
+        # jit-cache signature — a cascade-encoded, packed, and decoded
+        # staging of the same structure are different programs
+        cascades, packs = cascade_mod.plan_pair(segment, sorted(needed),
+                                                permuted=perm is not None)
+        block = segment.device_block(sorted(needed), perm=perm,
+                                     perm_key=perm_key)
 
-    arrays = dict(block.arrays)
-    if spec.key_mode == "dense":
-        for d in spec.dims:
-            if d.host_ids is not None:
-                # derived id column (numeric dimension): staged via the
-                # bounded device cache like any other derived key column
-                arrays[d.column] = _pad_device_cached(
-                    segment, d.ids_key, d.host_ids, block.padded_rows, 0)
-    if spec.key_mode == "host":
-        # derived projection keys ride the cascade FOR rung: their value
-        # range [-1, num_total) is known exactly, so they range-pack at
-        # plan-determined width (data/cascade.for_encode_derived)
-        arrays["__key"] = _pad_device_cached(
-            segment, spec.host_keys_cache, spec.host_keys,
-            block.padded_rows, -1, value_range=(-1, spec.num_total - 1))
-    elif spec.bucket_mode == "host":
-        arrays["__bucket"] = _pad_device_cached(
-            segment, spec.host_bucket_cache, spec.host_bucket_ids,
-            block.padded_rows, -1, value_range=(-1, spec.num_buckets - 1))
-    # resident filter-bitmap words (engine/filters.py device-bitmap path):
-    # cached per (segment, filter structure, aux digest, permutation
-    # digest) in the same pool; filtered-aggregator trees stage alongside
-    # the query filter's, and the projection path stages PERMUTED words
-    arrays.update(filters_mod.stage_device_bitmaps(
-        segment, filter_node, block.padded_rows, kernels=kernels,
-        perm=perm, perm_key=perm_key))
-    # per-leaf mask words for inline-fused (mega) subtrees
-    arrays.update(megakernel.stage_mega_leaves(
-        segment, filter_node, kernels, block.padded_rows,
-        perm=perm, perm_key=perm_key))
+        arrays = dict(block.arrays)
+        if spec.key_mode == "dense":
+            for d in spec.dims:
+                if d.host_ids is not None:
+                    # derived id column (numeric dimension): staged via the
+                    # bounded device cache like any other derived key column
+                    arrays[d.column] = _pad_device_cached(
+                        segment, d.ids_key, d.host_ids, block.padded_rows, 0)
+        if spec.key_mode == "host":
+            # derived projection keys ride the cascade FOR rung: their
+            # value range [-1, num_total) is known exactly, so they
+            # range-pack at plan-determined width
+            # (data/cascade.for_encode_derived)
+            arrays["__key"] = _pad_device_cached(
+                segment, spec.host_keys_cache, spec.host_keys,
+                block.padded_rows, -1, value_range=(-1, spec.num_total - 1))
+        elif spec.bucket_mode == "host":
+            arrays["__bucket"] = _pad_device_cached(
+                segment, spec.host_bucket_cache, spec.host_bucket_ids,
+                block.padded_rows, -1,
+                value_range=(-1, spec.num_buckets - 1))
+    with filters_mod.words_span():
+        # resident filter-bitmap words (engine/filters.py device-bitmap
+        # path): cached per (segment, filter structure, aux digest,
+        # permutation digest) in the same pool; filtered-aggregator trees
+        # stage alongside the query filter's, and the projection path
+        # stages PERMUTED words
+        arrays.update(filters_mod.stage_device_bitmaps(
+            segment, filter_node, block.padded_rows, kernels=kernels,
+            perm=perm, perm_key=perm_key))
+        # per-leaf mask words for inline-fused (mega) subtrees
+        arrays.update(megakernel.stage_mega_leaves(
+            segment, filter_node, kernels, block.padded_rows,
+            perm=perm, perm_key=perm_key))
 
     # the fused pallas variant: when the projection strategy landed on the
     # pallas kernel AND the tree carries top-level AND-conjunct mega nodes,
@@ -1253,39 +1334,48 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
             and megakernel.split_for_kernel(filter_node)[0]:
         spec.strategy = "megakernel"
 
-    aux = _assemble_aux(spec, segment, intervals, filter_node, kernels,
-                        vc_plans, vc_luts)
+    aux = None
     while True:
-        sig = _structure_sig(spec, len(intervals), filter_node, kernels,
-                             vc_plans, packs, cascades)
-        if spec.strategy == "megakernel":
-            # donation changes the jit construction (donate_argnums) and
-            # the carry handoff changes the carries treedef (empty vs full
-            # tuple), so both key the program cache; carry buffers key off
-            # the same sig
-            sig += f"|mk={int(megakernel.donation_enabled())}" \
-                f"{int(megakernel.carry_enabled())}"
-        with _JIT_CACHE_LOCK:
-            fn = _JIT_CACHE.get(sig)
-            # the builder-idiom miss IS the compile event: jit tracing +
-            # XLA compilation happen inside the first call below, so the
-            # dispatch span (and, on miss, the nested engine/compile span)
-            # time the existing dispatch boundary — no extra syncs
-            compiled = fn is None
-            if fn is None:
-                fn = _build_device_fn(spec, len(intervals), filter_node,
-                                      kernels, vc_plans)
-                _JIT_CACHE[sig] = fn
-                while len(_JIT_CACHE) > _JIT_CACHE_CAP:
-                    _JIT_CACHE.popitem(last=False)
-            else:
-                _JIT_CACHE.move_to_end(sig)
+        # pallas-class programs are BUILT before they run (the latch below
+        # catches exactly the build's failures); XLA strategies compile
+        # inside their first call
+        kernel_class = spec.strategy in ("pallas", "megakernel")
+        program = program_name("seg_agg", spec.strategy)
+        carried, donated, donated_nbytes = None, False, 0
         try:
-            with trace_span("engine/dispatch", strategy=spec.strategy,
-                            rows=segment.n_rows, compile=compiled), \
-                    trace_span_when(compiled, "engine/compile",
-                                    kind="segment",
-                                    strategy=spec.strategy):
+            with trace_span("engine/build", program=program) as build_span:
+                if aux is None:
+                    aux = _assemble_aux(spec, segment, intervals,
+                                        filter_node, kernels, vc_plans,
+                                        vc_luts)
+                sig = _structure_sig(spec, len(intervals), filter_node,
+                                     kernels, vc_plans, packs, cascades)
+                if spec.strategy == "megakernel":
+                    # donation changes the jit construction
+                    # (donate_argnums) and the carry handoff changes the
+                    # carries treedef (empty vs full tuple), so both key
+                    # the program cache; carry buffers key off the same sig
+                    sig += f"|mk={int(megakernel.donation_enabled())}" \
+                        f"{int(megakernel.carry_enabled())}"
+                with _JIT_CACHE_LOCK:
+                    fn = _JIT_CACHE.get(sig)
+                    # the builder-idiom miss IS the compile event: a
+                    # pallas-class program traces, lowers and compiles in
+                    # _build_kernel_program (here, under engine/build), an
+                    # XLA-strategy one inside its first call (under
+                    # engine/dispatch). The engine/compile span nests
+                    # where the compile happens — no extra syncs
+                    compiled = fn is None
+                    if fn is None:
+                        fn = _build_device_fn(spec, len(intervals),
+                                              filter_node, kernels, vc_plans)
+                        _JIT_CACHE[sig] = fn
+                        while len(_JIT_CACHE) > _JIT_CACHE_CAP:
+                            _JIT_CACHE.popitem(last=False)
+                    else:
+                        _JIT_CACHE.move_to_end(sig)
+                if build_span is not None:
+                    build_span.attrs["compile"] = compiled
                 if spec.strategy == "megakernel" \
                         and megakernel.carry_enabled():
                     # donated-carry handoff: the previous execution's raw
@@ -1323,19 +1413,47 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                     donated_nbytes = sum(
                         int(getattr(a, "nbytes", 0))
                         for a in carried) if donated else 0
+                if kernel_class:
+                    with trace_span_when(compiled, "engine/compile",
+                                         kind="segment",
+                                         strategy=spec.strategy):
+                        if carried is not None:
+                            try:
+                                _build_kernel_program(fn, arrays, aux,
+                                                      tuple(carried))
+                            except BaseException:
+                                # a failed build latches the program off,
+                                # so its carries are dead: discharge the
+                                # ownership the take popped
+                                megakernel.discard_carries(carried)
+                                raise
+                        elif spec.strategy == "megakernel":
+                            # no donation support: parking grids in the
+                            # budgeted pool would only evict useful
+                            # entries — run carryless
+                            _build_kernel_program(fn, arrays, aux, ())
+                        else:
+                            _build_kernel_program(fn, arrays, aux)
+            # the ENQUEUE: dispatch is asynchronous, so this span closes
+            # when the program is queued; engine/fetch (below) times its
+            # finish, where the host blocks for the results anyway
+            with trace_span("engine/dispatch", strategy=spec.strategy,
+                            rows=segment.n_rows, compile=compiled,
+                            program=program), \
+                    trace_span_when(compiled and not kernel_class,
+                                    "engine/compile", kind="segment",
+                                    strategy=spec.strategy):
+                if carried is not None:
                     try:
-                        _build_kernel_program(fn, arrays, aux,
-                                              tuple(carried))
                         counts, states, raw = fn(arrays, aux,
                                                  tuple(carried))
                     except BaseException:
                         # the take popped ownership; a failed dispatch may
                         # have already invalidated the donated buffers
-                        # mid-flight (and a failed build latches the
-                        # program off, so its carries are dead), so
-                        # discharge them explicitly — the pool's resident
-                        # bytes stay truthful and the next tick rebuilds
-                        # fresh zeros (donorguard take-without-repark)
+                        # mid-flight, so discharge them explicitly — the
+                        # pool's resident bytes stay truthful and the next
+                        # tick rebuilds fresh zeros (donorguard
+                        # take-without-repark)
                         megakernel.discard_carries(carried)
                         raise
                     segment.device_cached(("megacarry", sig),
@@ -1343,13 +1461,7 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                     if donated:
                         megakernel.stats().record_donated(donated_nbytes)
                 elif spec.strategy == "megakernel":
-                    # no donation support: parking grids in the budgeted
-                    # pool would only evict useful entries — run carryless
-                    _build_kernel_program(fn, arrays, aux, ())
                     counts, states, _raw = fn(arrays, aux, ())
-                elif spec.strategy == "pallas":
-                    _build_kernel_program(fn, arrays, aux)
-                    counts, states = fn(arrays, aux)
                 else:
                     counts, states = fn(arrays, aux)
             # count the SUCCESSFUL program only (a Mosaic-failure retry
@@ -1376,11 +1488,8 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                  if spec.window and spec.window <= w),
                 ("mixed", 0))
 
-    host_states = {k.name: k.host_post(st, segment)
-                   for k, st in zip(kernels, states)}
-    return SegmentPartial(segment=segment, spec=spec,
-                          counts=np.asarray(counts, dtype=np.int64),
-                          states=host_states, kernels=kernels)
+    return _fetch_partial(segment, spec, kernels, counts,
+                          states), spec.strategy
 
 
 def _build_kernel_program(fn, *args) -> None:

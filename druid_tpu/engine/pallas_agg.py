@@ -48,8 +48,9 @@ import numpy as np
 from druid_tpu.engine.contracts import (BLK_SMALL_W, BLK_WIDE_W, LANE,
                                         MAX_PALLAS_FIELDS, MAX_PALLAS_GROUPS,
                                         MAX_PALLAS_SLOTS, MAX_W,
-                                        MEGA_MASK_VPW, SPAN_BLOCK,
-                                        VMEM_SCRATCH_BYTES, WORD_TILE_ROWS)
+                                        MEGA_MASK_VPW, PALLAS_KERNEL_NAMES,
+                                        SPAN_BLOCK, VMEM_SCRATCH_BYTES,
+                                        WORD_TILE_ROWS)
 
 #: log2(WORD_TILE_ROWS): every in-kernel divide/modulo by a tile, word or
 #: block count is a shift/mask on int32 operands (all are powers of two)
@@ -540,6 +541,9 @@ def grouped_reduce(arrays: Dict, key, mwords2, kernels: Sequence,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=2 * tile_bytes + VMEM_SCRATCH_BYTES),
         interpret=_interpret(),
+        # a stable kernel name for the profiler's operations (the
+        # megakernel variant takes the filter mask as words)
+        name=PALLAS_KERNEL_NAMES[1 if has_mask else 0],
     )(keyx, *([mwords2] if has_mask else []), *vals2)
     counts, states = _finish_states(outs, kernels, ops, num_total)
     return counts, states, tuple(outs)

@@ -1,18 +1,17 @@
 """Observability: distributed query tracing (qtrace), the metrics catalog,
 and the Prometheus exposition sink. See trace.py for the span model and
-propagation contract, catalog.py for the declared metric names the
-druidlint `metric-name` rule enforces, prometheus.py for /metrics."""
+propagation contract, dispatch.py for the dispatch and backend-compile
+counters, catalog.py for the declared metric names the druidlint
+`metric-name` rule enforces, prometheus.py for /metrics. PERF.md §3 maps
+every span and counter to the metric or operator use it is for."""
 from druid_tpu.obs.catalog import METRICS, render_table
 from druid_tpu.obs.prometheus import MetricRegistry
-from druid_tpu.obs.trace import (COMPILE_SPAN, H2D_SPAN, NODE_SPAN, Span,
-                                 TraceStore, attach, current_span,
-                                 emit_trace_metrics, phase_breakdown,
-                                 root_span, span, trace_enabled, trace_store,
+from druid_tpu.obs.trace import (Span, TraceStore, attach, current_span,
+                                 late_span, root_span, span, trace_store,
                                  with_traceparent)
 
 __all__ = [
     "METRICS", "render_table", "MetricRegistry",
-    "COMPILE_SPAN", "H2D_SPAN", "NODE_SPAN", "Span", "TraceStore",
-    "attach", "current_span", "emit_trace_metrics", "phase_breakdown",
-    "root_span", "span", "trace_enabled", "trace_store", "with_traceparent",
+    "Span", "TraceStore", "attach", "current_span", "late_span",
+    "root_span", "span", "trace_store", "with_traceparent",
 ]

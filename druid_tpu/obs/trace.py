@@ -24,12 +24,28 @@ Propagation:
   * opt-out: context {"trace": false} disables tracing for the query
     everywhere (the stamp is simply never created).
 
+  * late spans: work that happens AFTER its natural parent closed — the
+    data node encoding the payload that carries the collected spans, the
+    HTTP front writing the answer after the `query` root ended — opens
+    with `late_span(anchor, ...)`: same trace, same store, parented to the
+    finished anchor (or to the anchor's parent).
+
+One clock with the device: in a process that has JAX loaded every span also
+enters a `jax.profiler.TraceAnnotation` of the same name — a no-op unless a
+profiler session is running — so a captured trace's `/host:CPU` plane holds
+the qtrace phases per thread, on the profiler's clock, above `XLA Ops`
+(open the trace in XProf/Perfetto). This module never imports JAX itself:
+a broker-only process imports it without.
+
 Storage: a bounded per-process ring buffer (TraceStore) serves
 GET /druid/v2/trace/<queryId> on any node type.
 """
 from __future__ import annotations
 
 import collections
+import itertools
+import os
+import sys
 import threading
 import time
 import uuid
@@ -49,8 +65,25 @@ H2D_SPAN = "pool/h2d"
 NODE_SPAN = "broker/node"
 
 
+def _reseed_ids() -> None:
+    global _ID_PREFIX, _ID_COUNTER
+    _ID_PREFIX = uuid.uuid4().hex[:8]
+    _ID_COUNTER = itertools.count(1)
+
+
+_reseed_ids()
+# a forked child (a peon) must not continue its parent's id sequence
+os.register_at_fork(after_in_child=_reseed_ids)
+
+
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    """16 hex digits: a per-process random prefix and a counter. NOT a
+    uuid4 a span: `os.urandom` releases the interpreter lock around its
+    system call, so every span opened under load handed the lock to
+    another request's thread and waited out a switch interval (5 ms) to
+    get it back — 3% of `analyst-groupby`'s latency at 173 spans a request
+    (my chip runs, PR 24)."""
+    return f"{_ID_PREFIX}{next(_ID_COUNTER) & 0xFFFFFFFF:08x}"
 
 
 class Span:
@@ -134,17 +167,40 @@ class _NullCtx:
 _NULL_CTX = _NullCtx()
 
 
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` for `name` when this process has
+    JAX loaded (looked up in sys.modules — never imported here), else
+    None. Outside a profiler session entering one costs a flag test."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return None
+        _ANNOTATION = profiler.TraceAnnotation
+    return _ANNOTATION(name)
+
+
 class _SpanCtx:
-    __slots__ = ("_span",)
+    __slots__ = ("_span", "_note")
 
     def __init__(self, s: Span):
         self._span = s
+        self._note = None
 
     def __enter__(self) -> Span:
         _stack().append(self._span)
+        self._note = _annotation(self._span.name)
+        if self._note is not None:
+            self._note.__enter__()
         return self._span
 
     def __exit__(self, et, ev, tb):
+        if self._note is not None:
+            self._note.__exit__(et, ev, tb)
         st = _stack()
         if st and st[-1] is self._span:
             st.pop()
@@ -199,6 +255,25 @@ def span_when(cond: bool, name: str, **attrs):
     compile event) gets its span without duplicating the call in an
     if/else."""
     return span(name, **attrs) if cond else _NULL_CTX
+
+
+def late_span(anchor: Optional[Span], name: str, sibling: bool = False,
+              **attrs):
+    """A span opened after `anchor` FINISHED, in its trace and store:
+    child of the anchor, or — `sibling=True` — of the anchor's parent. The
+    data node times the encoding of the payload that already carries its
+    collected spans this way (`datanode/encode`, beside `datanode/query`
+    under the broker's `broker/node`), and the HTTP front the writing of
+    an answer whose `query` root has closed (`http/respond`). Not added to
+    the anchor's collector: the caller ships `to_json()` of the yielded
+    span itself. Inactive when `anchor` is None (tracing off)."""
+    if anchor is None:
+        return _NULL_CTX
+    return _SpanCtx(Span(
+        trace_id=anchor.trace_id, span_id=_new_id(),
+        parent_id=anchor.parent_id if sibling else anchor.span_id,
+        name=name, service=anchor.service, attrs=attrs,
+        store=anchor._store))
 
 
 def trace_enabled(query) -> bool:
@@ -267,9 +342,6 @@ class TraceStore:
         self._traces: "collections.OrderedDict[str, dict]" = \
             collections.OrderedDict()
 
-    def add(self, s: Span) -> None:
-        self.add_json(s.to_json())
-
     def add_json(self, j: dict) -> None:
         tid = j.get("traceId")
         sid = j.get("spanId")
@@ -315,10 +387,6 @@ class TraceStore:
     def trace_ids(self) -> List[str]:
         with self._lock:
             return list(self._traces)
-
-    def clear(self) -> None:
-        with self._lock:
-            self._traces.clear()
 
 
 _STORE = TraceStore()

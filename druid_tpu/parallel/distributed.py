@@ -45,6 +45,7 @@ from druid_tpu.data.segment import Segment
 from druid_tpu.engine import filters as filters_mod
 from druid_tpu.engine.filters import ConstNode, plan_filter, simplify_node
 from druid_tpu.engine import grouping
+from druid_tpu.engine.contracts import named_program, program_name
 from druid_tpu.engine.grouping import (GroupSpec, KeyDim, SegmentPartial,
                                        assemble_stacked_aux, aux_equal,
                                        keydims_equal, make_group_spec,
@@ -314,7 +315,9 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
     from druid_tpu.obs import dispatch as dispatch_mod
     dispatch_mod.record("sharded")
     with trace_span("engine/sharded/dispatch", strategy=spec0.strategy,
-                    segments=K, devices=n_dev, compile=compiled), \
+                    segments=K, devices=n_dev, compile=compiled,
+                    program=program_name("sharded_agg",
+                                         spec0.strategy)), \
             trace_span_when(compiled, "engine/compile", kind="sharded",
                             strategy=spec0.strategy):
         counts, states = fn(stacked, time0s, iv_rel, bucket_off, aux)
@@ -324,11 +327,17 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
     # already collective-merged; host_from_device only converts the merged
     # device representation (HLL registers, first/last packed pairs) to
     # the host one, exactly like the single-segment path does per segment
-    host_states = {k.name: k.host_from_device(st)
-                   for k, st in zip(kernels, states)}
-    return SegmentPartial(segment=segments[0], spec=spec0,
-                          counts=np.asarray(counts, dtype=np.int64),
-                          states=host_states, kernels=kernels)
+    # engine/fetch: this conversion is where the host blocks for the
+    # enqueued program (wait-for-device + D2H) — the span adds no sync
+    with trace_span("engine/fetch", segments=K) as fetch_span:
+        if fetch_span is not None:
+            fetch_span.attrs["bytes"] = devicepool.entry_bytes(
+                (counts, states))
+        host_states = {k.name: k.host_from_device(st)
+                       for k, st in zip(kernels, states)}
+        return SegmentPartial(segment=segments[0], spec=spec0,
+                              counts=np.asarray(counts, dtype=np.int64),
+                              states=host_states, kernels=kernels)
 
 
 def _common_descriptors(segments: Sequence[Segment],
@@ -533,16 +542,17 @@ def _build_stack(mesh, segments: Sequence[Segment], columns: Tuple[str, ...],
     # wave path (query/filter/* accounting included), then stack each
     # `__fbmpN` slot; padding segments keep zero words (no row passes)
     bitmap_cols: Dict[str, np.ndarray] = {}
-    for i, (s, fn_s, ks) in enumerate(zip(segments, seg_filters,
-                                          seg_kernels)):
-        words = filters_mod.stage_device_bitmaps(s, fn_s, R, kernels=ks)
-        for col, w in words.items():
-            host = np.asarray(w)
-            slot = bitmap_cols.get(col)
-            if slot is None:
-                slot = np.zeros((K,) + host.shape, dtype=host.dtype)
-                bitmap_cols[col] = slot
-            slot[i] = host
+    with filters_mod.words_span(segments=K):
+        for i, (s, fn_s, ks) in enumerate(zip(segments, seg_filters,
+                                              seg_kernels)):
+            words = filters_mod.stage_device_bitmaps(s, fn_s, R, kernels=ks)
+            for col, w in words.items():
+                host = np.asarray(w)
+                slot = bitmap_cols.get(col)
+                if slot is None:
+                    slot = np.zeros((K,) + host.shape, dtype=host.dtype)
+                    bitmap_cols[col] = slot
+                slot[i] = host
     arrays.update(bitmap_cols)
 
     time0s = np.zeros((K,), dtype=np.int64)
@@ -681,7 +691,8 @@ def _build_sharded_fn(mesh, axis: str, n_dev: int, spec: GroupSpec,
     f = shard_map(body, mesh=mesh,
                   in_specs=layout.in_specs(stacked),
                   out_specs=layout.out_specs(), check_vma=not has_fold)
-    return jax.jit(f)
+    return jax.jit(named_program(f, program_name("sharded_agg",
+                                                 spec.strategy)))
 
 
 # ---------------------------------------------------------------------------

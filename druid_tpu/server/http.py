@@ -16,9 +16,11 @@ from __future__ import annotations
 import json
 import logging
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from druid_tpu.obs import trace as qtrace
 from druid_tpu.server.lifecycle import QueryLifecycle, Unauthorized
 from druid_tpu.server.querymanager import (QueryCapacityError,
                                            QueryInterruptedError,
@@ -154,8 +156,15 @@ class QueryHttpServer:
                 pass
 
             def _reply(self, code: int, body: dict | list,
-                       extra_headers: dict | None = None):
+                       extra_headers: dict | None = None, span=None):
+                """`span`: the open `http/respond` span of a query's
+                answer (None untraced) — stamped with what was encoded."""
+                t0 = time.monotonic()
                 data = json.dumps(body, default=_json_value).encode()
+                if span is not None:
+                    span.attrs["encodeMs"] = round(
+                        (time.monotonic() - t0) * 1000.0, 3)
+                    span.attrs["bytes"] = len(data)
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(data)))
@@ -399,8 +408,8 @@ class QueryHttpServer:
                             self.send_header("Content-Length", "0")
                             self.end_headers()
                             return
-                        rows = outer.lifecycle.run(query,
-                                                   identity=identity)
+                        rows, root = outer.lifecycle.run_with_root(
+                            query, identity=identity)
                         headers = {}
                         # a degraded result (allowPartialResults) stamps
                         # its missing-segments report on the response
@@ -419,7 +428,10 @@ class QueryHttpServer:
                                             "missingSegments": missing})
                         elif etag:
                             headers["X-Druid-ETag"] = etag
-                        self._reply(200, rows, headers or None)
+                        # the answer's way out: JSON encoding and the
+                        # socket write, after the `query` root closed
+                        with qtrace.late_span(root, "http/respond") as sp:
+                            self._reply(200, rows, headers or None, span=sp)
                     else:
                         self._reply(404, {"error": "unknown path"})
                 except Unauthorized as e:

@@ -164,6 +164,12 @@ class QueryLifecycle:
             self.on_result(True)
 
     def run(self, query: Query, identity: Optional[str] = None):
+        return self.run_with_root(query, identity)[0]
+
+    def run_with_root(self, query: Query, identity: Optional[str] = None):
+        """`run`, handing out the finished `query` root span beside the
+        rows (None when the query opted out of tracing): the HTTP front
+        parents its `http/respond` span to it (qtrace.late_span)."""
         query, qid = self._prepare(query, identity)
         t0 = time.monotonic()
         release = lambda: None
@@ -194,7 +200,7 @@ class QueryLifecycle:
         self._finish_trace(query, qid, ms, root)
         if self.on_result:
             self.on_result(True)
-        return rows
+        return rows, root
 
     def _finish_trace(self, query: Query, qid: str, ms: float,
                       root) -> None:

@@ -499,24 +499,36 @@ def test_real_tree_is_donorguard_clean():
 def test_prefix_dispatch_shape_fires_read_after_donate_and_repark():
     # the pre-PR shape: no exception-path discard, donated bytes summed
     # AFTER the dispatch — both ownership bugs donorguard was built for
+    path = "druid_tpu/engine/grouping.py"
+    # (1) the build under engine/build loses its exception-path discard
     sources = _mutate(
-        _tree_sources(), "druid_tpu/engine/grouping.py",
-        """                    donated_nbytes = sum(
-                        int(getattr(a, "nbytes", 0))
-                        for a in carried) if donated else 0
-                    try:
-                        _build_kernel_program(fn, arrays, aux,
-                                              tuple(carried))
+        _tree_sources(), path,
+        """                            try:
+                                _build_kernel_program(fn, arrays, aux,
+                                                      tuple(carried))
+                            except BaseException:
+                                # a failed build latches the program off,
+                                # so its carries are dead: discharge the
+                                # ownership the take popped
+                                megakernel.discard_carries(carried)
+                                raise
+""",
+        """                            _build_kernel_program(fn, arrays, aux,
+                                                  tuple(carried))
+""")
+    # (2) so does the dispatch, and the donated bytes are summed after it
+    sources = _mutate(
+        sources, path,
+        """                    try:
                         counts, states, raw = fn(arrays, aux,
                                                  tuple(carried))
                     except BaseException:
                         # the take popped ownership; a failed dispatch may
                         # have already invalidated the donated buffers
-                        # mid-flight (and a failed build latches the
-                        # program off, so its carries are dead), so
-                        # discharge them explicitly — the pool's resident
-                        # bytes stay truthful and the next tick rebuilds
-                        # fresh zeros (donorguard take-without-repark)
+                        # mid-flight, so discharge them explicitly — the
+                        # pool's resident bytes stay truthful and the next
+                        # tick rebuilds fresh zeros (donorguard
+                        # take-without-repark)
                         megakernel.discard_carries(carried)
                         raise
 """,
