@@ -226,6 +226,47 @@ def test_trace_false_records_no_span_and_sends_no_header(cluster):
         r.read()
 
 
+# ---------------------------------------------------------------------------
+# broker/merge says how the partials were aligned (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+def test_broker_merge_says_dense_over_shared_dictionaries(cluster):
+    """Three segments with the same dictionaries: the merge aligns them in
+    the dense key space, and the span says so and counts the groups."""
+    qid = f"phases-merge-{cluster.tag}"
+    rows = cluster.post(_groupby(qid))
+    (m,) = _named(cluster.trace(qid), "broker/merge")
+    assert m["attrs"]["mergePath"] == "dense"
+    assert m["attrs"]["groups"] == len(rows) == 70
+    assert m["attrs"]["partials"] == 3
+    # untraced: the same rows, and no span anywhere to hang an attribute on
+    off = f"phases-merge-off-{cluster.tag}"
+    assert cluster.post(_groupby(off, trace=False)) == rows
+    assert qtrace.trace_store().get(off) is None
+
+
+def test_broker_merge_says_sorted_for_a_host_key_query():
+    """Two dimensions of ~1,900 values each: the key space is past
+    DENSE_GROUP_LIMIT, the engine keys on the host, the merge sorts."""
+    wide = (ColumnSpec("dimX", "string", cardinality=2000),
+            ColumnSpec("dimY", "string", cardinality=2000),
+            ColumnSpec("metLong", "long", low=0, high=100))
+    segs = DataGenerator(wide, seed=11).segments(2, 6000, DAYS,
+                                                 datasource="phases")
+    assert all(s.dims["dimX"].cardinality * s.dims["dimY"].cardinality
+               > grouping.DENSE_GROUP_LIMIT for s in segs)
+    c = _Cluster(segs, own_store=False)
+    try:
+        q = _groupby("phases-merge-host")
+        q["dimensions"] = ["dimX", "dimY"]
+        rows = c.post(q)
+        (m,) = _named(c.trace("phases-merge-host"), "broker/merge")
+        assert m["attrs"]["mergePath"] == "sorted"
+        assert m["attrs"]["groups"] == len(rows) > 6000
+    finally:
+        c.stop()
+
+
 def test_late_span_header_on_the_wire(cluster):
     """The header is a JSON list of finished spans, siblings of the node's
     root under the caller's span; a mangled one costs the span only."""
