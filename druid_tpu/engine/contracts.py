@@ -260,6 +260,36 @@ def named_program(fn, name: str):
     return fn
 
 
+#: THE closed set of reasons a mesh node hands a query to the per-segment
+#: path (parallel/distributed.py `_plan_sharded`, one per ineligible exit):
+#: the `reason` attribute of an `engine/sharded/plan` span whose `fallback`
+#: is 1. tests/test_sharded_spans.py holds the source to this list.
+SHARDED_FALLBACK_REASONS = (
+    "cross_process_mesh",          # the mesh spans processes
+    "numeric_dimension",           # per-segment query-time id dictionaries
+    "key_dims_differ",             # key dims' columns or cardinalities
+    "key_dimension_missing",       # a raw key dim absent from a segment
+    "dictionaries_differ",         # a raw key dim's dictionaries disagree
+    "key_or_bucket_mode",          # not dense keys over all/uniform buckets
+    "filter_plans_differ",
+    "filter_constants_differ",
+    "kernel_plans_differ",
+    "kernel_constants_differ",
+    "virtual_columns_differ",
+    "complex_metric",              # a 2-D metric column: the stack is [K, R]
+    "dimension_presence_differs",
+    "metric_presence_differs",
+    "metric_types_differ",
+)
+
+
+def sharded_fallback_reason(reason: str) -> str:
+    """`reason`, refused unless it is in SHARDED_FALLBACK_REASONS."""
+    if reason not in SHARDED_FALLBACK_REASONS:
+        raise ValueError(f"{reason!r} is not a documented fall-back reason")
+    return reason
+
+
 # ---- dtype lattice --------------------------------------------------------
 
 DTYPE_BYTES = {

@@ -238,6 +238,13 @@ METRICS = {
                 "IN-PROGRAM by the mesh collectives (psum/pmin/pmax/"
                 "all_gather+fold) since the last tick — every sharded "
                 "dispatch, now that the broker-side host merge is gone"},
+    "query/sharded/fallback": {
+        "unit": "count/period", "dims": (),
+        "site": "parallel/distributed.py (ShardedMonitor)",
+        "help": "queries a mesh node found ineligible for the sharded "
+                "program and served per segment on one device since the "
+                "last tick (the `engine/sharded/plan` span's `reason` "
+                "says why: contracts.SHARDED_FALLBACK_REASONS)"},
     "query/sharded/stackBytes": {
         "unit": "bytes", "dims": (),
         "site": "parallel/distributed.py (ShardedMonitor)",

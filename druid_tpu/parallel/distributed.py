@@ -34,7 +34,7 @@ import functools
 import hashlib
 import threading
 import weakref
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -45,7 +45,8 @@ from druid_tpu.data.segment import Segment
 from druid_tpu.engine import filters as filters_mod
 from druid_tpu.engine.filters import ConstNode, plan_filter, simplify_node
 from druid_tpu.engine import grouping
-from druid_tpu.engine.contracts import named_program, program_name
+from druid_tpu.engine.contracts import (named_program, program_name,
+                                        sharded_fallback_reason)
 from druid_tpu.engine.grouping import (GroupSpec, KeyDim, SegmentPartial,
                                        assemble_stacked_aux, aux_equal,
                                        keydims_equal, make_group_spec,
@@ -115,6 +116,27 @@ _keydims_equal = keydims_equal
 _needed_columns = needed_columns
 
 
+class _ShardedPlan(NamedTuple):
+    """What `_plan_sharded` hands the run: the agreed per-query plan of an
+    eligible segment set."""
+    kds: List[KeyDim]
+    spec0: GroupSpec
+    filter_node: object
+    kernels: List[AggKernel]
+    n_slots: int
+    vc_plans: Tuple
+    vc_luts: Sequence
+    f_aux: Sequence
+    k_aux: Sequence
+    seg_filters: List[object]
+    seg_kernels: List[List[AggKernel]]
+    columns: Tuple[str, ...]
+    cascades: Tuple
+    packs: Tuple
+    valid_rle: bool
+    selected: str       # select_strategy's choice; spec0.strategy is what runs
+
+
 def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
                 granularity: Granularity,
                 kds_per_seg: Sequence[Sequence[KeyDim]],
@@ -122,29 +144,55 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
                 virtual_columns: Sequence = ()) -> Optional[SegmentPartial]:
     """Run the grouped aggregate for all segments as ONE sharded device
     program; returns a single merged SegmentPartial, or None if ineligible
-    (caller falls back to the per-segment path)."""
+    (caller falls back to the per-segment path). Past the mesh check every
+    exit is under `engine/sharded/plan`: a fall-back says so (`fallback` 1,
+    `reason` one of contracts.SHARDED_FALLBACK_REASONS) and is counted, so a
+    mesh node that serves a query on one device never does so silently."""
     mesh = context.get_mesh()
     if mesh is None or not segments:
         return None
+    with trace_span("engine/sharded/plan", segments=len(segments)) as sp:
+        plan = _plan_sharded(mesh, segments, intervals, granularity,
+                             kds_per_seg, aggs, flt, virtual_columns)
+        fallback = isinstance(plan, str)
+        if sp is not None:
+            sp.attrs["fallback"] = int(fallback)
+            if fallback:
+                sp.attrs["reason"] = plan
+            elif isinstance(plan, _ShardedPlan):
+                sp.attrs["selected"] = plan.selected
+                sp.attrs["strategy"] = plan.spec0.strategy
+    if fallback:
+        _SHARDED_STATS.record_fallback()
+        return None
+    if isinstance(plan, SegmentPartial):
+        return plan         # a const-false filter's whole-query zero
+    return _run_sharded(mesh, plan, segments, intervals, granularity)
+
+
+def _plan_sharded(mesh, segments: Sequence[Segment],
+                  intervals: Sequence[Interval], granularity: Granularity,
+                  kds_per_seg: Sequence[Sequence[KeyDim]],
+                  aggs: Sequence[AggregatorSpec], flt,
+                  virtual_columns: Sequence):
+    """Eligibility and the per-segment plans of one query: a `_ShardedPlan`,
+    the whole-query zero of a const-false filter, or the fall-back's reason
+    (contracts.SHARDED_FALLBACK_REASONS, the closed set)."""
     import jax
     if any(d.process_index != jax.process_index()
            for d in mesh.devices.flat):
         # cross-process mesh: the stacked program would need every shard's
         # data process-addressable; host-level combine is the broker's job
-        return None
-    layout = speclayout.layout_for(mesh)
-    axis = layout.seg_axis
-    n_dev = mesh.shape[axis]
-
+        return sharded_fallback_reason("cross_process_mesh")
     kds = list(kds_per_seg[0])
     if any(d.host_ids is not None for d in kds):
         # numeric-dimension ids are per-segment query-time dictionaries —
         # a stacked program cannot share one id space; per-segment path
         # merges them host-side
-        return None
+        return sharded_fallback_reason("numeric_dimension")
     for other in kds_per_seg[1:]:
         if not _keydims_equal(kds, other):
-            return None
+            return sharded_fallback_reason("key_dims_differ")
     # raw (remap-free) key dims fuse dictionary ids directly, so the
     # dictionaries themselves must agree across segments — equal cardinality
     # is NOT enough (ids would decode through segments[0]'s values)
@@ -155,14 +203,14 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
         for s in segments[1:]:
             other = s.dims.get(d.column)
             if other is None:
-                return None
+                return sharded_fallback_reason("key_dimension_missing")
             if other.dictionary is not first and \
                     list(other.dictionary.values) != list(first.values):
-                return None
+                return sharded_fallback_reason("dictionaries_differ")
 
     spec0 = make_group_spec(segments[0], intervals, granularity, kds)
     if spec0.key_mode != "dense" or spec0.bucket_mode not in ("all", "uniform"):
-        return None
+        return sharded_fallback_reason("key_or_bucket_mode")
 
     # plan filter + kernels + virtual columns per segment; constants must
     # agree across segments. Device-bitmap compilation follows the process
@@ -185,16 +233,16 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
         ks = [make_kernel(a, s) for a in aggs]
         filters_mod.assign_bitmap_slots(fn_s, ks)
         if (fn_s.signature() if fn_s else "none") != f_sig:
-            return None
+            return sharded_fallback_reason("filter_plans_differ")
         if not _aux_equal(fn_s.aux_arrays() if fn_s else [], f_aux):
-            return None
+            return sharded_fallback_reason("filter_constants_differ")
         if [k.signature() for k in ks] != [k.signature() for k in kernels]:
-            return None
+            return sharded_fallback_reason("kernel_plans_differ")
         if not _aux_equal([a for k in ks for a in k.aux_arrays()], k_aux):
-            return None
+            return sharded_fallback_reason("kernel_constants_differ")
         vp_s, vl_s = plan_virtual_columns(s, virtual_columns)
         if repr(vp_s) != repr(vc_plans) or not _aux_equal(vl_s, vc_luts):
-            return None
+            return sharded_fallback_reason("virtual_columns_differ")
         seg_filters.append(fn_s)
         seg_kernels.append(ks)
     # only after every segment agreed on the plan is a const-false filter a
@@ -221,18 +269,18 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
         in_dim0 = c in segments[0].dims
         met0 = segments[0].metrics.get(c)
         if met0 is not None and np.asarray(met0.values).ndim != 1:
-            return None
+            return sharded_fallback_reason("complex_metric")
         for s in segments[1:]:
             if (c in s.dims) != in_dim0:
-                return None
+                return sharded_fallback_reason("dimension_presence_differs")
             met = s.metrics.get(c)
             if (met is None) != (met0 is None):
-                return None
+                return sharded_fallback_reason("metric_presence_differs")
             if met is not None and (met.type is not met0.type
                                     or met.values.dtype != met0.values.dtype
                                     or s.staged_dtype(c)
                                     != segments[0].staged_dtype(c)):
-                return None
+                return sharded_fallback_reason("metric_types_differ")
 
     # compressed slots: the descriptor pair every segment can agree on
     # (cascade entries + pack entries), plus RLE validity masks — the
@@ -240,9 +288,8 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
     # chunk-mates agree and the cached program's treedef is pinned
     valid_rle = cascade_mod.enabled()
     cascades, packs = _common_descriptors(segments, columns)
-    stacked, time0s, R, K = _stack_segments(mesh, segments, columns,
-                                            cascades, packs, valid_rle,
-                                            seg_filters, seg_kernels, layout)
+    R, _K = _stack_shape(
+        segments, mesh.shape[speclayout.layout_for(mesh).seg_axis])
 
     # reduction strategy must agree across the whole stacked program; the
     # windowed path needs every segment's host span check to pass
@@ -267,46 +314,73 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
     # grouping.select_strategy) also steer the sharded path
     spec0.strategy, spec0.window = grouping.select_strategy(
         spec0, kernels, col_dtypes, R, _windowed_all)
-    if spec0.strategy == "projection":
+    selected = spec0.strategy
+    if selected == "projection":
         # sorted projections are per-segment layouts the stacked program
-        # cannot share. Falling back to per-segment pallas would also pay
-        # per-call dispatch/merge overhead once per segment; ONE stacked
-        # scatter-mixed program amortizes it across the whole set and
-        # measured ~2x faster at bench scale (8x12.5M rows) on v5e — so
-        # the stacked program overrides to mixed and the projection path
-        # stays the meshless per-segment winner.
+        # cannot share, so the stacked program overrides to the XLA scatter
+        # (`mixed`); the plan span carries both (`selected`, `strategy`).
+        # What the override costs against the meshless per-segment Pallas
+        # path is measured, not assumed: PERF.md §5, `mesh4-analyst-groupby`
+        # against `analyst-groupby`.
         spec0.strategy, spec0.window = "mixed", 0
+    return _ShardedPlan(
+        kds=kds, spec0=spec0,
+        filter_node=filter_node, kernels=kernels, n_slots=n_slots,
+        vc_plans=vc_plans, vc_luts=vc_luts, f_aux=f_aux, k_aux=k_aux,
+        seg_filters=seg_filters, seg_kernels=seg_kernels, columns=columns,
+        cascades=cascades, packs=packs, valid_rle=valid_rle,
+        selected=selected)
+
+
+def _run_sharded(mesh, plan: _ShardedPlan, segments: Sequence[Segment],
+                 intervals: Sequence[Interval],
+                 granularity: Granularity) -> SegmentPartial:
+    """Stack look-up (or build), per-request H2D, the ONE dispatch and its
+    fetch, each under its span."""
+    layout = speclayout.layout_for(mesh)
+    axis = layout.seg_axis
+    n_dev = mesh.shape[axis]
+    spec0, kds, kernels = plan.spec0, plan.kds, plan.kernels
+    stacked, time0s, R, K = _stack_segments(
+        mesh, segments, plan.columns, plan.cascades, plan.packs,
+        plan.valid_rle, plan.seg_filters, plan.seg_kernels, layout)
 
     # per-segment RELATIVE interval bounds + bucket start offsets: the
     # device program stays in int32 offset space (64-bit elementwise time
     # math is limb-emulated on TPU)
-    clip_lo, clip_hi = -(2**31) + 1, 2**31 - 1
-    iv_rel = np.zeros((K, max(len(intervals), 1), 2), dtype=np.int32)
-    bucket_off = np.zeros((K,), dtype=np.int32)
-    for i, s in enumerate(segments):
-        t0 = s.interval.start
-        for j, ivl in enumerate(intervals):
-            iv_rel[i, j, 0] = min(max(ivl.start - t0, clip_lo), clip_hi)
-            iv_rel[i, j, 1] = min(max(ivl.end - t0, clip_lo), clip_hi)
-        if spec0.bucket_mode == "uniform":
-            bucket_off[i] = min(max(int(spec0.bucket_starts[0]) - t0,
-                                    clip_lo), clip_hi)
-    iv_rel = layout.put_interval_bounds(mesh, iv_rel)
-    bucket_off = layout.put_bucket_offsets(mesh, bucket_off)
+    with trace_span("engine/sharded/put") as put_span:
+        clip_lo, clip_hi = -(2**31) + 1, 2**31 - 1
+        iv_rel = np.zeros((K, max(len(intervals), 1), 2), dtype=np.int32)
+        bucket_off = np.zeros((K,), dtype=np.int32)
+        for i, s in enumerate(segments):
+            t0 = s.interval.start
+            for j, ivl in enumerate(intervals):
+                iv_rel[i, j, 0] = min(max(ivl.start - t0, clip_lo), clip_hi)
+                iv_rel[i, j, 1] = min(max(ivl.end - t0, clip_lo), clip_hi)
+            if spec0.bucket_mode == "uniform":
+                bucket_off[i] = min(max(int(spec0.bucket_starts[0]) - t0,
+                                        clip_lo), clip_hi)
+        aux = _assemble_aux(spec0, kds, plan.f_aux, plan.k_aux, granularity,
+                            plan.vc_luts)
+        if put_span is not None:
+            put_span.attrs["bytes"] = iv_rel.nbytes + bucket_off.nbytes \
+                + devicepool.entry_bytes(aux)
+        iv_rel = layout.put_interval_bounds(mesh, iv_rel)
+        bucket_off = layout.put_bucket_offsets(mesh, bucket_off)
 
-    aux = _assemble_aux(spec0, kds, f_aux, k_aux, granularity, vc_luts)
-
-    sig = _sharded_sig(mesh, axis, spec0, kds, filter_node, kernels,
-                       len(intervals), vc_plans, K, R, columns, cascades,
-                       packs, n_slots, valid_rle, layout)
+    sig = _sharded_sig(mesh, axis, spec0, kds, plan.filter_node, kernels,
+                       len(intervals), plan.vc_plans, K, R, plan.columns,
+                       plan.cascades, plan.packs, plan.n_slots,
+                       plan.valid_rle, layout)
     with _CACHE_LOCK:
         fn = _FN_CACHE.get(sig)
         # the miss IS the compile event (shard_map traces/compiles on the
         # first call below) — timing stays at the existing dispatch boundary
         compiled = fn is None
         if fn is None:
-            fn = _build_sharded_fn(mesh, axis, n_dev, spec0, kds, filter_node,
-                                   kernels, vc_plans, layout, stacked)
+            fn = _build_sharded_fn(mesh, axis, n_dev, spec0, kds,
+                                   plan.filter_node, kernels, plan.vc_plans,
+                                   layout, stacked)
             _FN_CACHE[sig] = fn
             while len(_FN_CACHE) > _FN_CACHE_CAP:
                 _FN_CACHE.popitem(last=False)
@@ -459,26 +533,44 @@ def _stack_segments(mesh, segments: Sequence[Segment],
     key = (devicepool.STACKED_KIND, tuple(id(s) for s in segments), columns,
            n_dev, tuple(int(d.id) for d in mesh.devices.flat), cascades,
            packs, int(valid_rle), _bitmap_digest(seg_filters, seg_kernels))
+    built_bytes = None          # stays None on a pool hit
 
     def build():
-        return _build_stack(mesh, segments, columns, cascades, packs,
-                            valid_rle, seg_filters, seg_kernels, layout,
-                            n_dev)
+        nonlocal built_bytes
+        value = _build_stack(mesh, segments, columns, cascades, packs,
+                             valid_rle, seg_filters, seg_kernels, layout,
+                             n_dev)
+        built_bytes = devicepool.entry_bytes(value)
+        return value
 
-    value = pool.get_or_build(_stack_owner_token(pool), key, build)
-    return value[:4]
+    with trace_span("engine/sharded/stack", segments=len(segments),
+                    devices=n_dev) as sp:
+        dev_arrays, dev_time0s, R, K = pool.get_or_build(
+            _stack_owner_token(pool), key, build)[:4]
+        if sp is not None:
+            sp.attrs.update(hit=built_bytes is None,
+                            builtBytes=built_bytes or 0,
+                            paddedSegments=K, rows=R)
+    return dev_arrays, dev_time0s, R, K
+
+
+def _stack_shape(segments: Sequence[Segment], n_dev: int) -> Tuple[int, int]:
+    """(R, K) of the segments' stack: rows padded to the widest segment,
+    1024-aligned (pack_padded's tile quantum, 128 * values per word, for
+    every contract width, 4/8/16 alike); segments padded to a multiple of
+    the mesh axis."""
+    align = 1024
+    R = max(align, max(((s.n_rows + align - 1) // align) * align
+                       for s in segments))
+    K = ((len(segments) + n_dev - 1) // n_dev) * n_dev
+    return R, K
 
 
 def _build_stack(mesh, segments: Sequence[Segment], columns: Tuple[str, ...],
                  cascades: Tuple, packs: Tuple, valid_rle: bool,
                  seg_filters: Sequence, seg_kernels: Sequence,
                  layout: "speclayout.SpecLayout", n_dev: int):
-    # 1024-aligned rows satisfy pack_padded's tile quantum (128 * values
-    # per word) for every contract width, 4/8/16 alike
-    align = 1024
-    R = max(align, max(((s.n_rows + align - 1) // align) * align
-                       for s in segments))
-    K = ((len(segments) + n_dev - 1) // n_dev) * n_dev
+    R, K = _stack_shape(segments, n_dev)
     casc_by_name = {e[0]: e for e in cascades}
     pack_by_name = {e[0]: (e[1], e[2]) for e in packs}
 
@@ -703,21 +795,27 @@ class ShardedStats:
     """merged_device = sharded dispatches whose partials were merged by the
     in-program collectives (every dispatch since the host-merge tail was
     removed — the counter exists so its constancy is assertable);
-    segments = segments those dispatches covered."""
+    segments = segments those dispatches covered; fallbacks = queries a
+    mesh node found ineligible and handed to the per-segment path."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.merged_device = 0
         self.segments = 0
+        self.fallbacks = 0
 
     def record(self, n_segments: int) -> None:
         with self._lock:
             self.merged_device += 1
             self.segments += n_segments
 
-    def snapshot(self) -> Tuple[int, int]:
+    def record_fallback(self) -> None:
         with self._lock:
-            return (self.merged_device, self.segments)
+            self.fallbacks += 1
+
+    def snapshot(self) -> Tuple[int, int, int]:
+        with self._lock:
+            return (self.merged_device, self.segments, self.fallbacks)
 
 
 _SHARDED_STATS = ShardedStats()
@@ -729,20 +827,21 @@ def sharded_stats() -> ShardedStats:
 
 
 class ShardedMonitor(Monitor):
-    """Emits `query/sharded/*` per tick: device-merged dispatches over the
-    tick window, and the stacked-shard residency gauges from the device
-    pool's stacked accounting."""
+    """Emits `query/sharded/*` per tick: device-merged dispatches and
+    fall-backs over the tick window, and the stacked-shard residency gauges
+    from the device pool's stacked accounting."""
 
     def __init__(self, stats: Optional[ShardedStats] = None,
                  pool: Optional["devicepool.DeviceSegmentPool"] = None):
         self.stats = stats or sharded_stats()
         self.pool = pool or devicepool.device_pool()
-        self._last = (0, 0)
+        self._last = (0, 0, 0)
 
     def do_monitor(self, emitter) -> None:
         s = self.stats.snapshot()
         last, self._last = self._last, s
         emitter.metric("query/sharded/mergeDevice", s[0] - last[0])
+        emitter.metric("query/sharded/fallback", s[2] - last[2])
         p = self.pool.snapshot()
         emitter.metric("query/sharded/stackBytes", p.stacked_bytes)
         emitter.metric("query/sharded/packedRatio", p.stacked_ratio)
