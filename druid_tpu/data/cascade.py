@@ -12,6 +12,9 @@ top of it and, where the query allows, stops decoding entirely:
     Run metadata is ~8 bytes/run vs 4 bytes/ROW decoded, so sorted real
     data multiplies the device pool's effective capacity far past the
     bit-packing ratio.
+  * **prefix mask** (`PrefixMaskColumn`): a stacked segment's `__valid`
+    is its row count; the traced decode is `iota < n_rows`, one compare
+    (parallel/distributed.py builds it; 4 bytes a segment for R bools).
   * **delta / FOR** (`DeltaColumn` / `ForColumn`): `__time_offset` in
     rollup segments is near-constant — it stages as base-biased
     range-packed words (FOR) or width-packed non-negative deltas with an
@@ -46,7 +49,7 @@ batching._Plan.digest. Opt-out: DRUID_TPU_CASCADE=0 restores the
 packed-only world bit-for-bit.
 
 The decode counter (`decode_stats`) increments at TRACE time whenever any
-decode (packed/rle/delta/lz4) enters a program — the "code-domain paths
+decode (packed/rle/prefix/delta/lz4) enters a program — the "code-domain paths
 perform ZERO unpack" acceptance gate is asserted against its deltas.
 """
 from __future__ import annotations
@@ -266,6 +269,50 @@ def rle_decode_device(rc: RleColumn):
     v = jnp.where(iota < rc.n_rows, rc.values[idx], 0)
     dt = jnp.dtype(rc.dtype_str)
     return v.astype(dt) if v.dtype != dt else v
+
+
+# ---------------------------------------------------------------------------
+# PrefixMaskColumn
+# ---------------------------------------------------------------------------
+
+class PrefixMaskColumn:
+    """A bool column that is True on rows [0, n_rows) and False on the
+    padding after them — a stacked segment's `__valid` — carried as what
+    it is: the row count. The traced decode is ONE compare against an
+    iota, which XLA fuses into every consumer; no run table, no search.
+
+    `n_rows` rides as a DEVICE SCALAR leaf (stacked: a `[K]` leaf), never
+    treedef aux — the RleColumn.n_rows rule. A padding segment's count is
+    0: every row invalid."""
+
+    cascade_kind = "prefix"
+    __slots__ = ("n_rows", "padded_rows")
+
+    def __init__(self, n_rows, padded_rows: int):
+        _register(PrefixMaskColumn,
+                  lambda c: ((c.n_rows,), (c.padded_rows,)),
+                  lambda aux, leaves: PrefixMaskColumn(leaves[0], *aux))
+        self.n_rows = n_rows
+        self.padded_rows = int(padded_rows)
+
+    @property
+    def nbytes(self) -> int:
+        return int(getattr(self.n_rows, "nbytes", 0))
+
+    @property
+    def logical_nbytes(self) -> int:
+        return int(self.padded_rows * np.dtype(bool).itemsize)
+
+    def __repr__(self):
+        return f"PrefixMaskColumn(rows={self.padded_rows})"
+
+
+def prefix_mask_decode_device(pm: PrefixMaskColumn):
+    """Traced: the dense bool mask, `iota < n_rows`."""
+    import jax.numpy as jnp
+
+    record_decode("prefix")
+    return jnp.arange(pm.padded_rows, dtype=jnp.int32) < pm.n_rows
 
 
 # ---------------------------------------------------------------------------
@@ -772,6 +819,9 @@ def split_resident(arrays: Dict) -> Tuple[Dict, Dict]:
     for k, v in arrays.items():
         if isinstance(v, RleColumn):
             out[k] = rle_decode_device(v)
+            changed = True
+        elif isinstance(v, PrefixMaskColumn):
+            out[k] = prefix_mask_decode_device(v)
             changed = True
         elif isinstance(v, DeltaColumn):
             out[k] = delta_decode_device(v)

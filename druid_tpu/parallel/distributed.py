@@ -11,11 +11,12 @@ Reference analog, inverted for TPU:
 
 The stacked blocks are COMPRESSED-RESIDENT: each shard carries per-segment
 packed words (data/packed.py tile-planar layout), cascade columns (RLE run
-tables, delta/FOR words — data/cascade.py), and resident filter-bitmap
-words (engine/filters.py DeviceBitmapNode slots), and the program decodes
-at its top through the same `cascade.split_resident` every other path
-calls — one decode/filter story for per-segment, batched and sharded
-execution. Every PartitionSpec comes from parallel/speclayout.py (the
+tables, delta/FOR words — data/cascade.py), resident filter-bitmap words
+(engine/filters.py DeviceBitmapNode slots), and each segment's validity as
+its row count (cascade.PrefixMaskColumn: `__valid` is `iota < n_rows`, one
+compare), and the program decodes at its top through the same
+`cascade.split_resident` every other path calls — one decode/filter story
+for per-segment, batched and sharded execution. Every PartitionSpec comes from parallel/speclayout.py (the
 canonical SpecLayout; lint-enforced single source), and partial grids are
 merged ON DEVICE by the collectives — the broker-side host merge for this
 path is gone; `host_from_device` below only converts the already-merged
@@ -133,7 +134,6 @@ class _ShardedPlan(NamedTuple):
     columns: Tuple[str, ...]
     cascades: Tuple
     packs: Tuple
-    valid_rle: bool
     selected: str       # select_strategy's choice; spec0.strategy is what runs
 
 
@@ -283,10 +283,9 @@ def _plan_sharded(mesh, segments: Sequence[Segment],
                 return sharded_fallback_reason("metric_types_differ")
 
     # compressed slots: the descriptor pair every segment can agree on
-    # (cascade entries + pack entries), plus RLE validity masks — the
-    # descriptors join the stack pool key AND _sharded_sig below, so
-    # chunk-mates agree and the cached program's treedef is pinned
-    valid_rle = cascade_mod.enabled()
+    # (cascade entries + pack entries) — the descriptors join the stack
+    # pool key AND _sharded_sig below, so chunk-mates agree and the cached
+    # program's treedef is pinned
     cascades, packs = _common_descriptors(segments, columns)
     R, _K = _stack_shape(
         segments, mesh.shape[speclayout.layout_for(mesh).seg_axis])
@@ -328,8 +327,7 @@ def _plan_sharded(mesh, segments: Sequence[Segment],
         filter_node=filter_node, kernels=kernels, n_slots=n_slots,
         vc_plans=vc_plans, vc_luts=vc_luts, f_aux=f_aux, k_aux=k_aux,
         seg_filters=seg_filters, seg_kernels=seg_kernels, columns=columns,
-        cascades=cascades, packs=packs, valid_rle=valid_rle,
-        selected=selected)
+        cascades=cascades, packs=packs, selected=selected)
 
 
 def _run_sharded(mesh, plan: _ShardedPlan, segments: Sequence[Segment],
@@ -343,7 +341,7 @@ def _run_sharded(mesh, plan: _ShardedPlan, segments: Sequence[Segment],
     spec0, kds, kernels = plan.spec0, plan.kds, plan.kernels
     stacked, time0s, R, K = _stack_segments(
         mesh, segments, plan.columns, plan.cascades, plan.packs,
-        plan.valid_rle, plan.seg_filters, plan.seg_kernels, layout)
+        plan.seg_filters, plan.seg_kernels, layout)
 
     # per-segment RELATIVE interval bounds + bucket start offsets: the
     # device program stays in int32 offset space (64-bit elementwise time
@@ -370,8 +368,7 @@ def _run_sharded(mesh, plan: _ShardedPlan, segments: Sequence[Segment],
 
     sig = _sharded_sig(mesh, axis, spec0, kds, plan.filter_node, kernels,
                        len(intervals), plan.vc_plans, K, R, plan.columns,
-                       plan.cascades, plan.packs, plan.n_slots,
-                       plan.valid_rle, layout)
+                       plan.cascades, plan.packs, plan.n_slots, layout)
     with _CACHE_LOCK:
         fn = _FN_CACHE.get(sig)
         # the miss IS the compile event (shard_map traces/compiles on the
@@ -487,12 +484,13 @@ def _bitmap_digest(seg_filters: Sequence, seg_kernels: Sequence) -> str:
 
 def _stack_tree(cols: List, K: int):
     """Stack K per-segment column pytrees (decoded arrays, PackedColumn,
-    RLE/FOR/delta columns) leaf-wise onto a leading segment axis. Padding
-    segments are zeroed copies of the first: RLE zeros decode all-invalid
-    (n_rows=0), packed/FOR zeros decode to the base — every consumer masks
-    them through `__valid`. Descriptor agreement (_common_descriptors)
-    guarantees equal treedefs, so per-segment row counts/firsts ride as
-    stacked [K] scalar leaves, not aux."""
+    RLE/FOR/delta columns, the prefix mask) leaf-wise onto a leading
+    segment axis. Padding segments are zeroed copies of the first: a zero
+    row count is an all-invalid `__valid`, packed/FOR zeros decode to the
+    base — every consumer masks them through `__valid`. Descriptor
+    agreement (_common_descriptors) guarantees equal treedefs, so
+    per-segment row counts/firsts ride as stacked [K] scalar leaves, not
+    aux."""
     import jax
     if len(cols) < K:
         pad = jax.tree.map(lambda leaf: np.zeros_like(np.asarray(leaf)),
@@ -505,14 +503,13 @@ def _stack_tree(cols: List, K: int):
 
 def _stack_segments(mesh, segments: Sequence[Segment],
                     columns: Tuple[str, ...], cascades: Tuple, packs: Tuple,
-                    valid_rle: bool, seg_filters: Sequence,
-                    seg_kernels: Sequence,
+                    seg_filters: Sequence, seg_kernels: Sequence,
                     layout: "speclayout.SpecLayout"):
     """Stack segments into COMPRESSED-RESIDENT [K, ...] slots sharded over
-    the mesh axis: cascade columns (RLE run tables, delta/FOR words),
-    packed words, resident filter-bitmap words, decoded rows for the rest —
-    the sharded program decodes in-program through cascade.split_resident
-    exactly like _build_device_fn.
+    the mesh axis: cascade columns (RLE run tables, delta/FOR words, the
+    validity's row counts), packed words, resident filter-bitmap words,
+    decoded rows for the rest — the sharded program decodes in-program
+    through cascade.split_resident exactly like _build_device_fn.
 
     K pads to a multiple of the axis size with empty (all-invalid)
     segments; R pads rows to the max padded row count (1024-aligned — a
@@ -532,14 +529,13 @@ def _stack_segments(mesh, segments: Sequence[Segment],
     # device-bitmap) and filter-word content changes restack.
     key = (devicepool.STACKED_KIND, tuple(id(s) for s in segments), columns,
            n_dev, tuple(int(d.id) for d in mesh.devices.flat), cascades,
-           packs, int(valid_rle), _bitmap_digest(seg_filters, seg_kernels))
+           packs, _bitmap_digest(seg_filters, seg_kernels))
     built_bytes = None          # stays None on a pool hit
 
     def build():
         nonlocal built_bytes
         value = _build_stack(mesh, segments, columns, cascades, packs,
-                             valid_rle, seg_filters, seg_kernels, layout,
-                             n_dev)
+                             seg_filters, seg_kernels, layout, n_dev)
         built_bytes = devicepool.entry_bytes(value)
         return value
 
@@ -550,7 +546,8 @@ def _stack_segments(mesh, segments: Sequence[Segment],
         if sp is not None:
             sp.attrs.update(hit=built_bytes is None,
                             builtBytes=built_bytes or 0,
-                            paddedSegments=K, rows=R)
+                            paddedSegments=K, rows=R,
+                            validity=dev_arrays["__valid"].cascade_kind)
     return dev_arrays, dev_time0s, R, K
 
 
@@ -567,7 +564,7 @@ def _stack_shape(segments: Sequence[Segment], n_dev: int) -> Tuple[int, int]:
 
 
 def _build_stack(mesh, segments: Sequence[Segment], columns: Tuple[str, ...],
-                 cascades: Tuple, packs: Tuple, valid_rle: bool,
+                 cascades: Tuple, packs: Tuple,
                  seg_filters: Sequence, seg_kernels: Sequence,
                  layout: "speclayout.SpecLayout", n_dev: int):
     R, K = _stack_shape(segments, n_dev)
@@ -611,24 +608,12 @@ def _build_stack(mesh, segments: Sequence[Segment], columns: Tuple[str, ...],
         arrays[name] = _stack_tree([encoded_col(s, name) for s in segments],
                                    K)
 
-    # validity as an RLE run table (8 int32 pairs/segment instead of R
-    # bools): rows < n_rows decode 1, pads 0 — bit-exact with the dense
-    # mask. Dense [K, R] bools only when cascading is off.
-    if valid_rle:
-        valid_cols = []
-        for s in segments:
-            nr = int(s.n_rows)
-            vals = np.zeros(8, dtype=np.int32)
-            vals[0] = 1 if nr else 0
-            ends = np.full(8, nr, dtype=np.int32)
-            valid_cols.append(cascade_mod.RleColumn(
-                vals, ends, np.asarray(nr, dtype=np.int32), R, "bool"))
-        arrays["__valid"] = _stack_tree(valid_cols, K)
-    else:
-        valid = np.zeros((K, R), dtype=bool)
-        for i, s in enumerate(segments):
-            valid[i, : s.n_rows] = True
-        arrays["__valid"] = valid
+    # validity is a row count a segment (4 bytes instead of R bools): the
+    # program decodes `iota < n_rows`, bit-exact with the dense mask;
+    # _stack_tree's padding segments count 0 rows
+    arrays["__valid"] = _stack_tree(
+        [cascade_mod.PrefixMaskColumn(np.asarray(s.n_rows, dtype=np.int32), R)
+         for s in segments], K)
 
     # resident filter-bitmap words: stage per segment through the pooled
     # wave path (query/filter/* accounting included), then stack each
@@ -698,11 +683,12 @@ _assemble_aux = assemble_stacked_aux
 
 def _sharded_sig(mesh, axis, spec: GroupSpec, kds, filter_node, kernels,
                  n_intervals, vc_plans, K, R, columns, cascades, packs,
-                 n_bitmap_slots, valid_rle, layout) -> Tuple:
+                 n_bitmap_slots, layout) -> Tuple:
     """Cache key of one sharded program. The compressed-slot inputs —
-    staged column set, cascade/pack descriptors, bitmap slot count, RLE
-    validity — pin the stacked pytree's treedef, so two queries share a
-    cached program only when their stacks share a structure."""
+    staged column set, cascade/pack descriptors, bitmap slot count — pin
+    the stacked pytree's treedef (`__valid` is always the prefix mask, its
+    one static field R), so two queries share a cached program only when
+    their stacks share a structure."""
     dims_sig = ",".join(
         f"{d.column}:{'remap' if d.remap is not None else 'raw'}" for d in kds)
     vc_sig = ";".join(f"{name}={expr!r}:{out_type}:l{n_luts}"
@@ -712,7 +698,7 @@ def _sharded_sig(mesh, axis, spec: GroupSpec, kds, filter_node, kernels,
             filter_node.signature() if filter_node else "none",
             ";".join(k.signature() for k in kernels), spec.num_total, K, R,
             spec.strategy, spec.window, columns, cascades, packs,
-            n_bitmap_slots, int(valid_rle))
+            n_bitmap_slots)
 
 
 def _merge_states(kernel: AggKernel, stacked_state, axis: str, n_dev: int,

@@ -73,7 +73,7 @@ class SpecLayout:
 
     def time0s(self):
         """Per-segment scalars [K]: time origins, delta-column firsts,
-        RLE row counts, bucket offsets."""
+        RLE and validity (prefix-mask) row counts, bucket offsets."""
         return _pspec()(self.seg_axis)
 
     def interval_bounds(self):

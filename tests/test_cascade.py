@@ -88,6 +88,101 @@ def test_rle_roundtrip_device():
     np.testing.assert_array_equal(out[1000:], 0)   # staging pad fill
 
 
+# a stacked segment's validity: rows [0, n_rows) of R, as its row count
+PREFIX_R = 3 * 1024
+PREFIX_COUNTS = [0, 1, PREFIX_R - 1, PREFIX_R, 1777]
+
+
+def _dense_valid(counts, K, R):
+    """The dense [K, R] mask a stack held before validity was a row count."""
+    valid = np.zeros((K, R), dtype=bool)
+    for i, n in enumerate(counts):
+        valid[i, :n] = True
+    return valid
+
+
+def _decode_valid(col):
+    return cascade.split_resident({"__valid": col})[1]["__valid"]
+
+
+def _prefix(n_rows):
+    return cascade.PrefixMaskColumn(np.asarray(n_rows, np.int32), PREFIX_R)
+
+
+def _stacked(cols, K=4):
+    """Per-segment columns stacked as `_build_stack` stacks them: padding
+    segments are `_stack_tree`'s zeroed copies."""
+    from druid_tpu.parallel.distributed import _stack_tree
+    return _stack_tree(cols, K)
+
+
+@pytest.mark.parametrize("form", ["alone", "stacked"])
+@pytest.mark.parametrize("n_rows", PREFIX_COUNTS)
+def test_prefix_mask_decode_equals_the_dense_mask(n_rows, form):
+    """`iota < n_rows` through split_resident, the one decode entry point:
+    alone, and stacked [K] under vmap with the all-invalid padding segments
+    `_stack_tree` adds (row count 0)."""
+    import jax
+    if form == "alone":
+        out = jax.jit(_decode_valid)(jax.device_put(_prefix(n_rows)))
+        want = _dense_valid([n_rows], 1, PREFIX_R)[0]
+    else:
+        counts = [n_rows, 5, PREFIX_R]
+        stacked = _stacked([_prefix(n) for n in counts])
+        assert stacked.n_rows.shape == (4,) and stacked.n_rows.dtype == np.int32
+        out = jax.jit(jax.vmap(_decode_valid))(jax.device_put(stacked))
+        want = _dense_valid(counts, 4, PREFIX_R)
+    out = np.asarray(out)
+    assert out.dtype == np.bool_ and out.shape == want.shape
+    np.testing.assert_array_equal(out, want)
+
+
+def _rle_valid_table(n_rows, R):
+    """The 8-run validity table the stack held before (the control)."""
+    vals = np.zeros(8, np.int32)
+    vals[0] = 1 if n_rows else 0
+    return cascade.RleColumn(vals, np.full(8, n_rows, np.int32),
+                             np.asarray(n_rows, np.int32), R, "bool")
+
+
+@pytest.mark.parametrize("kind,form", [("prefix", "alone"),
+                                       ("prefix", "stacked"),
+                                       ("rle", "stacked")])
+def test_validity_decode_compiles_to_no_search(kind, form):
+    """The structural guard: the compiled decode of a row-count validity
+    holds neither a `while` nor a `gather`; the run table's (the control,
+    what the mesh program spent 58% of its time in) holds one."""
+    import jax
+    one = _prefix(1777) if kind == "prefix" \
+        else _rle_valid_table(1777, PREFIX_R)
+    if form == "alone":
+        fn, arg = _decode_valid, one
+    else:
+        fn, arg = jax.vmap(_decode_valid), _stacked([one] * 3)
+    text = jax.jit(fn).lower(arg).compile().as_text()
+    searched = "while" in text or "gather" in text
+    assert searched == (kind == "rle"), text[:2000]
+
+
+def test_prefix_mask_accounting_and_decode_kind():
+    """4 bytes a segment against R bools, counted by the pool's cascade
+    walker; the decode counts under a kind of its own, never `rle`."""
+    import jax
+    K = 4
+    stacked = jax.device_put(
+        _stacked([_prefix(n) for n in (7, 1777, PREFIX_R)], K))
+    assert stacked.cascade_kind == "prefix"
+    assert (stacked.nbytes, stacked.logical_nbytes) == (4 * K, PREFIX_R)
+    assert entry_cascade_bytes({"__valid": stacked, "x": np.zeros(16)}) \
+        == (4 * K, PREFIX_R)
+    assert devicepool.entry_bytes({"__valid": stacked}) == 4 * K
+    before = cascade.decode_stats()
+    jax.jit(jax.vmap(_decode_valid)).lower(stacked)
+    after = cascade.decode_stats()
+    assert after.get("prefix", 0) == before.get("prefix", 0) + 1
+    assert after.get("rle", 0) == before.get("rle", 0)
+
+
 def test_delta_roundtrip_device():
     import jax
     v = np.cumsum(np.random.default_rng(1).integers(

@@ -131,6 +131,96 @@ def test_groupby_uneven_segments(generator, mesh):
     _assert_rows_equal(sorted(plain, key=key), sorted(sharded, key=key))
 
 
+UNEQUAL_ROWS = (2_500, 1, 1_777)     # R = 3,072: no count is aligned to it
+
+
+def _unequal_segments(generator, datasource):
+    """Three day segments of unequal row counts sharing dictionaries."""
+    from druid_tpu.utils.intervals import Interval
+    day = WEEK.width // 7
+    return [generator.segment(
+        n, Interval(WEEK.start + i * day, WEEK.start + (i + 1) * day),
+        datasource=datasource) for i, n in enumerate(UNEQUAL_ROWS)]
+
+
+@pytest.mark.parametrize("n_dev", [2, 4])
+def test_groupby_unequal_row_counts_padded_k(generator, n_dev):
+    """3 segments of unequal row counts on 2 and 4 devices (K pads to 4):
+    each segment's validity is its own row count, a padding segment's is 0.
+    Sharded == per-segment == a numpy reference, `==` (count, long sum and
+    float max are exact merges)."""
+    from tests.conftest import rows_as_frame
+    segs = _unequal_segments(generator, f"unequal{n_dev}")
+    q = GroupByQuery.of(
+        f"unequal{n_dev}", [WEEK], [DefaultDimensionSpec("dimA")],
+        [CountAggregator("rows"), LongSumAggregator("lsum", "metLong"),
+         DoubleMaxAggregator("fmax", "metFloat")], granularity="all")
+    plain, sharded = _run_both(q, segs, make_mesh(n_dev))
+    key = lambda r: r["event"]["dimA"]
+    assert sorted(sharded, key=key) == sorted(plain, key=key)
+    want = {}
+    for frame in map(rows_as_frame, segs):
+        for d, m, f in zip(frame["dimA"], frame["metLong"], frame["metFloat"]):
+            n, total, top = want.get(d, (0, 0, -np.inf))
+            want[d] = (n + 1, total + int(m), max(top, float(f)))
+    got = {r["event"]["dimA"]: (r["event"]["rows"], r["event"]["lsum"],
+                                r["event"]["fmax"]) for r in sharded}
+    assert got == want
+    assert sum(n for n, _, _ in got.values()) == sum(UNEQUAL_ROWS)
+
+
+def test_stack_validity_is_a_row_count_in_the_pool(generator, monkeypatch):
+    """The stack that serves a mesh query holds `__valid` as one int32 a
+    segment (padding segments 0), sharded over the segment axis, counted by
+    the pool's cascade accounting at those 4 bytes; its program's decode
+    counts as `prefix` and no run-table decode enters it."""
+    from druid_tpu.data import cascade, devicepool
+    from druid_tpu.parallel import distributed
+    pool = devicepool.DeviceSegmentPool(budget_bytes=1 << 40)
+    monkeypatch.setattr(devicepool, "_POOL", pool)
+    built = []
+    build_stack = distributed._build_stack
+
+    def capture(*args, **kwargs):
+        built.append(build_stack(*args, **kwargs))
+        return built[-1]
+    monkeypatch.setattr(distributed, "_build_stack", capture)
+    distributed.clear_stack_cache()     # re-home the owner token on this pool
+    distributed.clear_fn_cache()        # decode_stats counts at trace time
+    segs = _unequal_segments(generator, "validity")
+    q = TimeseriesQuery.of("validity", [WEEK], [CountAggregator("rows")],
+                           granularity="all")
+    mesh = make_mesh(4)
+    before = cascade.decode_stats()
+    try:
+        with use_mesh(mesh):
+            rows = QueryExecutor(segs).run(q)
+        after = cascade.decode_stats()
+        snap = pool.snapshot()
+    finally:
+        distributed.clear_stack_cache()
+        distributed.clear_fn_cache()
+    assert rows[0]["result"]["rows"] == sum(UNEQUAL_ROWS)
+    (stack,) = built
+    arrays, R, K = stack[0], stack[2], stack[3]
+    valid = arrays["__valid"]
+    assert isinstance(valid, cascade.PrefixMaskColumn)
+    assert (R, K, valid.padded_rows) == (3_072, 4, 3_072)
+    assert np.asarray(valid.n_rows).tolist() == list(UNEQUAL_ROWS) + [0]
+    assert valid.n_rows.dtype == np.int32
+    assert valid.n_rows.sharding.spec == \
+        distributed.speclayout.layout_for(mesh).time0s()
+    assert devicepool.entry_cascade_bytes({"__valid": valid}) == (4 * K, R)
+    # the pool counts the whole entry the same way, and its decoded
+    # equivalent is K * R of int32 time offsets and of validity bools (one
+    # segment's share in each column, K - 1 in the LogicalBytes correction)
+    assert (snap.cascade_bytes, snap.cascade_logical_bytes) == \
+        devicepool.entry_cascade_bytes(stack)
+    assert snap.stacked_logical_bytes == K * R * (4 + 1) + stack[1].nbytes
+    assert after.get("prefix", 0) == before.get("prefix", 0) + 1
+    assert after.get("rle", 0) == before.get("rle", 0)
+
+
 def test_heterogeneous_column_presence(mesh):
     """A filter column existing in only SOME segments must not shortcut to a
     whole-query zero (const-false plan on segment 0 only)."""

@@ -124,11 +124,14 @@ def test_sharded_path_is_under_spans_and_the_second_request_hits(served):
     rows = -(-ROWS // 1024) * 1024
     stack, = first["stack"]
     assert stack.pop("builtBytes") > SEGMENTS * rows       # > 1 B a row
+    # `validity` names the decode the program ran for `__valid`: the row
+    # count's one compare, on the build and on the pool hit alike
     assert stack == {"segments": SEGMENTS, "devices": DEVICES, "hit": False,
-                     "paddedSegments": 8, "rows": rows}
+                     "paddedSegments": 8, "rows": rows, "validity": "prefix"}
     assert second["stack"] == [{"segments": SEGMENTS, "devices": DEVICES,
                                 "hit": True, "builtBytes": 0,
-                                "paddedSegments": 8, "rows": rows}]
+                                "paddedSegments": 8, "rows": rows,
+                                "validity": "prefix"}]
 
 
 def test_projection_override_is_visible_on_the_plan_span(served, monkeypatch):
