@@ -5,7 +5,7 @@
 One process, and the only one that touches JAX. It refuses to start without
 a TPU (non-zero exit, no result line), then drives the path a user drives:
 
-    headline dataset (bench.headline_segments, 100M rows / 8 segments,
+    headline dataset (headline_segments below, 100M rows / 8 segments,
     4 columns + time, generated from --seed)
       -> persisted with the default (V2) segment writer
       -> cli.build_historical(segments_dir=...)      mmap load, H2D staging
@@ -46,6 +46,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 HEADLINE_ROWS = 100_000_000
+HEADLINE_SEED = 1234
 SEGMENTS = 8
 #: a cold first request pays projection sorts, H2D staging and compiles for
 #: eight 12.5M-row segments — well past the client's default 300 s
@@ -59,6 +60,23 @@ class SmokeFailure(Exception):
 
 def log(msg: str) -> None:
     print(msg, flush=True)
+
+
+def headline_segments(rows: int, n_segments: int, seed: int = HEADLINE_SEED):
+    """The headline dataset: one day, `rows` rows over `n_segments` segments,
+    two string dimensions and two metrics, made from `seed`."""
+    from druid_tpu.data.generator import ColumnSpec, DataGenerator
+    from druid_tpu.utils.intervals import Interval
+    schema = (
+        ColumnSpec("dimA", "string", cardinality=100, distribution="uniform"),
+        ColumnSpec("dimB", "string", cardinality=1000, distribution="zipf"),
+        ColumnSpec("metLong", "long", low=0, high=10_000),
+        ColumnSpec("metFloat", "float", distribution="normal", mean=100.0,
+                   std=25.0),
+    )
+    return DataGenerator(schema, seed=seed).segments(
+        n_segments, rows // n_segments,
+        Interval.of("2026-01-01", "2026-01-02"), datasource="bench")
 
 
 def host_rss_bytes() -> int:
@@ -545,7 +563,6 @@ def main(argv=None) -> int:
         return 2
     # the repo before the first stdout line: run alone, without the
     # program, this script fails here having printed nothing
-    import bench
     from druid_tpu import native
     # the pure-python LZ4 fallback at this scale looks like a hang
     native.require()
@@ -556,8 +573,8 @@ def main(argv=None) -> int:
             f"{HEADLINE_ROWS:,} (widths, cardinalities and the "
             f"{SEGMENTS} segments unchanged)")
     t0 = time.monotonic()
-    seed = bench.HEADLINE_SEED if args.seed is None else args.seed
-    segments = bench.headline_segments(args.rows, SEGMENTS, seed=seed)
+    seed = HEADLINE_SEED if args.seed is None else args.seed
+    segments = headline_segments(args.rows, SEGMENTS, seed=seed)
     log(f"generated {sum(s.n_rows for s in segments):,} rows in "
         f"{len(segments)} segments from seed {seed} "
         f"({time.monotonic() - t0:.1f}s)")

@@ -118,10 +118,6 @@ FLAGS = {
         default="1", semantics="latch",
         doc="Standing-query incremental maintenance opt-out; 0 "
             "restores re-scan on every tick (engine/standing.py)."),
-    "DRUID_TPU_STRATEGY": Flag(
-        default="", semantics="latch",
-        doc="Grouping strategy override for measurement runs "
-            "(engine/grouping.py, tools/chip_suite.py)."),
     "DRUID_TPU_UNIDIM_TTL_S": Flag(
         default="900", semantics="latch",
         doc="Unidimensional result-cache TTL in seconds; <= 0 "

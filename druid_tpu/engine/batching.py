@@ -12,7 +12,7 @@ operator inputs across queries/segments — here:
   2. pad rows up a powers-of-two ladder (rungs = 2^i × BATCH_ROW_ALIGN) and
      pin chunk sizes to powers of two, so compile counts stay bounded per
      structure (row ladder × K ladder);
-  3. run the shared per-segment body (grouping.make_stacked_segment_fn)
+  3. run the shared per-segment body (grouping.traced_segment)
      UNROLLED over the chunk's pooled DeviceBlocks inside ONE jitted
      program — HBM-resident blocks feed the program directly, no
      re-staging, and XLA schedules the K independent reduction subgraphs
@@ -21,7 +21,7 @@ operator inputs across queries/segments — here:
 
 Stragglers — ineligible segments and undersized buckets — fall back to the
 per-segment path. Parity is structural, not coincidental: the batched
-program runs the SAME traced body (fuse_filter_update) over the same staged
+program runs the SAME traced body (grouping.traced_segment) over the same staged
 columns and post-processes states with the same host_post, so results are
 bit-identical to per-segment execution.
 
@@ -32,6 +32,7 @@ cluster/dataserver.py).
 from __future__ import annotations
 
 import collections
+import functools
 import logging
 import os
 import threading
@@ -41,7 +42,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from druid_tpu.data import cascade
-from druid_tpu.data.devicepool import entry_bytes
 from druid_tpu.data.segment import DEFAULT_ROW_ALIGN, Segment
 from druid_tpu.engine import filters as filters_mod
 from druid_tpu.engine import grouping
@@ -52,11 +52,11 @@ from druid_tpu.engine.contracts import (BATCH_MAX_SEGMENT_ROWS,
 from druid_tpu.engine.filters import ConstNode
 from druid_tpu.engine.grouping import (GroupPlan, GroupSpec, KeyDim,
                                        SegmentPartial, assemble_stacked_aux,
-                                       aux_equal, keydims_equal,
-                                       make_stacked_segment_fn,
-                                       needed_columns,
+                                       aux_equal, common_window,
+                                       fetch_partials, keydims_equal,
                                        plan_grouped_aggregate,
                                        run_grouped_aggregate,
+                                       stacked_origins, traced_segment,
                                        windowed_window)
 from druid_tpu.engine.kernels import AggKernel
 from druid_tpu.obs.trace import span as trace_span
@@ -208,9 +208,9 @@ def row_rung(n_rows: int) -> int:
 
 @dataclass
 class _Plan:
-    """One segment's per-query plan, the unit of shape-bucket grouping.
-    Wraps the shared host-side GroupPlan (grouping.plan_grouped_aggregate)
-    with the batching-only derivations (ladder rung, bucket digest); the
+    """One segment's per-query plan, the unit of shape-bucket grouping: the
+    shared host-side GroupPlan (grouping.plan_grouped_aggregate) with what
+    only batching derives (ladder rung, descriptors, bucket digest). The
     GroupPlan rides along so straggler fallback re-executes WITHOUT
     re-planning (run_grouped_aggregate(plan=...)).
 
@@ -220,7 +220,6 @@ class _Plan:
     not from the chunk reference. `req` tags the owning request — the
     queryId of the split-back."""
     segment: Segment
-    kds: Tuple[KeyDim, ...]
     index: int                       # position in the caller's segment list
     gplan: GroupPlan
     intervals: Tuple[Interval, ...] = ()
@@ -228,34 +227,10 @@ class _Plan:
     req: int = 0                     # owning request (multi-query split-back)
     #: False = straggler (runs per-segment, but still through this gplan)
     eligible: bool = False
-    f_aux: List[np.ndarray] = None
-    k_aux: List[np.ndarray] = None
-    columns: Tuple[str, ...] = ()
-    col_dtypes: Dict[str, np.dtype] = None
     rung: int = 0
     packs: Tuple = ()                # pack descriptor (data/packed.py)
     cascades: Tuple = ()             # cascade descriptor (data/cascade.py)
     digest: Tuple = None             # hashable shape-bucket prefilter
-
-    @property
-    def spec(self) -> GroupSpec:
-        return self.gplan.spec
-
-    @property
-    def filter_node(self):
-        return self.gplan.filter_node
-
-    @property
-    def kernels(self) -> List[AggKernel]:
-        return self.gplan.kernels
-
-    @property
-    def vc_plans(self) -> Tuple:
-        return self.gplan.vc_plans
-
-    @property
-    def vc_luts(self) -> List[np.ndarray]:
-        return self.gplan.vc_luts
 
 
 def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
@@ -274,7 +249,7 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
     kds = tuple(kds)
     gplan = plan_grouped_aggregate(segment, intervals, granularity, kds,
                                    aggs, flt, virtual_columns)
-    plan = _Plan(segment=segment, kds=kds, index=index, gplan=gplan,
+    plan = _Plan(segment=segment, index=index, gplan=gplan,
                  intervals=tuple(intervals), granularity=granularity)
     if segment.n_rows > BATCH_MAX_SEGMENT_ROWS:
         return plan
@@ -310,9 +285,7 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
         # constant-false: the per-segment path skips the device entirely —
         # batching it would only waste a stacked slot
         return plan
-    needed, columns = needed_columns(segment, kds, aggs, flt, virtual_columns,
-                                     filter_node=filter_node,
-                                     kernels=gplan.kernels)
+    columns = gplan.columns
     # complex (2-D) metric columns — HLL registers, sketch states — stack
     # like any other column now that the mask is in-program; their width is
     # a compile-shape dimension, so it joins the digest below
@@ -320,19 +293,7 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
         (c, np.asarray(segment.metrics[c].values).shape[1:])
         for c in columns if c in segment.metrics
         and np.asarray(segment.metrics[c].values).ndim > 1))
-    col_dtypes: Dict[str, np.dtype] = {
-        "__time_offset": np.dtype(np.int32), "__valid": np.dtype(bool)}
-    for c in columns:
-        col_dtypes[c] = np.dtype(np.int32) if c in segment.dims \
-            else np.dtype(segment.staged_dtype(c))
-    for d in kds:
-        if d.host_ids is not None:
-            col_dtypes[d.column] = np.dtype(np.int32)
     plan.eligible = True
-    plan.f_aux = filter_node.aux_arrays() if filter_node else []
-    plan.k_aux = [a for k in kernels for a in k.aux_arrays()]
-    plan.columns = columns
-    plan.col_dtypes = col_dtypes
     plan.rung = row_rung(segment.n_rows)
     # cascade + pack descriptors (pure fns of column stats, pow2-quantized
     # widths/bases/run counts precisely so near-identical segments keep
@@ -350,7 +311,8 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
     # are per-segment mapped args (iv_rel), only their COUNT is shape
     # (already in the structure sig).
     plan.digest = (sig, plan.rung, columns, col_shapes,
-                   tuple(sorted((c, str(d)) for c, d in col_dtypes.items())),
+                   tuple(sorted((c, str(d))
+                                for c, d in gplan.col_dtypes.items())),
                    str(granularity), spec.num_buckets)
     return plan
 
@@ -359,10 +321,11 @@ def _compatible(ref: _Plan, cand: _Plan) -> bool:
     """Digest-equal plans still carry array-valued constants (filter LUTs,
     kernel aux, dim remaps, vc string LUTs) that become SHARED aux in the
     stacked program — they must be value-equal."""
-    return (keydims_equal(ref.kds, cand.kds)
-            and aux_equal(ref.f_aux, cand.f_aux)
-            and aux_equal(ref.k_aux, cand.k_aux)
-            and aux_equal(ref.vc_luts, cand.vc_luts))
+    a, b = ref.gplan, cand.gplan
+    return (keydims_equal(a.spec.dims, b.spec.dims)
+            and aux_equal(a.f_aux, b.f_aux)
+            and aux_equal(a.k_aux, b.k_aux)
+            and aux_equal(a.vc_luts, b.vc_luts))
 
 
 def _shape_buckets(plans: Sequence[_Plan]) -> List[List[_Plan]]:
@@ -400,7 +363,7 @@ def _pow2_chunks(group: List[_Plan]) -> Tuple[List[List[_Plan]], List[_Plan]]:
 # The batched device program
 # ---------------------------------------------------------------------------
 
-def _build_batched_fn(spec: GroupSpec, kds: Tuple[KeyDim, ...], filter_node,
+def _build_batched_fn(spec: GroupSpec, filter_node,
                       kernels: List[AggKernel], vc_plans: Tuple, K: int):
     """One jitted program for a whole shape bucket: the shared per-segment
     body UNROLLED over the K pooled blocks. Per-segment origins (time0,
@@ -412,7 +375,8 @@ def _build_batched_fn(spec: GroupSpec, kds: Tuple[KeyDim, ...], filter_node,
     fall out without a stacked-axis slice."""
     import jax
 
-    body = make_stacked_segment_fn(spec, kds, filter_node, kernels, vc_plans)
+    body = functools.partial(traced_segment, spec, filter_node, kernels,
+                             vc_plans)
 
     def fn(blocks, time0s, iv_rel, bucket_off, aux):
         return tuple(body(blocks[i], time0s[i], iv_rel[i], bucket_off[i], aux)
@@ -430,31 +394,22 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
     bucket start — is derived from the plan's OWN intervals, so
     cross-query mates produce exactly the partials their own serial run
     would."""
-    import jax
-
-    ref = chunk[0]
-    R = ref.rung
+    ref = chunk[0].gplan
+    R = chunk[0].rung
     K = len(chunk)                  # a power of two by _pow2_chunks
 
-    def _windowed_all():
-        w_all = 0
-        for p in chunk:
-            w = windowed_window(p.segment, p.intervals, p.granularity,
-                                p.spec)
-            if not w:
-                return 0
-            w_all = max(w_all, w)
-        return w_all
-
     strategy, window = grouping.select_strategy(
-        ref.spec, ref.kernels, ref.col_dtypes, R, _windowed_all)
+        ref.spec, ref.kernels, ref.col_dtypes, R,
+        lambda: common_window(
+            windowed_window(p.segment, p.intervals, p.granularity,
+                            p.gplan.spec) for p in chunk))
     if strategy == "projection":
         # sorted projections are per-segment layouts a stacked program
         # cannot share — and projection-grade segments are big enough that
         # per-segment dispatch overhead is already amortized
         return None
     for p in chunk:
-        p.spec.strategy, p.spec.window = strategy, window
+        p.gplan.spec.strategy, p.gplan.spec.window = strategy, window
 
     blocks = [p.segment.device_block(list(ref.columns), row_align=R)
               for p in chunk]
@@ -468,49 +423,34 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
     # entirely different bitmap filters under one shared program structure)
     with filters_mod.words_span(segments=K):
         bmp_per_slot = filters_mod.stage_device_bitmaps_multi(
-            [(p.segment, p.filter_node, p.kernels) for p in chunk], R)
+            [(p.segment, p.gplan.filter_node, p.gplan.kernels)
+             for p in chunk], R)
     arrs_per_slot = []
     for p, b, bmp in zip(chunk, blocks, bmp_per_slot):
         arrs = dict(b.arrays)
-        for d in p.kds:
+        for d in p.gplan.spec.dims:
             if d.host_ids is not None:
                 arrs[d.column] = grouping._pad_device_cached(
                     p.segment, d.ids_key, d.host_ids, R, 0)
         arrs.update(bmp)
         arrs_per_slot.append(arrs)
 
-    clip_lo, clip_hi = -(2**31) + 1, 2**31 - 1
-    iv_rel = np.zeros((K, max(len(ref.intervals), 1), 2), dtype=np.int32)
-    time0s = np.zeros((K,), dtype=np.int64)
-    bucket_off = np.zeros((K,), dtype=np.int32)
-    for i, p in enumerate(chunk):
-        t0 = p.segment.interval.start
-        time0s[i] = t0
-        for j, ivl in enumerate(p.intervals):
-            iv_rel[i, j, 0] = min(max(ivl.start - t0, clip_lo), clip_hi)
-            iv_rel[i, j, 1] = min(max(ivl.end - t0, clip_lo), clip_hi)
-        if p.spec.bucket_mode == "uniform":
-            bucket_off[i] = min(max(int(p.spec.bucket_starts[0]) - t0,
-                                    clip_lo), clip_hi)
-
-    aux = assemble_stacked_aux(ref.spec, ref.kds, ref.f_aux, ref.k_aux,
-                               ref.granularity, ref.vc_luts)
+    time0s, iv_rel, bucket_off = stacked_origins(
+        [p.segment for p in chunk], [p.intervals for p in chunk],
+        [p.gplan.spec for p in chunk])
+    aux = assemble_stacked_aux(ref.spec, ref.f_aux, ref.k_aux, ref.vc_luts)
     sig = "batched|" + grouping._structure_sig(
-        ref.spec, len(ref.intervals), ref.filter_node, ref.kernels,
-        ref.vc_plans, ref.packs, ref.cascades) + f"|K={K}|R={R}"
+        ref.spec, len(chunk[0].intervals), ref.filter_node, ref.kernels,
+        ref.vc_plans, chunk[0].packs, chunk[0].cascades) + f"|K={K}|R={R}"
     with _JIT_CACHE_LOCK:
         fn = _JIT_CACHE.get(sig)
         # the miss IS the compile event (jit traces/compiles on the first
         # call below) — timing stays at the existing dispatch boundary
         compiled = fn is None
         if fn is None:
-            fn = _build_batched_fn(ref.spec, ref.kds, ref.filter_node,
-                                   ref.kernels, ref.vc_plans, K)
-            # kds STRUCTURE is a pure function of spec.dims plus the
-            # packs/cascades folded into sig; the per-segment id arrays
-            # inside kds enter the traced fn as runtime arguments, never
-            # as trace constants
-            _JIT_CACHE[sig] = fn  # druidlint: disable=unkeyed-trace-input
+            fn = _build_batched_fn(ref.spec, ref.filter_node, ref.kernels,
+                                   ref.vc_plans, K)
+            _JIT_CACHE[sig] = fn
             while len(_JIT_CACHE) > _JIT_CACHE_CAP:
                 _JIT_CACHE.popitem(last=False)
         else:
@@ -528,20 +468,9 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
     # falls back per-segment and must not double-bill the scoreboard
     dispatch_mod.record("batched")
 
-    out: List[SegmentPartial] = []
-    # engine/fetch: the host conversion is where this thread blocks for the
-    # enqueued program (wait-for-device + D2H + host_post) — no added sync
-    with trace_span("engine/fetch", segments=K) as fetch_span:
-        if fetch_span is not None:
-            fetch_span.attrs["bytes"] = entry_bytes(outs)
-        for p, (counts, states) in zip(chunk, outs):
-            states_h = jax.tree.map(lambda x: np.asarray(x), states)
-            host_states = {k.name: k.host_post(s, p.segment)
-                           for k, s in zip(p.kernels, states_h)}
-            out.append(SegmentPartial(
-                segment=p.segment, spec=p.spec,
-                counts=np.asarray(counts, dtype=np.int64),
-                states=host_states, kernels=p.kernels))
+    out = fetch_partials(
+        [(p.segment, p.gplan.spec, p.gplan.kernels) for p in chunk], outs,
+        segments=K)
     _STATS.record_batch(K, sum(p.segment.n_rows for p in chunk), K * R)
     return out
 
@@ -574,8 +503,8 @@ def run_with_batching(segs: Sequence[Segment], intervals: Sequence[Interval],
     if not any(len(b) >= BATCH_MIN_SEGMENTS for b in buckets):
         # nothing batches — but the per-segment planning already happened:
         # run the plain path HERE so the plans are executed, not rebuilt
-        return [_run_straggler(p, intervals, granularity, aggs, flt,
-                               virtual_columns, check, first=(i == 0))
+        return [_run_straggler(p, aggs, flt, virtual_columns, check,
+                               first=(i == 0))
                 for i, p in enumerate(plans)]
 
     results: List[Optional[SegmentPartial]] = [None] * len(segs)
@@ -599,20 +528,19 @@ def run_with_batching(segs: Sequence[Segment], intervals: Sequence[Interval],
         _STATS.record_fallback(n_fallback)
     for i, p in enumerate(plans):
         if results[i] is None:
-            results[i] = _run_straggler(p, intervals, granularity, aggs,
-                                        flt, virtual_columns, check,
+            results[i] = _run_straggler(p, aggs, flt, virtual_columns, check,
                                         first=not dispatched and i == 0)
     return results
 
 
-def _run_straggler(p: _Plan, intervals, granularity, aggs, flt,
-                   virtual_columns, check, first: bool) -> SegmentPartial:
+def _run_straggler(p: _Plan, aggs, flt, virtual_columns, check,
+                   first: bool) -> SegmentPartial:
     """Per-segment execution reusing the plan built for bucket grouping
     (the ROADMAP's 'stragglers are planned twice' follow-on, closed)."""
     if check is not None and not first:
         check()
     return run_grouped_aggregate(
-        p.segment, intervals, granularity, p.kds, aggs, flt,
+        p.segment, p.intervals, p.granularity, p.gplan.spec.dims, aggs, flt,
         virtual_columns=virtual_columns, plan=p.gplan)
 
 
@@ -737,8 +665,7 @@ def run_multi_with_batching(work: Sequence[BatchWork],
             for i, p in enumerate(plans):
                 if res[i] is None:
                     res[i] = _run_straggler(
-                        p, w.intervals, w.granularity, w.aggs, w.flt,
-                        w.virtual_columns, w.check,
+                        p, w.aggs, w.flt, w.virtual_columns, w.check,
                         first=not dispatched and i == 0)
         except Exception as e:
             out.append(e)

@@ -39,9 +39,9 @@ measured rates on a v5e chip at 12.5M rows):
 from __future__ import annotations
 
 import collections
+import functools
 import logging
 import math
-import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -51,7 +51,7 @@ import numpy as np
 
 from druid_tpu.data import cascade as cascade_mod
 from druid_tpu.data import packed as packed_mod
-from druid_tpu.data.segment import DeviceBlock, Segment
+from druid_tpu.data.segment import DEFAULT_ROW_ALIGN, Segment
 from druid_tpu.data.devicepool import entry_bytes
 from druid_tpu.engine import filters as filters_mod
 from druid_tpu.engine import megakernel, pallas_agg
@@ -174,14 +174,36 @@ class Projection:
     max_span: int           # max key span over WINDOW_BLOCK-row blocks
 
 
+def _key_structure(kind: str, granularity: Granularity,
+                   intervals: Sequence[Interval],
+                   dims: Sequence[KeyDim]) -> Tuple:
+    """Cache identity of what one (granularity, intervals, key dims) derives
+    from a segment: its fused keys, its projection, its window span."""
+    return (kind, str(granularity),
+            tuple((iv.start, iv.end) for iv in intervals),
+            tuple((d.column, d.cardinality,
+                   None if d.remap is None else d.remap.tobytes())
+                  for d in dims))
+
+
+def _max_block_span(keys: np.ndarray) -> int:
+    """Widest span of valid keys (-1 = invalid row) over the
+    WINDOW_BLOCK-row blocks of `keys`; 1 when no row is valid."""
+    n, blk = keys.shape[0], WINDOW_BLOCK
+    top = np.iinfo(np.int64).max
+    kp = np.full(max(-(-n // blk), 1) * blk, top, dtype=np.int64)
+    kp[:n] = np.where(keys >= 0, keys, top)
+    lo = kp.reshape(-1, blk).min(axis=1)
+    hi = np.where(kp == top, -1, kp).reshape(-1, blk).max(axis=1)
+    live = hi >= 0
+    return int((hi[live] - lo[live] + 1).max()) if live.any() else 1
+
+
 def build_projection(segment: Segment, intervals: Sequence[Interval],
                      granularity: Granularity,
                      spec: "GroupSpec") -> Projection:
-    cache_key = ("projection", str(granularity),
-                 tuple((iv.start, iv.end) for iv in intervals),
-                 tuple((d.column, d.cardinality,
-                        None if d.remap is None else d.remap.tobytes())
-                       for d in spec.dims))
+    cache_key = _key_structure("projection", granularity, intervals,
+                               spec.dims)
 
     def _compute():
         # runs only when the projection is BUILT (a miss of the segment's
@@ -207,22 +229,10 @@ def build_projection(segment: Segment, intervals: Sequence[Interval],
             keys[n_invalid:] = np.cumsum(newgrp) - 1
         else:
             unique = np.zeros(0, dtype=np.int64)
-        # max masked key span over WINDOW_BLOCK-row blocks (the sorted layout
-        # keeps this near the per-block distinct-group count)
-        blk = WINDOW_BLOCK
-        npad = ((n + blk - 1) // blk) * blk if n else blk
-        kp = np.full(npad, np.iinfo(np.int32).max, dtype=np.int64)
-        kp[:n] = np.where(keys >= 0, keys.astype(np.int64),
-                          np.iinfo(np.int32).max)
-        kb = kp.reshape(-1, blk)
-        lo = kb.min(axis=1)
-        kneg = np.where(kp == np.iinfo(np.int32).max,
-                        np.iinfo(np.int64).min, kp).reshape(-1, blk)
-        hi = kneg.max(axis=1)
-        span = np.maximum(hi - lo + 1, 1)
-        span = int(span[hi >= 0].max()) if (hi >= 0).any() else 1
+        # the sorted layout keeps the span near the per-block
+        # distinct-group count
         return Projection(order=order.astype(np.int32), keys=keys,
-                          unique=unique, max_span=span)
+                          unique=unique, max_span=_max_block_span(keys))
 
     return segment.aux_cached(cache_key, _compute)
 
@@ -274,19 +284,16 @@ def make_group_spec(segment: Segment, intervals: Sequence[Interval],
         group_card *= max(d.cardinality, 1)
     dense_total = B * group_card
 
+    spec = GroupSpec(bucket_starts=bucket_starts, bucket_mode=bucket_mode,
+                     uniform_period=period, uniform_first_offset=first_off,
+                     host_bucket_ids=host_bucket, key_mode="dense", dims=dims,
+                     num_total=pad_pow2(dense_total),
+                     host_bucket_cache=host_bucket_cache)
     if not dims or dense_total <= DENSE_GROUP_LIMIT:
-        return GroupSpec(bucket_starts=bucket_starts, bucket_mode=bucket_mode,
-                         uniform_period=period, uniform_first_offset=first_off,
-                         host_bucket_ids=host_bucket, key_mode="dense",
-                         dims=dims, num_total=pad_pow2(dense_total),
-                         host_bucket_cache=host_bucket_cache)
+        return spec
 
     # host-compacted key path: fuse (bucket, dim ids) host-side and np.unique
-    cache_key = ("fused_keys", str(granularity),
-                 tuple((iv.start, iv.end) for iv in intervals),
-                 tuple((d.column, d.cardinality,
-                        None if d.remap is None else d.remap.tobytes())
-                       for d in dims))
+    cache_key = _key_structure("fused_keys", granularity, intervals, dims)
 
     def _compute_keys():
         key = _fused_raw_keys(segment, bucket_mode, bucket_starts, period, B,
@@ -298,14 +305,11 @@ def make_group_spec(segment: Segment, intervals: Sequence[Interval],
             uniq = uniq[1:]
         return uniq, compact.astype(np.int32)
 
-    uniq, compact = segment.aux_cached(cache_key, _compute_keys)
-    return GroupSpec(bucket_starts=bucket_starts, bucket_mode=bucket_mode,
-                     uniform_period=period, uniform_first_offset=first_off,
-                     host_bucket_ids=host_bucket, key_mode="host", dims=dims,
-                     host_keys=compact, host_unique=uniq,
-                     num_total=pad_pow2(max(len(uniq), 1)),
-                     host_keys_cache=cache_key,
-                     host_bucket_cache=host_bucket_cache)
+    spec.host_unique, spec.host_keys = segment.aux_cached(cache_key,
+                                                          _compute_keys)
+    spec.key_mode, spec.host_keys_cache = "host", cache_key
+    spec.num_total = pad_pow2(max(len(spec.host_unique), 1))
+    return spec
 
 
 # ---------------------------------------------------------------------------
@@ -379,18 +383,15 @@ def eval_virtual_columns(arrays: Dict, t_abs, vc_plans, it=None) -> Dict:
     return arrays
 
 
-def fuse_filter_update(arrays: Dict, mask, key, it,
-                       dim_cols: Tuple, has_remap: Tuple,
+def fuse_filter_update(arrays: Dict, mask, key, it, dims: Sequence[KeyDim],
                        filter_node: Optional[FilterNode],
                        kernels: Sequence[AggKernel], num_total: int,
                        strategy: str = "mixed", window: int = 0,
                        packed_cols: Optional[Dict] = None):
-    """Traced: the shared tail of the grouped-aggregate program — fuse dim
-    ids into the key (through optional remap tables), apply the filter mask,
-    and run every kernel's segmented reduction via the selected strategy.
-    Both the per-segment (_build_device_fn) and sharded
-    (parallel/distributed.py) builders call this, so keying/update semantics
-    cannot diverge between paths.
+    """Traced: the tail of the grouped-aggregate body (traced_segment) —
+    fuse the ids of `dims` into the key (through optional remap tables),
+    apply the filter mask, and run every kernel's segmented reduction via
+    the selected strategy.
 
     `arrays` is the DENSE view (the program top already decoded any
     bit-packed columns — data/packed.py); `packed_cols` carries the
@@ -400,11 +401,11 @@ def fuse_filter_update(arrays: Dict, mask, key, it,
     import jax
     import jax.numpy as jnp
 
-    for i in range(len(dim_cols)):
-        if dim_cols[i] is None:
+    for d in dims:
+        if d.column is None:
             continue
-        ids = arrays[dim_cols[i]]
-        if has_remap[i]:
+        ids = arrays[d.column]
+        if d.remap is not None:
             remap = next(it)
             ids = remap[ids]
             mask = mask & (ids >= 0)
@@ -566,47 +567,13 @@ def windowed_window(segment: Segment, intervals: Sequence[Interval],
     0. Conservative: spans are measured over ALL interval-valid rows; any
     query filter only shrinks the row set, so a sub-mask can never widen a
     block's span. Cached per (segment, key structure)."""
-    key = ("windowed_span", str(granularity),
-           tuple((iv.start, iv.end) for iv in intervals),
-           tuple((d.column, d.cardinality,
-                  None if d.remap is None else d.remap.tobytes())
-                 for d in spec.dims))
+    key = _key_structure("windowed_span", granularity, intervals, spec.dims)
 
     def _compute():
-        n = segment.n_rows
-        if n == 0:
-            return 1
-        if spec.bucket_mode == "all":
-            b = np.zeros(n, dtype=np.int64)
-            ok = np.ones(n, dtype=bool)
-        elif spec.bucket_mode == "uniform":
-            b = (segment.time_ms - int(spec.bucket_starts[0])) \
-                // spec.uniform_period
-            ok = (b >= 0) & (b < spec.num_buckets)
-        else:
-            b = spec.host_bucket_ids[:n].astype(np.int64)
-            ok = b >= 0
-        k = b
-        for d in spec.dims:
-            if d.column is None:
-                continue
-            ids = d.host_ids if d.host_ids is not None \
-                else segment.dims[d.column].ids
-            if d.remap is not None:
-                ids = d.remap[ids]
-                ok = ok & (ids >= 0)
-            k = k * d.cardinality + np.maximum(ids, 0)
-        blk = WINDOW_BLOCK
-        npad = ((n + blk - 1) // blk) * blk
-        kp = np.full(npad, np.iinfo(np.int64).max, dtype=np.int64)
-        kp[:n] = np.where(ok, k, np.iinfo(np.int64).max)
-        kb = kp.reshape(-1, blk)
-        lo = kb.min(axis=1)
-        kneg = np.where(kp == np.iinfo(np.int64).max,
-                        np.iinfo(np.int64).min, kp).reshape(-1, blk)
-        hi = kneg.max(axis=1)
-        span = int(np.maximum(hi - lo + 1, 1).max())
-        return span
+        return _max_block_span(_fused_raw_keys(
+            segment, spec.bucket_mode, spec.bucket_starts,
+            spec.uniform_period, spec.num_buckets, spec.host_bucket_ids,
+            spec.dims))
 
     span = segment.aux_cached(key, _compute)
     for w in WINDOW_CHOICES:
@@ -615,11 +582,9 @@ def windowed_window(segment: Segment, intervals: Sequence[Interval],
     return 0
 
 
-#: measurement override (tools/chip_suite.py; env DRUID_TPU_STRATEGY):
-#: force an ELIGIBLE strategy so cutovers are tuned from measured
-#: per-backend numbers, not assumptions. Ineligible forces fall through
-#: to normal selection.
-FORCE_STRATEGY: Optional[str] = os.environ.get("DRUID_TPU_STRATEGY") or None
+#: test override (tests set the module attribute): force an ELIGIBLE
+#: strategy; an ineligible force falls through to normal selection.
+FORCE_STRATEGY: Optional[str] = None
 
 
 def select_strategy(spec: GroupSpec, kernels: Sequence[AggKernel],
@@ -627,9 +592,8 @@ def select_strategy(spec: GroupSpec, kernels: Sequence[AggKernel],
                     windowed_w) -> Tuple[str, int]:
     """Pick the reduction strategy for one (segment, query) plan.
 
-    windowed_w: 0/W precomputed by the caller (host span check over every
-    participating segment), or a callable invoked lazily only when the
-    windowed path is actually a candidate."""
+    windowed_w: the host span check over every participating segment (0 or
+    W), called only when the windowed path is actually a candidate."""
     from druid_tpu.engine.mmagg import MM_GROUP_LIMIT
     num = spec.num_total
     plans = [k.mm_plan(col_dtypes, padded_rows) for k in kernels]
@@ -646,7 +610,7 @@ def select_strategy(spec: GroupSpec, kernels: Sequence[AggKernel],
             # mislabeled timings are worse than a fallthrough
             return "blocked", 0
         if f == "windowed" and blocked_ok:
-            w = windowed_w() if callable(windowed_w) else windowed_w
+            w = windowed_w()
             if w:
                 return "windowed", w
         if f == "projection" and blocked_ok:
@@ -656,7 +620,7 @@ def select_strategy(spec: GroupSpec, kernels: Sequence[AggKernel],
     if mm_ok and num <= 2048:
         return "mm", 0
     if num > BLOCKED_GROUP_LIMIT and blocked_ok and spec.key_mode == "dense":
-        w = windowed_w() if callable(windowed_w) else windowed_w
+        w = windowed_w()
         if w:
             return "windowed", w
     if blocked_ok and num <= BLOCKED_GROUP_LIMIT:
@@ -774,11 +738,12 @@ def _structure_sig(spec: GroupSpec, n_intervals: int, filter_node, kernels,
 
 def _build_device_fn(spec: GroupSpec, n_intervals: int,
                      filter_node: Optional[FilterNode],
-                     kernels: List[AggKernel],
-                     vc_plans: Tuple = ()):
-    """Build the traced program. Structure-only closure: every segment-specific
-    constant arrives via `aux` (device arrays), so one jitted callable serves
-    every segment with the same structure.
+                     kernels: List[AggKernel], vc_plans: Tuple = ()):
+    """Build the per-segment program: the shared body (traced_segment) with
+    the segment's origins taken off the head of `aux` (_assemble_aux).
+    Structure-only closure: every segment-specific constant arrives via
+    `aux` (device arrays), so one jitted callable serves every segment with
+    the same structure.
 
     The "megakernel" strategy's callable takes a third `carries` argument —
     the previous execution's raw accumulator grids, donated
@@ -788,68 +753,11 @@ def _build_device_fn(spec: GroupSpec, n_intervals: int,
     to fresh zeros). `keep_unused` holds the carries in the signature:
     they exist purely as donatable buffers, never as data."""
     import jax
-    import jax.numpy as jnp
-
-    bucket_mode, key_mode = spec.bucket_mode, spec.key_mode
-    num_total = spec.num_total
-    n_dims = len(spec.dims)
-    dim_cols = tuple(d.column for d in spec.dims)
-    has_remap = tuple(d.remap is not None for d in spec.dims)
 
     def fn(arrays: Dict[str, object], aux: Tuple, carries: Tuple = ()):
-        it = iter(aux)
-        # decode compressed columns at the program top: HBM keeps the
-        # packed/RLE/delta/LZ4 representation, XLA fuses the decode into
-        # every consumer; the pallas strategy additionally receives the
-        # raw packed words (packed_cols, FOR included) and unpacks per
-        # tile inside the kernel instead (data/cascade.split_resident is
-        # the ONE decode entry point)
-        packed_cols, arrays = cascade_mod.split_resident(arrays)
-        t = arrays["__time_offset"]
-        mask = arrays["__valid"]
-
-        if vc_plans:
-            time0 = next(it)
-            # absolute __time needs all 64 bits (epoch millis overflow
-            # int32); engine/__init__ enables x64 before any trace runs
-            arrays = eval_virtual_columns(arrays, t.astype(jnp.int64) + time0,  # druidlint: disable=x64-dtype
-                                          vc_plans, it)
-
-        # time-in-intervals
-        iv = next(it)  # int32 [k, 2]
-        within = (t[:, None] >= iv[None, :, 0]) & (t[:, None] < iv[None, :, 1])
-        mask = mask & jnp.any(within, axis=1)
-
-        # bucket ids
-        if key_mode == "host":
-            key = arrays["__key"]
-            mask = mask & (key >= 0)
-            dims_for_key = ()
-            remaps_for_key = ()
-        else:
-            if bucket_mode == "all":
-                key = jnp.zeros(t.shape, dtype=jnp.int32)
-            elif bucket_mode == "uniform":
-                first_off = next(it)
-                period = next(it)
-                # int32 bucket math: offsets are int32 by construction and
-                # uniform periods (≤ week) fit int32; 64-bit div would be
-                # limb-emulated on TPU
-                b = (t - first_off) // period
-                nb = next(it)  # num buckets as device scalar
-                mask = mask & (b >= 0) & (b < nb)
-                key = b.astype(jnp.int32)
-            else:
-                key = arrays["__bucket"]
-                mask = mask & (key >= 0)
-            dims_for_key = dim_cols
-            remaps_for_key = has_remap
-
-        return fuse_filter_update(arrays, mask, key, it, dims_for_key,
-                                  remaps_for_key, filter_node, kernels,
-                                  num_total, strategy=spec.strategy,
-                                  window=spec.window,
-                                  packed_cols=packed_cols or None)
+        time0, iv_rel, bucket_off = aux[:3]
+        return traced_segment(spec, filter_node, kernels, vc_plans, arrays,
+                              time0, iv_rel, bucket_off, aux[3:])
 
     # the program's stable name: the profiler's modules and the dispatch
     # spans' `program` read `seg_agg_<strategy>`, never a shape
@@ -861,27 +769,103 @@ def _build_device_fn(spec: GroupSpec, n_intervals: int,
     return jax.jit(fn)
 
 
-def _assemble_aux(spec: GroupSpec, segment: Segment, intervals: Sequence[Interval],
-                  filter_node: Optional[FilterNode],
-                  kernels: List[AggKernel],
+def _assemble_aux(spec: GroupSpec, segment: Segment,
+                  intervals: Sequence[Interval],
+                  filter_node: Optional[FilterNode], kernels: List[AggKernel],
                   vc_plans: Tuple = (),
                   vc_luts: Sequence[np.ndarray] = ()) -> Tuple:
-    t0 = segment.interval.start
-    clip_lo, clip_hi = -(2**31) + 1, 2**31 - 1
-    iv = np.asarray(
-        [[min(max(ivl.start - t0, clip_lo), clip_hi),
-          min(max(ivl.end - t0, clip_lo), clip_hi)] for ivl in intervals],
-        dtype=np.int64).astype(np.int32)
-    # order must match the reads in _build_device_fn: vc time0 + string
-    # LUTs (if any), then interval bounds, then bucket/dim/filter/kernel aux
-    aux: List[np.ndarray] = []
+    """The per-segment program's aux: the segment's own origins (the head
+    `_build_device_fn.fn` takes off) and then the stacked layout. `vc_plans`
+    rides for the call shape it shares with `_build_device_fn`."""
+    time0s, iv_rel, bucket_off = stacked_origins([segment], [intervals],
+                                                 [spec])
+    return (time0s[0], iv_rel[0], bucket_off[0]) + assemble_stacked_aux(
+        spec, filter_node.aux_arrays() if filter_node is not None else (),
+        [a for k in kernels for a in k.aux_arrays()], vc_luts)
+
+
+# ---------------------------------------------------------------------------
+# The one traced body and its inputs. The per-segment program, the batched
+# program (engine/batching.py, the body UNROLLED inside one jit) and the
+# sharded shard_map program (parallel/distributed.py, vmapped within each
+# shard) all run traced_segment over assemble_stacked_aux's layout, with
+# stacked_origins' per-segment arguments.
+# ---------------------------------------------------------------------------
+
+def traced_segment(spec: GroupSpec, filter_node: Optional[FilterNode],
+                   kernels: Sequence[AggKernel], vc_plans: Tuple,
+                   arrays, time0, iv_rel, bucket_off, aux):
+    """THE traced per-segment body. The plan's structure comes first (four
+    arguments the stacked programs close over, never traced); a segment's
+    origins (time0, relative interval bounds, bucket origin) arrive as
+    arguments — mapped over the stack, or off the head of the per-segment
+    program's aux — so one body serves every segment of a structure.
+    Returns RAW (counts, states): callers apply device_post/host_post as
+    their merge discipline requires. Only the per-segment program stages
+    `__key` (host key mode) or `__bucket` (host bucket mode)."""
+    import jax.numpy as jnp
+
+    it = iter(aux)
+    # decode compressed columns at the program top: HBM keeps the
+    # packed/RLE/delta/LZ4 representation (pooled blocks and stacked slots
+    # alike), XLA fuses the decode into every consumer; the pallas strategy
+    # additionally receives the raw packed words (packed_cols, FOR
+    # included) and unpacks per tile inside the kernel instead
+    # (data/cascade.split_resident is the ONE decode entry point)
+    packed_cols, arrays = cascade_mod.split_resident(arrays)
+    t = arrays["__time_offset"]
+    mask = arrays["__valid"]
+
     if vc_plans:
-        aux.append(np.asarray(t0, dtype=np.int64))
-        aux.extend(vc_luts)
-    aux.append(iv)
+        # expressions may reference absolute __time — the one consumer of
+        # 64-bit per-row time (epoch millis overflow int32; x64 is globally
+        # on via engine/__init__)
+        arrays = eval_virtual_columns(
+            arrays, t.astype(jnp.int64) + time0, vc_plans, it)  # druidlint: disable=x64-dtype
+
+    # int32 relative bounds — no 64-bit elementwise time math
+    within = (t[:, None] >= iv_rel[None, :, 0]) \
+        & (t[:, None] < iv_rel[None, :, 1])
+    mask = mask & jnp.any(within, axis=1)
+
+    dims = spec.dims
+    if spec.key_mode == "host":
+        # the host-fused key already holds bucket and dims
+        key = arrays["__key"]
+        mask = mask & (key >= 0)
+        dims = ()
+    elif spec.bucket_mode == "all":
+        key = jnp.zeros(t.shape, dtype=jnp.int32)
+    elif spec.bucket_mode == "uniform":
+        # int32 bucket math: offsets are int32 by construction and uniform
+        # periods (≤ week) fit int32; 64-bit div would be limb-emulated on
+        # TPU
+        period = next(it)
+        nb = next(it)  # num buckets as device scalar
+        b = (t - bucket_off) // period
+        mask = mask & (b >= 0) & (b < nb)
+        key = b.astype(jnp.int32)
+    else:
+        key = arrays["__bucket"]
+        mask = mask & (key >= 0)
+
+    return fuse_filter_update(arrays, mask, key, it, dims, filter_node,
+                              kernels, spec.num_total,
+                              strategy=spec.strategy, window=spec.window,
+                              packed_cols=packed_cols or None)
+
+
+def assemble_stacked_aux(spec: GroupSpec, f_aux: Sequence[np.ndarray],
+                         k_aux: Sequence[np.ndarray],
+                         vc_luts: Sequence[np.ndarray] = ()) -> Tuple:
+    """Aux stream in the order of traced_segment's reads: interval
+    bounds and bucket origins are per-segment arguments (NOT aux); only
+    plan constants live here. vc string-LUTs lead (consumed inside
+    eval_virtual_columns first); a host-fused key reads no bucket or dim
+    constants."""
+    aux: List[np.ndarray] = list(vc_luts)
     if spec.key_mode == "dense":
         if spec.bucket_mode == "uniform":
-            aux.append(np.asarray(spec.uniform_first_offset, dtype=np.int32))
             aux.append(np.asarray(spec.uniform_period, dtype=np.int32))
             aux.append(np.asarray(spec.num_buckets, dtype=np.int32))
         for d in spec.dims:
@@ -890,102 +874,51 @@ def _assemble_aux(spec: GroupSpec, segment: Segment, intervals: Sequence[Interva
             if d.remap is not None:
                 aux.append(d.remap.astype(np.int32))
             aux.append(np.asarray(d.cardinality, dtype=np.int32))
-    if filter_node is not None:
-        aux.extend(filter_node.aux_arrays())
-    for k in kernels:
-        aux.extend(k.aux_arrays())
-    return tuple(aux)
-
-
-# ---------------------------------------------------------------------------
-# Shared multi-segment (stacked) execution pieces
-#
-# Both stacked executions — the batched program (engine/batching.py, the
-# per-segment body UNROLLED inside one jit) and the sharded shard_map
-# program (parallel/distributed.py, vmapped within each shard) — run ONE
-# device program over many segments. They share the per-segment traced
-# body and the aux layout below, so keying/filter/update semantics cannot
-# diverge from each other (and both call fuse_filter_update, so they
-# cannot diverge from the per-segment program either).
-# ---------------------------------------------------------------------------
-
-def make_stacked_segment_fn(spec: GroupSpec, kds: Sequence[KeyDim],
-                            filter_node: Optional[FilterNode],
-                            kernels: Sequence[AggKernel],
-                            vc_plans: Tuple = ()):
-    """Traced per-segment body for stacked execution: segment-specific
-    origins (time0, relative interval bounds, bucket origin) arrive as
-    mapped-axis arguments instead of aux constants, so one closure serves
-    every segment in the stack. Returns RAW (counts, states) — callers
-    apply device_post/host_post as their merge discipline requires."""
-    import jax.numpy as jnp
-
-    bucket_mode = spec.bucket_mode
-    num_total = spec.num_total
-    dim_cols = tuple(d.column for d in kds)
-    has_remap = tuple(d.remap is not None for d in kds)
-
-    def per_segment(arrays, time0, iv_rel, bucket_off, aux):
-        it = iter(aux)
-        # same decode-at-top story as _build_device_fn: stacked blocks may
-        # carry bit-packed or cascade-encoded columns — both the batched
-        # path and the sharded mesh path stack compressed-resident slots
-        # through the device pool and decode them here, in-program
-        packed_cols, arrays = cascade_mod.split_resident(arrays)
-        t = arrays["__time_offset"]
-        mask = arrays["__valid"]
-
-        if vc_plans:
-            # expressions may reference absolute __time — the one consumer
-            # of 64-bit per-row time (epoch millis overflow int32; x64 is
-            # globally on via engine/__init__)
-            arrays = eval_virtual_columns(
-                arrays, t.astype(jnp.int64) + time0, vc_plans, it)  # druidlint: disable=x64-dtype
-
-        # int32 relative bounds — no 64-bit elementwise time math
-        within = (t[:, None] >= iv_rel[None, :, 0]) \
-            & (t[:, None] < iv_rel[None, :, 1])
-        mask = mask & jnp.any(within, axis=1)
-
-        if bucket_mode == "all":
-            key = jnp.zeros(t.shape, dtype=jnp.int32)
-        else:
-            period = next(it)
-            nb = next(it)
-            b = (t - bucket_off) // period
-            mask = mask & (b >= 0) & (b < nb)
-            key = b.astype(jnp.int32)
-
-        return fuse_filter_update(arrays, mask, key, it, dim_cols, has_remap,
-                                  filter_node, kernels, num_total,
-                                  strategy=spec.strategy, window=spec.window,
-                                  packed_cols=packed_cols or None)
-
-    return per_segment
-
-
-def assemble_stacked_aux(spec: GroupSpec, kds: Sequence[KeyDim],
-                         f_aux: Sequence[np.ndarray],
-                         k_aux: Sequence[np.ndarray],
-                         granularity: Granularity,
-                         vc_luts: Sequence[np.ndarray] = ()) -> Tuple:
-    """Aux stream for make_stacked_segment_fn's reads: interval bounds and
-    bucket origins arrive as per-segment mapped args (NOT aux); only shared
-    plan constants live here. vc string-LUTs lead (consumed inside
-    eval_virtual_columns first)."""
-    aux: List[np.ndarray] = list(vc_luts)
-    if spec.bucket_mode == "uniform":
-        aux.append(np.asarray(granularity.period_ms, dtype=np.int32))
-        aux.append(np.asarray(spec.num_buckets, dtype=np.int32))
-    for d in kds:
-        if d.column is None:
-            continue
-        if d.remap is not None:
-            aux.append(d.remap.astype(np.int32))
-        aux.append(np.asarray(d.cardinality, dtype=np.int32))
     aux.extend(f_aux)
     aux.extend(k_aux)
     return tuple(aux)
+
+
+def stacked_origins(segments: Sequence[Segment],
+                    intervals_per_segment: Sequence[Sequence[Interval]],
+                    specs: Sequence[GroupSpec], K: Optional[int] = None
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Per-segment origins of the traced body, `[K]`-leading (K pads past
+    the segments with zeros: no interval, so no row): each segment's start
+    (`time0s`, int64), the query intervals RELATIVE to it (`iv_rel`, int32
+    `[K, n, 2]`) and its uniform bucket origin (`bucket_off`, int32). The
+    device program stays in int32 offset space (64-bit elementwise time
+    math is limb-emulated on TPU), so everything relative clips HERE, once,
+    to int32: a bound beyond ±24.8 days of the segment's start lies
+    outside every row offset anyway."""
+    K = len(segments) if K is None else K
+    clip_lo, clip_hi = -(2**31) + 1, 2**31 - 1
+    n_iv = max((len(ivs) for ivs in intervals_per_segment), default=0)
+    time0s = np.zeros((K,), dtype=np.int64)
+    iv_rel = np.zeros((K, max(n_iv, 1), 2), dtype=np.int32)
+    bucket_off = np.zeros((K,), dtype=np.int32)
+    for i, (s, ivs, spec) in enumerate(zip(segments, intervals_per_segment,
+                                           specs)):
+        t0 = time0s[i] = s.interval.start
+        for j, ivl in enumerate(ivs):
+            iv_rel[i, j, 0] = min(max(ivl.start - t0, clip_lo), clip_hi)
+            iv_rel[i, j, 1] = min(max(ivl.end - t0, clip_lo), clip_hi)
+        if spec.bucket_mode == "uniform":
+            bucket_off[i] = min(max(int(spec.bucket_starts[0]) - t0,
+                                    clip_lo), clip_hi)
+    return time0s, iv_rel, bucket_off
+
+
+def common_window(windows) -> int:
+    """The window every stacked segment agrees on: the widest of the
+    segments' own (`windowed_window`, given lazily), 0 as soon as one
+    segment has none."""
+    w_all = 0
+    for w in windows:
+        if not w:
+            return 0
+        w_all = max(w_all, w)
+    return w_all
 
 
 def aux_equal(a: Sequence[np.ndarray], b: Sequence[np.ndarray]) -> bool:
@@ -1012,39 +945,27 @@ def keydims_equal(a: Sequence[KeyDim], b: Sequence[KeyDim]) -> bool:
     return True
 
 
-_NO_NODE = object()   # "caller did not plan the filter" sentinel
-
-
-def needed_columns(segment: Segment, kds: Sequence[KeyDim],
-                   aggs: Sequence[AggregatorSpec], flt,
-                   virtual_columns: Sequence, filter_node=_NO_NODE,
-                   kernels: Optional[Sequence[AggKernel]] = None):
-    """Returns (all referenced real-column names, the subset present in
-    `segment` — i.e. the columns to stage). When the PLANNED `filter_node`
-    is passed (None counts: the filter simplified away), filter needs come
-    from its required_device_columns() — subtrees compiled to device
-    bitmaps (filters.DeviceBitmapNode) consume no staged columns, so
-    filter-only dimensions stop staging. When the PLANNED `kernels` ride
-    along, filtered aggregators likewise contribute their planned needs
-    (bitmap-compiled aggregator filters read words, not columns)."""
+def needed_columns(segment: Segment, aggs: Sequence[AggregatorSpec],
+                   virtual_columns: Sequence, filter_node,
+                   kernels: Sequence[AggKernel],
+                   extra_columns: Sequence[str] = ()):
+    """Returns (all real-column names the filter, aggregators and virtual
+    columns reference, the subset present in `segment` — i.e. the columns
+    to stage whatever fuses the key). Needs come from the PLANNED filter
+    tree and kernels, not the raw filter's: subtrees compiled to device
+    bitmaps (filters.DeviceBitmapNode) read resident words, so filter-only
+    dimensions stop staging; a kernel without planned needs (None) falls
+    back to its aggregator's."""
     from druid_tpu.utils.expression import parse_expression
-    vc_names = {v.name for v in virtual_columns}
-    needed = set()
-    for d in kds:
-        if d.column is not None:
-            needed.add(d.column)
-    if filter_node is _NO_NODE:
-        if flt is not None:
-            needed |= flt.required_columns()
-    elif filter_node is not None:
+    needed = set(extra_columns)
+    if filter_node is not None:
         needed |= filter_node.required_device_columns()
-    for i, a in enumerate(aggs):
-        kc = kernels[i].required_device_columns() \
-            if kernels is not None else None
+    for a, k in zip(aggs, kernels):
+        kc = k.required_device_columns()
         needed |= a.required_columns() if kc is None else kc
     for v in virtual_columns:
         needed |= parse_expression(v.expression).required_columns()
-    needed -= vc_names
+    needed -= {v.name for v in virtual_columns}
     needed -= {"__time", "__time_offset", "__valid"}
     present = tuple(sorted(c for c in needed
                            if c in segment.dims or c in segment.metrics))
@@ -1054,26 +975,57 @@ def needed_columns(segment: Segment, kds: Sequence[KeyDim],
 @dataclass
 class GroupPlan:
     """The host-side planning product for one segment's grouped aggregation
-    — everything run_grouped_aggregate derives BEFORE staging: group spec,
-    simplified filter tree, kernel instances, virtual-column programs.
-    Built by plan_grouped_aggregate; the batched path (engine/batching.py)
-    plans every segment once for bucket grouping and hands the same plan
-    back on straggler fallback so nothing is planned twice.
+    — everything derived BEFORE staging. Built by plan_grouped_aggregate,
+    the ONE planner of all three builders: the per-segment path runs it,
+    the batched path (engine/batching.py) groups plans into shape buckets
+    and hands a straggler's plan back so nothing is planned twice, the mesh
+    (parallel/distributed.py) compares the plans of a query's segments.
 
-    Single-use per execution: run_grouped_aggregate mutates spec (strategy
-    selection, projection rewrites) — do not share one plan across runs."""
+    Single-use per execution: run_grouped_aggregate mutates it (strategy
+    selection, the projection rewrite, megakernel conversion) — do not
+    share one plan across runs."""
     spec: "GroupSpec"
     filter_node: object
     kernels: List[AggKernel]
     vc_plans: Tuple
     vc_luts: List[np.ndarray]
+    n_slots: int                        # `__fbmpN` bitmap slots assigned
+    f_aux: Sequence[np.ndarray]         # the PLANNED filter's aux constants
+    k_aux: List[np.ndarray]             # the kernels' aux constants
+    needed: set                         # referenced real columns, held or not
+    base_columns: Tuple[str, ...]       # staged whatever fuses the key
+    columns: Tuple[str, ...]            # + the dims a dense key fuses on device
+    col_dtypes: Dict[str, np.dtype]     # staged dtype of every program input
+    perm: Optional[np.ndarray] = None   # a sorted projection's row order
+    perm_key: Optional[Tuple] = None
+
+
+def _staged_col_dtypes(segment: Segment, spec: "GroupSpec",
+                       columns: Sequence[str]) -> Dict[str, np.dtype]:
+    """Dtype each input of the program stages as — what select_strategy is
+    asked with BEFORE anything stages (a projection stages a permuted
+    layout, so there is no block to read dtypes from)."""
+    i32 = np.dtype(np.int32)
+    out = {"__time_offset": i32, "__valid": np.dtype(bool)}
+    for c in columns:
+        out[c] = i32 if c in segment.dims \
+            else np.dtype(segment.staged_dtype(c))
+    if spec.key_mode == "host":
+        out["__key"] = i32
+    else:
+        out.update((d.column, i32) for d in spec.dims
+                   if d.host_ids is not None)
+        if spec.bucket_mode == "host":
+            out["__bucket"] = i32
+    return out
 
 
 def plan_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                            granularity: Granularity,
                            dims: Sequence[KeyDim],
                            aggs: Sequence[AggregatorSpec], flt,
-                           virtual_columns: Sequence = ()) -> GroupPlan:
+                           virtual_columns: Sequence = (),
+                           extra_columns: Sequence[str] = ()) -> GroupPlan:
     """Host-side planning for one segment (no staging, no device work)."""
     vc_plans, vc_luts = plan_virtual_columns(segment, virtual_columns)
     filter_node = simplify_node(plan_filter(flt, segment, virtual_columns))
@@ -1081,12 +1033,52 @@ def plan_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
     # globally unique bitmap slots across the query filter AND the
     # filtered-aggregator trees — their staged word arrays share one
     # `__fbmpN` namespace in the arrays dict
-    filters_mod.assign_bitmap_slots(filter_node, kernels)
+    n_slots = filters_mod.assign_bitmap_slots(filter_node, kernels)
+    spec = make_group_spec(segment, intervals, granularity, dims)
+    needed, base_columns = needed_columns(
+        segment, aggs, virtual_columns, filter_node, kernels, extra_columns)
+    # a dense key fuses its dims on the device: their id columns stage too
+    # (a derived id column, `host_ids`, stages from its host array)
+    key_columns = {d.column for d in spec.dims
+                   if spec.key_mode == "dense" and d.column is not None}
+    columns = tuple(sorted(key_columns.intersection(segment.dims)
+                           .union(base_columns)))
     return GroupPlan(
-        spec=make_group_spec(segment, intervals, granularity, dims),
-        filter_node=filter_node,
-        kernels=kernels,
-        vc_plans=vc_plans, vc_luts=vc_luts)
+        spec=spec, filter_node=filter_node, kernels=kernels,
+        vc_plans=vc_plans, vc_luts=vc_luts, n_slots=n_slots,
+        f_aux=filter_node.aux_arrays() if filter_node is not None else [],
+        k_aux=[a for k in kernels for a in k.aux_arrays()],
+        needed=needed | key_columns, base_columns=base_columns,
+        columns=columns,
+        col_dtypes=_staged_col_dtypes(segment, spec, columns))
+
+
+def _host_post(kernel: AggKernel, state, segment: Segment):
+    return kernel.host_post(state, segment)
+
+
+def fetch_partials(targets: Sequence[Tuple], outs: Sequence[Tuple],
+                   post=_host_post, **attrs) -> List[SegmentPartial]:
+    """Device results → host partials, under the ONE `engine/fetch`.
+    `targets` are (segment, spec, kernels) and `outs` their (counts,
+    states); `post(kernel, state, segment)` is the one thing that differs
+    between the builders: a per-segment result takes the kernel's host_post
+    (the default), a mesh result that the collectives already merged its
+    host_from_device. This is where the host already blocks for the device
+    (`np.asarray` of an enqueued program's outputs), so the span adds no
+    sync: its duration is wait-for-device plus D2H plus the host
+    conversion. `bytes` is what comes back."""
+    with trace_span("engine/fetch", **attrs) as sp:
+        if sp is not None:
+            sp.attrs["bytes"] = entry_bytes(outs)
+        return [SegmentPartial(
+            segment=segment, spec=spec,
+            counts=np.asarray(counts, dtype=np.int64),
+            states={k.name: post(k, st, segment)
+                    for k, st in zip(kernels, states)},
+            kernels=kernels)
+            for (segment, spec, kernels), (counts, states)
+            in zip(targets, outs)]
 
 
 def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
@@ -1100,8 +1092,11 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
     arguments) skips re-planning — the batched path's straggler fallback
     passes the plan it already built for bucket grouping.
 
-    Traced, the segment's time lies under one `engine/segment` span whose
-    children are consecutive phases (at most 8 spans a warm segment):
+    The phases run one after another, each a function: `_plan_segment`,
+    `_stage_segment`, `_dispatch_segment` (the ENQUEUE: it returns what
+    `fetch_partials` takes) and the fetch. Traced, the segment's time lies
+    under one `engine/segment` span whose children are consecutive phases
+    (at most 8 spans a warm segment):
     `engine/plan` (group spec, the code-domain probe, strategy, projection
     lookup — with an `engine/projection/build` child when the projection
     is built),
@@ -1110,58 +1105,51 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
     `engine/stage` (columns and derived keys; `pool/h2d` nests here),
     `engine/build` (aux, signature, program cache, the kernel build),
     `engine/dispatch` (the ENQUEUE: dispatch is asynchronous) and
-    `engine/fetch` (see `_fetch_partial`: the wait for the device)."""
+    `engine/fetch` (see `fetch_partials`: the wait for the device)."""
     with trace_span("engine/segment", rows=segment.n_rows) as seg_span:
-        partial, strategy = _run_grouped_aggregate(
-            segment, intervals, granularity, dims, aggs, flt, extra_columns,
-            virtual_columns, plan)
-        if seg_span is not None:
-            # formatted only when traced: untraced segments pay no str()
-            seg_span.attrs.update(segment=str(segment.id), strategy=strategy)
-        return partial
-
-
-def _fetch_partial(segment: Segment, spec: "GroupSpec",
-                   kernels: List[AggKernel], counts, states
-                   ) -> SegmentPartial:
-    """Device results → host partial, under `engine/fetch`. This is where
-    the host already blocks for the device (`np.asarray` of an enqueued
-    program's outputs), so the span adds no sync: its duration is
-    wait-for-device plus D2H plus the kernels' host_post. `bytes` is what
-    comes back."""
-    with trace_span("engine/fetch") as sp:
-        if sp is not None:
-            sp.attrs["bytes"] = entry_bytes((counts, states))
-        host_states = {k.name: k.host_post(st, segment)
-                       for k, st in zip(kernels, states)}
-        return SegmentPartial(segment=segment, spec=spec,
-                              counts=np.asarray(counts, dtype=np.int64),
-                              states=host_states, kernels=kernels)
-
-
-def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
-                           extra_columns, virtual_columns, plan):
-    """run_grouped_aggregate's body: (partial, the strategy that ran)."""
-    from druid_tpu.utils.expression import parse_expression
-
-    run_domain = False
-    with trace_span("engine/plan") as plan_span:
-        if plan is None:
-            plan = plan_grouped_aggregate(segment, intervals, granularity,
-                                          dims, aggs, flt, virtual_columns)
-        spec = plan.spec
-        filter_node = plan.filter_node
-        kernels = plan.kernels
-        vc_plans, vc_luts = plan.vc_plans, plan.vc_luts
-
-        if isinstance(filter_node, ConstNode) and not filter_node.value:
-            # constant-false filter: nothing matches — skip the device
-            return SegmentPartial(
+        plan, route = _plan_segment(segment, intervals, granularity, dims,
+                                    aggs, flt, extra_columns,
+                                    virtual_columns, plan)
+        spec, kernels = plan.spec, plan.kernels
+        if route == "constFalse":
+            # nothing matches — skip the device
+            partial = SegmentPartial(
                 segment=segment, spec=spec,
                 counts=np.zeros(spec.num_total, dtype=np.int64),
                 states={k.name: k.empty_state(spec.num_total)
                         for k in kernels},
-                kernels=kernels), "constFalse"
+                kernels=kernels)
+        else:
+            if route == "runDomain":
+                out = cascade_mod.try_run_domain(
+                    segment, intervals, granularity, spec, kernels, flt,
+                    virtual_columns)
+            else:
+                staged = _stage_segment(segment, plan)
+                out = _dispatch_segment(segment, intervals, plan, *staged)
+                route = spec.strategy
+            partial, = fetch_partials([(segment, spec, kernels)], [out])
+        if seg_span is not None:
+            # formatted only when traced: untraced segments pay no str()
+            seg_span.attrs.update(segment=str(segment.id), strategy=route)
+        return partial
+
+
+def _plan_segment(segment, intervals, granularity, dims, aggs, flt,
+                  extra_columns, virtual_columns,
+                  plan: Optional[GroupPlan]) -> Tuple[GroupPlan, Optional[str]]:
+    """`engine/plan`: the plan complete before anything stages — strategy
+    and projection included — and the route when no row program runs:
+    `constFalse` (the filter folded away every row) or `runDomain`."""
+    with trace_span("engine/plan") as plan_span:
+        if plan is None:
+            plan = plan_grouped_aggregate(segment, intervals, granularity,
+                                          dims, aggs, flt, virtual_columns,
+                                          extra_columns)
+        spec, filter_node, kernels = plan.spec, plan.filter_node, plan.kernels
+
+        if isinstance(filter_node, ConstNode) and not filter_node.value:
+            return plan, "constFalse"
 
         # code-domain fast path (data/cascade.py): when every referenced
         # column is constant within one shared run partition and the query
@@ -1180,90 +1168,49 @@ def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
             if plan_span is not None:
                 plan_span.attrs["runDomainMs"] = round(
                     (time.monotonic() - t0) * 1000.0, 3)
+            if run_domain:
+                return plan, "runDomain"
 
-        if not run_domain:
-            vc_names = {v.name for v in virtual_columns}
-            base_needed = set(extra_columns)
-            if filter_node is not None:
-                # the PLANNED tree's column needs, not the raw filter's:
-                # subtrees compiled to device bitmaps read resident words, not
-                # columns
-                base_needed |= filter_node.required_device_columns()
-            for a, k in zip(aggs, kernels):
-                # the PLANNED kernel's needs where narrower: a filtered agg
-                # whose filter compiled to bitmap words reads words, not filter
-                # columns
-                kc = k.required_device_columns()
-                base_needed |= a.required_columns() if kc is None else kc
-            for v in virtual_columns:
-                base_needed |= parse_expression(
-                    v.expression).required_columns()
-            base_needed -= vc_names
-            base_needed = {c for c in base_needed
-                           if c in segment.dims or c in segment.metrics}
-            needed = set(base_needed)
-            for d in spec.dims:
-                if spec.key_mode == "dense" and d.column is not None \
-                        and d.host_ids is None:
-                    needed.add(d.column)
+        spec.strategy, spec.window = select_strategy(
+            spec, kernels, plan.col_dtypes, _padded_rows(segment),
+            lambda: windowed_window(segment, intervals, granularity, spec))
 
-            # strategy BEFORE staging: the projection path stages a permuted
-            # layout, so dtypes come from staged_dtype, not from a staged block
-            from druid_tpu.data.segment import DEFAULT_ROW_ALIGN
-            padded_rows = max(DEFAULT_ROW_ALIGN,
-                              -(-segment.n_rows // DEFAULT_ROW_ALIGN)
-                              * DEFAULT_ROW_ALIGN)
-            col_dtypes = {"__time_offset": np.dtype(np.int32),
-                          "__valid": np.dtype(bool)}
-            for c in needed:
-                col_dtypes[c] = np.dtype(np.int32) if c in segment.dims \
-                    else np.dtype(segment.staged_dtype(c))
-            if spec.key_mode == "dense":
-                for d in spec.dims:
-                    if d.host_ids is not None:
-                        col_dtypes[d.column] = np.dtype(np.int32)
-            if spec.key_mode == "host":
-                col_dtypes["__key"] = np.dtype(np.int32)
-            elif spec.bucket_mode == "host":
-                col_dtypes["__bucket"] = np.dtype(np.int32)
-            spec.strategy, spec.window = select_strategy(
-                spec, kernels, col_dtypes, padded_rows,
-                lambda: windowed_window(segment, intervals, granularity, spec))
+        if spec.strategy == "projection":
+            proj = build_projection(segment, intervals, granularity, spec)
+            spec.key_mode = "host"
+            spec.host_keys = proj.keys
+            spec.host_unique = proj.unique
+            spec.num_total = pad_pow2(max(len(proj.unique), 1))
+            # key prefused: dim columns stay host-side, `__key` stages
+            plan.columns = plan.base_columns
+            plan.col_dtypes = _staged_col_dtypes(segment, spec, plan.columns)
+            spec.strategy, spec.window = _projection_strategy(
+                proj, kernels, plan.col_dtypes, spec.num_total)
+            plan.perm = proj.order
+            plan.perm_key = _key_structure("projection", granularity,
+                                           intervals, spec.dims)
+            spec.host_keys_cache = plan.perm_key
+            # bitmap subtrees STAY on the words path: the projection's
+            # permuted row layout stages its own words under a
+            # permutation-digest pool key (filters.bitmap_pool_key), so the
+            # bit test aligns with the permuted columns instead of forcing
+            # a column-path re-plan
+    return plan, None
 
-            perm, perm_key = None, None
-            if spec.strategy == "projection":
-                proj = build_projection(segment, intervals, granularity, spec)
-                spec.key_mode = "host"
-                spec.host_keys = proj.keys
-                spec.host_unique = proj.unique
-                spec.num_total = pad_pow2(max(len(proj.unique), 1))
-                col_dtypes.pop("__bucket", None)
-                col_dtypes["__key"] = np.dtype(np.int32)
-                spec.strategy, spec.window = _projection_strategy(
-                    proj, kernels, col_dtypes, spec.num_total)
-                perm = proj.order
-                perm_key = ("projection", str(granularity),
-                            tuple((iv.start, iv.end) for iv in intervals),
-                            tuple((d.column, d.cardinality,
-                                   None if d.remap is None
-                                   else d.remap.tobytes())
-                                  for d in spec.dims))
-                spec.host_keys_cache = perm_key
-                # key prefused: dim columns stay host-side
-                needed = base_needed
-                # bitmap subtrees STAY on the words path: the projection's
-                # permuted row layout stages its own words under a
-                # permutation-digest pool key (filters.bitmap_pool_key), so the
-                # bit test aligns with the permuted columns instead of forcing
-                # a column-path re-plan
 
-    if run_domain:
-        counts, states = cascade_mod.try_run_domain(
-            segment, intervals, granularity, spec, kernels, flt,
-            virtual_columns)
-        return _fetch_partial(segment, spec, kernels, counts,
-                              states), "runDomain"
+def _padded_rows(segment: Segment) -> int:
+    """Rows a segment's block stages at (Segment.device_block's default
+    alignment), known before it stages."""
+    return max(DEFAULT_ROW_ALIGN,
+               -(-segment.n_rows // DEFAULT_ROW_ALIGN) * DEFAULT_ROW_ALIGN)
 
+
+def _stage_segment(segment: Segment, plan: GroupPlan) -> Tuple:
+    """`engine/filter/words` and `engine/stage`: what the program reads,
+    resident — (arrays, packs, cascades)."""
+    spec, kernels = plan.spec, plan.kernels
+    perm, perm_key = plan.perm, plan.perm_key
+    padded_rows = _padded_rows(segment)
     # megakernel conversion (engine/megakernel.py): bitmap subtrees whose
     # combined words are not already resident fuse INLINE — per-leaf words
     # stay resident, the algebra evaluates inside the ONE aggregation
@@ -1274,11 +1221,12 @@ def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
     with filters_mod.words_span():
         # conversion only: nothing stages here, the words stage below
         if megakernel.enabled():
-            filter_node = megakernel.megaize(filter_node, segment,
-                                             padded_rows, pdg)
+            plan.filter_node = megakernel.megaize(plan.filter_node, segment,
+                                                  padded_rows, pdg)
             megakernel.megaize_kernels(kernels, segment, padded_rows, pdg)
         else:
-            megakernel.record_disabled_fallback(filter_node, kernels)
+            megakernel.record_disabled_fallback(plan.filter_node, kernels)
+    filter_node = plan.filter_node
 
     with trace_span("engine/stage"):
         # cascade + pack descriptors of the staged column set: must be
@@ -1286,10 +1234,10 @@ def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
         # (cascade.plan_pair, the one shared derivation), and both join the
         # jit-cache signature — a cascade-encoded, packed, and decoded
         # staging of the same structure are different programs
-        cascades, packs = cascade_mod.plan_pair(segment, sorted(needed),
+        columns = list(plan.columns)
+        cascades, packs = cascade_mod.plan_pair(segment, columns,
                                                 permuted=perm is not None)
-        block = segment.device_block(sorted(needed), perm=perm,
-                                     perm_key=perm_key)
+        block = segment.device_block(columns, perm=perm, perm_key=perm_key)
 
         arrays = dict(block.arrays)
         if spec.key_mode == "dense":
@@ -1333,7 +1281,18 @@ def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
     if spec.strategy == "pallas" \
             and megakernel.split_for_kernel(filter_node)[0]:
         spec.strategy = "megakernel"
+    return arrays, packs, cascades
 
+
+def _dispatch_segment(segment: Segment, intervals: Sequence[Interval],
+                      plan: GroupPlan, arrays: Dict, packs: Tuple,
+                      cascades: Tuple) -> Tuple:
+    """`engine/build` and `engine/dispatch`: the program found or built and
+    ENQUEUED — returns its device (counts, states), which `fetch_partials`
+    waits for."""
+    spec, filter_node, kernels = plan.spec, plan.filter_node, plan.kernels
+    vc_plans, vc_luts = plan.vc_plans, plan.vc_luts
+    col_dtypes = plan.col_dtypes
     aux = None
     while True:
         # pallas-class programs are BUILT before they run (the latch below
@@ -1475,8 +1434,8 @@ def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
             # not fail user queries (reference queries never depend on
             # which engine strategy runs). Nothing that fails while the
             # program RUNS is caught here. The latch is loud: the reason
-            # is pallas_agg.broken_reason(), and chip_smoke.py / bench.py
-            # treat a latched process as failed. A megakernel tree keeps
+            # is pallas_agg.broken_reason(), and chip_smoke.py and the
+            # benchmark treat a latched process as failed. A megakernel tree keeps
             # working: its mega nodes expand to row masks in XLA
             # (MegaBitmapNode.build).
             pallas_agg.mark_broken(e.__cause__ or e)
@@ -1488,8 +1447,7 @@ def _run_grouped_aggregate(segment, intervals, granularity, dims, aggs, flt,
                  if spec.window and spec.window <= w),
                 ("mixed", 0))
 
-    return _fetch_partial(segment, spec, kernels, counts,
-                          states), spec.strategy
+    return counts, states
 
 
 def _build_kernel_program(fn, *args) -> None:
@@ -1507,11 +1465,15 @@ def _build_kernel_program(fn, *args) -> None:
             f"{type(e).__name__}: {e}") from e
 
 
-def _pad_device(arr: np.ndarray, padded: int, fill) -> object:
-    import jax
+def _pad_host(arr: np.ndarray, padded: int, fill) -> np.ndarray:
     out = np.full((padded,), fill, dtype=arr.dtype)
     out[: arr.shape[0]] = arr
-    return jax.device_put(out)
+    return out
+
+
+def _pad_device(arr: np.ndarray, padded: int, fill) -> object:
+    import jax
+    return jax.device_put(_pad_host(arr, padded, fill))
 
 
 def _pad_device_cached(segment: Segment, cache_key: Optional[Tuple],
@@ -1533,9 +1495,8 @@ def _pad_device_cached(segment: Segment, cache_key: Optional[Tuple],
 
         def _build_for():
             import jax
-            out = np.full((padded,), fill, dtype=arr.dtype)
-            out[: arr.shape[0]] = arr
-            words = packed_mod.pack_padded(out, w, base)
+            words = packed_mod.pack_padded(_pad_host(arr, padded, fill), w,
+                                           base)
             return cascade_mod.ForColumn(jax.device_put(words), w, base,
                                          padded, str(arr.dtype))
         if cache_key is None:
@@ -1546,8 +1507,3 @@ def _pad_device_cached(segment: Segment, cache_key: Optional[Tuple],
         return _pad_device(arr, padded, fill)
     return segment.device_cached(("devpad", cache_key, padded, fill),
                                  lambda: _pad_device(arr, padded, fill))
-
-
-def combine_states(kernels: List[AggKernel], a: Dict[str, object],
-                   b: Dict[str, object]) -> Dict[str, object]:
-    return {k.name: k.combine(a[k.name], b[k.name]) for k in kernels}

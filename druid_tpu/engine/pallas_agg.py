@@ -87,7 +87,7 @@ def mark_broken(exc: BaseException) -> None:
 
 def broken_reason() -> Optional[str]:
     """Why the pallas path is latched off in this process, or None while
-    it is live. chip_smoke.py and bench.py fail on a non-None value: a
+    it is live. chip_smoke.py and the benchmark fail on a non-None value: a
     result computed after the latch is an XLA result under another name."""
     return _BROKEN
 

@@ -44,17 +44,18 @@ from druid_tpu.data import devicepool
 from druid_tpu.data import packed as packed_mod
 from druid_tpu.data.segment import Segment
 from druid_tpu.engine import filters as filters_mod
-from druid_tpu.engine.filters import ConstNode, plan_filter, simplify_node
+from druid_tpu.engine.filters import ConstNode
 from druid_tpu.engine import grouping
 from druid_tpu.engine.contracts import (named_program, program_name,
                                         sharded_fallback_reason)
-from druid_tpu.engine.grouping import (GroupSpec, KeyDim, SegmentPartial,
-                                       assemble_stacked_aux, aux_equal,
-                                       keydims_equal, make_group_spec,
-                                       make_stacked_segment_fn,
-                                       needed_columns, plan_virtual_columns,
+from druid_tpu.engine.grouping import (GroupPlan, GroupSpec, KeyDim,
+                                       SegmentPartial, assemble_stacked_aux,
+                                       aux_equal, common_window,
+                                       fetch_partials, keydims_equal,
+                                       plan_grouped_aggregate,
+                                       stacked_origins, traced_segment,
                                        windowed_window)
-from druid_tpu.engine.kernels import AggKernel, make_kernel
+from druid_tpu.engine.kernels import AggKernel
 from druid_tpu.obs.trace import span as trace_span
 from druid_tpu.obs.trace import span_when as trace_span_when
 from druid_tpu.parallel import context, speclayout
@@ -110,31 +111,14 @@ def _stack_owner_token(pool: "devicepool.DeviceSegmentPool") -> int:
         return _STACK_TOKEN
 
 
-# plan-constant equality + column planning now live in engine/grouping.py,
-# shared with the batched (unrolled, engine/batching.py) multi-segment path
-_aux_equal = aux_equal
-_keydims_equal = keydims_equal
-_needed_columns = needed_columns
-
-
 class _ShardedPlan(NamedTuple):
-    """What `_plan_sharded` hands the run: the agreed per-query plan of an
-    eligible segment set."""
-    kds: List[KeyDim]
-    spec0: GroupSpec
-    filter_node: object
-    kernels: List[AggKernel]
-    n_slots: int
-    vc_plans: Tuple
-    vc_luts: Sequence
-    f_aux: Sequence
-    k_aux: Sequence
-    seg_filters: List[object]
-    seg_kernels: List[List[AggKernel]]
-    columns: Tuple[str, ...]
+    """What `_plan_sharded` hands the run: the per-segment plans of an
+    eligible segment set (they agree on every constant; the first speaks
+    for the program) and what only a stack derives."""
+    plans: List[GroupPlan]
     cascades: Tuple
     packs: Tuple
-    selected: str       # select_strategy's choice; spec0.strategy is what runs
+    selected: str       # select_strategy's choice; the spec's is what runs
 
 
 def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
@@ -161,13 +145,13 @@ def try_sharded(segments: Sequence[Segment], intervals: Sequence[Interval],
                 sp.attrs["reason"] = plan
             elif isinstance(plan, _ShardedPlan):
                 sp.attrs["selected"] = plan.selected
-                sp.attrs["strategy"] = plan.spec0.strategy
+                sp.attrs["strategy"] = plan.plans[0].spec.strategy
     if fallback:
         _SHARDED_STATS.record_fallback()
         return None
     if isinstance(plan, SegmentPartial):
         return plan         # a const-false filter's whole-query zero
-    return _run_sharded(mesh, plan, segments, intervals, granularity)
+    return _run_sharded(mesh, plan, segments, intervals)
 
 
 def _plan_sharded(mesh, segments: Sequence[Segment],
@@ -191,7 +175,7 @@ def _plan_sharded(mesh, segments: Sequence[Segment],
         # merges them host-side
         return sharded_fallback_reason("numeric_dimension")
     for other in kds_per_seg[1:]:
-        if not _keydims_equal(kds, other):
+        if not keydims_equal(kds, other):
             return sharded_fallback_reason("key_dims_differ")
     # raw (remap-free) key dims fuse dictionary ids directly, so the
     # dictionaries themselves must agree across segments — equal cardinality
@@ -208,43 +192,40 @@ def _plan_sharded(mesh, segments: Sequence[Segment],
                     list(other.dictionary.values) != list(first.values):
                 return sharded_fallback_reason("dictionaries_differ")
 
-    spec0 = make_group_spec(segments[0], intervals, granularity, kds)
+    # ONE planner (grouping.plan_grouped_aggregate) for every segment; the
+    # first is planned first, and alone until its key mode is known: a
+    # host-keyed group spec sorts a segment's rows
+    p0 = plan_grouped_aggregate(segments[0], intervals, granularity, kds,
+                                aggs, flt, virtual_columns)
+    spec0, filter_node, kernels = p0.spec, p0.filter_node, p0.kernels
     if spec0.key_mode != "dense" or spec0.bucket_mode not in ("all", "uniform"):
         return sharded_fallback_reason("key_or_bucket_mode")
 
-    # plan filter + kernels + virtual columns per segment; constants must
-    # agree across segments. Device-bitmap compilation follows the process
-    # default (the stacked program reads resident `__fbmpN` word slots,
-    # exactly like _build_device_fn) — slots are assigned per plan BEFORE
-    # signatures are compared, so filtered-aggregator trees cannot collide
-    # with the query filter's slot 0.
-    filter_node = simplify_node(plan_filter(flt, segments[0],
-                                            virtual_columns))
-    kernels = [make_kernel(a, segments[0]) for a in aggs]
-    n_slots = filters_mod.assign_bitmap_slots(filter_node, kernels)
-    vc_plans, vc_luts = plan_virtual_columns(segments[0], virtual_columns)
+    # filter + kernels + virtual columns are planned per segment; constants
+    # must agree across segments. Device-bitmap compilation follows the
+    # process default (the stacked program reads resident `__fbmpN` word
+    # slots, exactly like the per-segment program) — slots are assigned per
+    # plan BEFORE signatures are compared, so filtered-aggregator trees
+    # cannot collide with the query filter's slot 0.
     f_sig = filter_node.signature() if filter_node else "none"
-    f_aux = filter_node.aux_arrays() if filter_node else []
-    k_aux = [a for k in kernels for a in k.aux_arrays()]
-    seg_filters: List[object] = [filter_node]
-    seg_kernels: List[List[AggKernel]] = [kernels]
-    for s in segments[1:]:
-        fn_s = simplify_node(plan_filter(flt, s, virtual_columns))
-        ks = [make_kernel(a, s) for a in aggs]
-        filters_mod.assign_bitmap_slots(fn_s, ks)
+    plans = [p0]
+    for s, kds_s in zip(segments[1:], kds_per_seg[1:]):
+        p = plan_grouped_aggregate(s, intervals, granularity, kds_s, aggs,
+                                   flt, virtual_columns)
+        fn_s = p.filter_node
         if (fn_s.signature() if fn_s else "none") != f_sig:
             return sharded_fallback_reason("filter_plans_differ")
-        if not _aux_equal(fn_s.aux_arrays() if fn_s else [], f_aux):
+        if not aux_equal(p.f_aux, p0.f_aux):
             return sharded_fallback_reason("filter_constants_differ")
-        if [k.signature() for k in ks] != [k.signature() for k in kernels]:
+        if [k.signature() for k in p.kernels] \
+                != [k.signature() for k in kernels]:
             return sharded_fallback_reason("kernel_plans_differ")
-        if not _aux_equal([a for k in ks for a in k.aux_arrays()], k_aux):
+        if not aux_equal(p.k_aux, p0.k_aux):
             return sharded_fallback_reason("kernel_constants_differ")
-        vp_s, vl_s = plan_virtual_columns(s, virtual_columns)
-        if repr(vp_s) != repr(vc_plans) or not _aux_equal(vl_s, vc_luts):
+        if repr(p.vc_plans) != repr(p0.vc_plans) \
+                or not aux_equal(p.vc_luts, p0.vc_luts):
             return sharded_fallback_reason("virtual_columns_differ")
-        seg_filters.append(fn_s)
-        seg_kernels.append(ks)
+        plans.append(p)
     # only after every segment agreed on the plan is a const-false filter a
     # whole-query zero (a column may exist in some segments only)
     if isinstance(filter_node, ConstNode) and not filter_node.value:
@@ -258,19 +239,15 @@ def _plan_sharded(mesh, segments: Sequence[Segment],
     # segments: the plain path handles per-segment differences (missing
     # aggregates as zero), but one stacked program cannot — fall back rather
     # than KeyError, silently cast, or crash. Complex (2-D) metric columns
-    # also fall back: the stacker allocates [K, R] only. Planned
-    # filter/kernel trees are passed so bitmap-compiled subtrees stop
-    # staging their columns (their data rides in the word slots).
-    needed, columns = _needed_columns(segments[0], kds, aggs, flt,
-                                      virtual_columns,
-                                      filter_node=filter_node,
-                                      kernels=kernels)
-    for c in needed:
+    # also fall back: the stacker allocates [K, R] only. The plans' needs
+    # are the PLANNED trees': bitmap-compiled subtrees stage no columns
+    # (their data rides in the word slots).
+    for c in p0.needed:
         in_dim0 = c in segments[0].dims
         met0 = segments[0].metrics.get(c)
         if met0 is not None and np.asarray(met0.values).ndim != 1:
             return sharded_fallback_reason("complex_metric")
-        for s in segments[1:]:
+        for s, p in zip(segments[1:], plans[1:]):
             if (c in s.dims) != in_dim0:
                 return sharded_fallback_reason("dimension_presence_differs")
             met = s.metrics.get(c)
@@ -278,41 +255,27 @@ def _plan_sharded(mesh, segments: Sequence[Segment],
                 return sharded_fallback_reason("metric_presence_differs")
             if met is not None and (met.type is not met0.type
                                     or met.values.dtype != met0.values.dtype
-                                    or s.staged_dtype(c)
-                                    != segments[0].staged_dtype(c)):
+                                    or p.col_dtypes.get(c)
+                                    != p0.col_dtypes.get(c)):
                 return sharded_fallback_reason("metric_types_differ")
 
     # compressed slots: the descriptor pair every segment can agree on
     # (cascade entries + pack entries) — the descriptors join the stack
     # pool key AND _sharded_sig below, so chunk-mates agree and the cached
     # program's treedef is pinned
-    cascades, packs = _common_descriptors(segments, columns)
+    cascades, packs = _common_descriptors(segments, p0.columns)
     R, _K = _stack_shape(
         segments, mesh.shape[speclayout.layout_for(mesh).seg_axis])
 
     # reduction strategy must agree across the whole stacked program; the
-    # windowed path needs every segment's host span check to pass
-    col_dtypes = {"__time_offset": np.dtype(np.int32),
-                  "__valid": np.dtype(bool)}
-    for c in columns:
-        if c in segments[0].dims:
-            col_dtypes[c] = np.dtype(np.int32)
-        else:
-            col_dtypes[c] = np.dtype(segments[0].staged_dtype(c))
-
-    def _windowed_all():
-        w_all = 0
-        for s in segments:
-            w = windowed_window(s, intervals, granularity, spec0)
-            if not w:
-                return 0
-            w_all = max(w_all, w)
-        return w_all
-
-    # via the module so tests forcing a strategy (monkeypatching
-    # grouping.select_strategy) also steer the sharded path
+    # windowed path needs every segment's host span check to pass.
+    # select_strategy via the module so tests forcing a strategy
+    # (monkeypatching grouping.select_strategy) also steer the sharded path
     spec0.strategy, spec0.window = grouping.select_strategy(
-        spec0, kernels, col_dtypes, R, _windowed_all)
+        spec0, kernels, p0.col_dtypes, R,
+        lambda: common_window(
+            windowed_window(s, intervals, granularity, p.spec)
+            for s, p in zip(segments, plans)))
     selected = spec0.strategy
     if selected == "projection":
         # sorted projections are per-segment layouts the stacked program
@@ -322,62 +285,48 @@ def _plan_sharded(mesh, segments: Sequence[Segment],
         # path is measured, not assumed: PERF.md §5, `mesh4-analyst-groupby`
         # against `analyst-groupby`.
         spec0.strategy, spec0.window = "mixed", 0
-    return _ShardedPlan(
-        kds=kds, spec0=spec0,
-        filter_node=filter_node, kernels=kernels, n_slots=n_slots,
-        vc_plans=vc_plans, vc_luts=vc_luts, f_aux=f_aux, k_aux=k_aux,
-        seg_filters=seg_filters, seg_kernels=seg_kernels, columns=columns,
-        cascades=cascades, packs=packs, selected=selected)
+    return _ShardedPlan(plans=plans, cascades=cascades, packs=packs,
+                        selected=selected)
 
 
 def _run_sharded(mesh, plan: _ShardedPlan, segments: Sequence[Segment],
-                 intervals: Sequence[Interval],
-                 granularity: Granularity) -> SegmentPartial:
+                 intervals: Sequence[Interval]) -> SegmentPartial:
     """Stack look-up (or build), per-request H2D, the ONE dispatch and its
     fetch, each under its span."""
     layout = speclayout.layout_for(mesh)
     axis = layout.seg_axis
     n_dev = mesh.shape[axis]
-    spec0, kds, kernels = plan.spec0, plan.kds, plan.kernels
+    p0 = plan.plans[0]
+    spec0, kernels = p0.spec, p0.kernels
     stacked, time0s, R, K = _stack_segments(
-        mesh, segments, plan.columns, plan.cascades, plan.packs,
-        plan.seg_filters, plan.seg_kernels, layout)
+        mesh, segments, p0.columns, plan.cascades, plan.packs,
+        [p.filter_node for p in plan.plans],
+        [p.kernels for p in plan.plans], layout)
 
-    # per-segment RELATIVE interval bounds + bucket start offsets: the
-    # device program stays in int32 offset space (64-bit elementwise time
-    # math is limb-emulated on TPU)
+    # per-segment RELATIVE interval bounds + bucket start offsets (the
+    # stack holds the segments' starts)
     with trace_span("engine/sharded/put") as put_span:
-        clip_lo, clip_hi = -(2**31) + 1, 2**31 - 1
-        iv_rel = np.zeros((K, max(len(intervals), 1), 2), dtype=np.int32)
-        bucket_off = np.zeros((K,), dtype=np.int32)
-        for i, s in enumerate(segments):
-            t0 = s.interval.start
-            for j, ivl in enumerate(intervals):
-                iv_rel[i, j, 0] = min(max(ivl.start - t0, clip_lo), clip_hi)
-                iv_rel[i, j, 1] = min(max(ivl.end - t0, clip_lo), clip_hi)
-            if spec0.bucket_mode == "uniform":
-                bucket_off[i] = min(max(int(spec0.bucket_starts[0]) - t0,
-                                        clip_lo), clip_hi)
-        aux = _assemble_aux(spec0, kds, plan.f_aux, plan.k_aux, granularity,
-                            plan.vc_luts)
+        _, iv_rel, bucket_off = stacked_origins(
+            segments, [intervals] * len(segments),
+            [p.spec for p in plan.plans], K)
+        aux = assemble_stacked_aux(spec0, p0.f_aux, p0.k_aux, p0.vc_luts)
         if put_span is not None:
             put_span.attrs["bytes"] = iv_rel.nbytes + bucket_off.nbytes \
                 + devicepool.entry_bytes(aux)
         iv_rel = layout.put_interval_bounds(mesh, iv_rel)
         bucket_off = layout.put_bucket_offsets(mesh, bucket_off)
 
-    sig = _sharded_sig(mesh, axis, spec0, kds, plan.filter_node, kernels,
-                       len(intervals), plan.vc_plans, K, R, plan.columns,
-                       plan.cascades, plan.packs, plan.n_slots, layout)
+    sig = _sharded_sig(mesh, axis, spec0, p0.filter_node, kernels,
+                       len(intervals), p0.vc_plans, K, R, p0.columns,
+                       plan.cascades, plan.packs, p0.n_slots, layout)
     with _CACHE_LOCK:
         fn = _FN_CACHE.get(sig)
         # the miss IS the compile event (shard_map traces/compiles on the
         # first call below) — timing stays at the existing dispatch boundary
         compiled = fn is None
         if fn is None:
-            fn = _build_sharded_fn(mesh, axis, n_dev, spec0, kds,
-                                   plan.filter_node, kernels, plan.vc_plans,
-                                   layout, stacked)
+            fn = _build_sharded_fn(mesh, axis, n_dev, spec0, p0.filter_node,
+                                   kernels, p0.vc_plans, layout, stacked)
             _FN_CACHE[sig] = fn
             while len(_FN_CACHE) > _FN_CACHE_CAP:
                 _FN_CACHE.popitem(last=False)
@@ -391,24 +340,18 @@ def _run_sharded(mesh, plan: _ShardedPlan, segments: Sequence[Segment],
                                          spec0.strategy)), \
             trace_span_when(compiled, "engine/compile", kind="sharded",
                             strategy=spec0.strategy):
-        counts, states = fn(stacked, time0s, iv_rel, bucket_off, aux)
+        out = fn(stacked, time0s, iv_rel, bucket_off, aux)
     _SHARDED_STATS.record(len(segments))
 
     # NOT a host merge: counts/states left the program replicated and
     # already collective-merged; host_from_device only converts the merged
     # device representation (HLL registers, first/last packed pairs) to
     # the host one, exactly like the single-segment path does per segment
-    # engine/fetch: this conversion is where the host blocks for the
-    # enqueued program (wait-for-device + D2H) — the span adds no sync
-    with trace_span("engine/fetch", segments=K) as fetch_span:
-        if fetch_span is not None:
-            fetch_span.attrs["bytes"] = devicepool.entry_bytes(
-                (counts, states))
-        host_states = {k.name: k.host_from_device(st)
-                       for k, st in zip(kernels, states)}
-        return SegmentPartial(segment=segments[0], spec=spec0,
-                              counts=np.asarray(counts, dtype=np.int64),
-                              states=host_states, kernels=kernels)
+    partial, = fetch_partials(
+        [(segments[0], spec0, kernels)], [out],
+        post=lambda kernel, state, _segment: kernel.host_from_device(state),
+        segments=K)
+    return partial
 
 
 def _common_descriptors(segments: Sequence[Segment],
@@ -509,7 +452,7 @@ def _stack_segments(mesh, segments: Sequence[Segment],
     the mesh axis: cascade columns (RLE run tables, delta/FOR words, the
     validity's row counts), packed words, resident filter-bitmap words,
     decoded rows for the rest — the sharded program decodes in-program
-    through cascade.split_resident exactly like _build_device_fn.
+    through cascade.split_resident exactly like the per-segment program.
 
     K pads to a multiple of the axis size with empty (all-invalid)
     segments; R pads rows to the max padded row count (1024-aligned — a
@@ -677,28 +620,19 @@ def clear_fn_cache() -> int:
         return n
 
 
-# aux layout shared with the batched path (engine/grouping.py)
-_assemble_aux = assemble_stacked_aux
-
-
-def _sharded_sig(mesh, axis, spec: GroupSpec, kds, filter_node, kernels,
+def _sharded_sig(mesh, axis, spec: GroupSpec, filter_node, kernels,
                  n_intervals, vc_plans, K, R, columns, cascades, packs,
                  n_bitmap_slots, layout) -> Tuple:
-    """Cache key of one sharded program. The compressed-slot inputs —
-    staged column set, cascade/pack descriptors, bitmap slot count — pin
-    the stacked pytree's treedef (`__valid` is always the prefix mask, its
-    one static field R), so two queries share a cached program only when
-    their stacks share a structure."""
-    dims_sig = ",".join(
-        f"{d.column}:{'remap' if d.remap is not None else 'raw'}" for d in kds)
-    vc_sig = ";".join(f"{name}={expr!r}:{out_type}:l{n_luts}"
-                      for name, expr, out_type, n_luts in vc_plans)
-    return (speclayout.layout_sig(layout, mesh), axis, spec.bucket_mode,
-            dims_sig, n_intervals, vc_sig,
-            filter_node.signature() if filter_node else "none",
-            ";".join(k.signature() for k in kernels), spec.num_total, K, R,
-            spec.strategy, spec.window, columns, cascades, packs,
-            n_bitmap_slots)
+    """Cache key of one sharded program: the plan's structure as every
+    builder keys it (grouping._structure_sig) and what pins the stacked
+    pytree's treedef — mesh layout, [K, R], staged column set, bitmap slot
+    count (`__valid` is always the prefix mask, its one static field R) —
+    so two queries share a cached program only when their stacks share a
+    structure."""
+    return (speclayout.layout_sig(layout, mesh), axis,
+            grouping._structure_sig(spec, n_intervals, filter_node, kernels,
+                                    vc_plans, packs, cascades),
+            K, R, columns, n_bitmap_slots)
 
 
 def _merge_states(kernel: AggKernel, stacked_state, axis: str, n_dev: int,
@@ -734,18 +668,16 @@ def _merge_states(kernel: AggKernel, stacked_state, axis: str, n_dev: int,
 
 
 def _build_sharded_fn(mesh, axis: str, n_dev: int, spec: GroupSpec,
-                      kds: Sequence[KeyDim], filter_node,
-                      kernels: List[AggKernel], vc_plans: Tuple,
+                      filter_node, kernels: List[AggKernel], vc_plans: Tuple,
                       layout: "speclayout.SpecLayout", stacked):
     import jax
     import jax.numpy as jnp
     from jax import shard_map
 
-    seg_body = make_stacked_segment_fn(spec, kds, filter_node, kernels,
-                                       vc_plans)
-
     def per_segment(arrays, time0, iv_rel, bucket_off, aux):
-        counts, states = seg_body(arrays, time0, iv_rel, bucket_off, aux)
+        counts, states = traced_segment(spec, filter_node, kernels, vc_plans,
+                                        arrays, time0, iv_rel, bucket_off,
+                                        aux)
         states = tuple(k.device_post(s, time0)
                        for k, s in zip(kernels, states))
         return counts, states

@@ -163,7 +163,7 @@ def test_filter_only_dims_are_not_staged(fb_segments):
     assert isinstance(node, DeviceBitmapNode)
     assert node.required_device_columns() == set()
     from druid_tpu.engine.grouping import needed_columns
-    _, columns = needed_columns(seg, [], [], flt, (), filter_node=node)
+    _, columns = needed_columns(seg, [], (), node, [])
     assert "dHi" not in columns
 
 

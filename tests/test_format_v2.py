@@ -71,9 +71,8 @@ def _mixed_segment(rows: int = 4096, datasource: str = "fmt"):
 
 
 def _rollup_segment(rows: int = 4096, datasource: str = "roll"):
-    """The RLE-friendly run-domain-eligible shape (bench.py's
-    cascade_segments): dimension-sorted rows, constant rollup count,
-    run-aligned value metric."""
+    """The RLE-friendly run-domain-eligible shape: dimension-sorted rows,
+    constant rollup count, run-aligned value metric."""
     card = 16
     reps = -(-rows // card)
     b = SegmentBuilder(datasource, IV, version="v0", partition=0)

@@ -2,8 +2,7 @@
 start/stop cycles must return the process to its resource baseline —
 stable project-thread set, stable open-fd table, device-pool resident
 bytes back where they started. The leak witness is the measurement
-substrate; bench.py's DRUID_TPU_BENCH_SOAK mode runs the same shape at
-scale and reports drift in its JSON line.
+substrate.
 
 The point is the millions-of-cycles story: a service absorbing heavy
 traffic does exactly this loop forever, so ANY per-cycle residue — a

@@ -102,7 +102,7 @@ def family_of(r: Rule) -> str:
 # ---- configuration -------------------------------------------------------
 
 _DEFAULT_CONFIG = {
-    "include": ["druid_tpu", "tools", "bench.py", "__graft_entry__.py"],
+    "include": ["druid_tpu", "tools", "__graft_entry__.py"],
     "exclude": ["**/__pycache__/**", "*.pyc"],
     "rules": [],                        # empty = all registered rules
     "baseline": "tools/druidlint/baseline.json",
