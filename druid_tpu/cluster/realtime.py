@@ -22,7 +22,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from druid_tpu.cluster.metadata import SegmentDescriptor
 from druid_tpu.cluster.shardspec import NumberedShardSpec
-from druid_tpu.cluster.view import InventoryView
+from druid_tpu.cluster.view import InventoryView, node_answer
 from druid_tpu.data.segment import Segment
 from druid_tpu.engine.engines import AggregatePartials, make_aggregate_partials
 from druid_tpu.query.model import Query
@@ -93,7 +93,8 @@ class RealtimeServer:
             raise ConnectionError(f"server [{self.name}] is down")
         segs, served = self._select(segment_ids)
         ap = make_aggregate_partials(query, segs, clamp=False)
-        return ap, served
+        # the hydrants' partials leave as one, like a historical's segments'
+        return node_answer(ap, check), served
 
     def run_rows(self, query: Query, segment_ids: Sequence[str]
                  ) -> Tuple[List[dict], Set[str]]:

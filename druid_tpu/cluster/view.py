@@ -14,7 +14,9 @@ Reference analogs:
 
 The node boundary (run_partials / run_rows) is in-process here; a real
 multi-host deployment serializes AggregatePartials' numpy states over the
-wire — shapes and dtypes are all plain host arrays by construction.
+wire — shapes and dtypes are all plain host arrays by construction. What
+crosses it is ONE merged partial a node (`node_answer`), as the
+reference's ServerManager merges its per-segment runners before it answers.
 """
 from __future__ import annotations
 
@@ -33,6 +35,7 @@ from druid_tpu.cluster.timeline import (PartitionChunk,
 from druid_tpu.data.segment import Segment
 from druid_tpu.engine import engines
 from druid_tpu.engine.engines import AggregatePartials, make_aggregate_partials
+from druid_tpu.obs import trace as qtrace
 from druid_tpu.query.model import (GroupByQuery, Query, TimeseriesQuery,
                                    TopNQuery)
 from druid_tpu.utils.intervals import Interval
@@ -52,6 +55,24 @@ def descriptor_for(segment: Segment,
     return SegmentDescriptor(
         segment.id.datasource, segment.id.interval, segment.id.version,
         segment.id.partition, shard_spec, num_rows=segment.n_rows)
+
+
+def node_answer(ap: AggregatePartials,
+                check: Optional[Callable[[], None]] = None
+                ) -> AggregatePartials:
+    """What leaves a data node for `ap`, the partials it produced or found
+    in its cache: ONE merged partial where there are two or more, so the
+    wire and the broker's merge carry a node and not its segments. One
+    partial (a mesh node's, a one-segment query's) or none is already that.
+    The merge builds fresh arrays, so the segment cache's entries stay per
+    segment and untouched; `check` (cancel/timeout probe) runs before it.
+    `datanode/merge` is open only while a merge runs: the span says the
+    mechanism engaged."""
+    if check is not None:
+        check()
+    with qtrace.span_when(len(ap.partials) > 1, "datanode/merge",
+                          partialsIn=len(ap.partials)):
+        return ap.merged()
 
 
 def _is_aggregate(query: Query) -> bool:
@@ -164,7 +185,8 @@ class DataNode:
         """Aggregate path: produce partial states for the requested segments
         (clamp=False — the broker pre-bounds intervals so bucket index
         spaces align across nodes). Per-segment partials are cached when the
-        segment cache is enabled (CachingQueryRunner analog).
+        segment cache is enabled (CachingQueryRunner analog); the answer is
+        their merge (`node_answer`).
 
         `check` (cancel/timeout probe) runs at every dispatch boundary —
         between per-segment programs, between batched shape-bucket
@@ -204,8 +226,6 @@ class DataNode:
                         query, f"{len(segs)}-segments",
                         (time.monotonic() - t0) * 1e3,
                         (time.thread_time() - c0) * 1e3, cached=False)
-                if check is not None:
-                    check()
             else:
                 parts = []
                 for s in segs:
@@ -219,7 +239,7 @@ class DataNode:
                                        (time.thread_time() - c0) * 1e3,
                                        cached=False)
                 ap = AggregatePartials.concat(parts)
-            return ap, served
+            return node_answer(ap, check), served
         qkey, parts, to_compute = self._cache_scan(query, segs)
         if to_compute and (self.mesh is not None
                            or (self.emitter is not None
@@ -257,7 +277,7 @@ class DataNode:
                                cached=False)
             self._cache_put(qkey, zip(to_compute, per_seg))
             parts.extend(per_seg)
-        return AggregatePartials.concat(parts), served
+        return node_answer(AggregatePartials.concat(parts), check), served
 
     def _cache_scan(self, query: Query, segs: Sequence[Segment]
                     ) -> Tuple[str, List[AggregatePartials], List[Segment]]:
@@ -320,9 +340,10 @@ class DataNode:
     def run_partials_group(self, requests, on_batch=None) -> List[object]:
         """Cross-query serving: one call for a whole scheduler flush.
         `requests` is a sequence of (query, segment_ids, check) triples;
-        returns one entry per request — (AggregatePartials, served) or the
-        Exception that request failed with (one query's cancel/timeout
-        must not fail its flush-mates).
+        returns one entry per request — (AggregatePartials, served), merged
+        as run_partials' answer is (`node_answer`), or the Exception that
+        request failed with (one query's cancel/timeout must not fail its
+        flush-mates).
 
         Plan-compatible segment work FUSES across the requests into shared
         device dispatches (engines.make_aggregate_partials_multi). Requests
@@ -337,6 +358,14 @@ class DataNode:
         fused_idx: List[int] = []
         fused_items = []        # ((query, segs, check), (served, cache_meta))
         out: List[object] = [None] * len(requests)
+
+        def settle(i, ap, served, check):
+            # a request's own probe or merge fails that request alone
+            try:
+                out[i] = (node_answer(ap, check), served)
+            except Exception as e:
+                out[i] = e
+
         for i, (query, segment_ids, check) in enumerate(requests):
             if not self.fusable(query):
                 # robustness backstop — DataNodeServer bypasses the
@@ -360,7 +389,8 @@ class DataNode:
                 if not to_compute:
                     # the hot-datasource shape: a fully-cached query costs
                     # the flush nothing at all
-                    out[i] = (AggregatePartials.concat(hit_parts), served)
+                    settle(i, AggregatePartials.concat(hit_parts), served,
+                           check)
                     continue
                 fused_idx.append(i)
                 fused_items.append(((query, to_compute, check),
@@ -374,7 +404,7 @@ class DataNode:
                 [item for item, _ in fused_items], on_batch=on_batch)
             wall_ms = (time.monotonic() - t0) * 1e3
             cpu_ms = (time.thread_time() - c0) * 1e3
-            for i, got, ((query, segs, _), (served, cache_meta)) \
+            for i, got, ((query, segs, check), (served, cache_meta)) \
                     in zip(fused_idx, results, fused_items):
                 if isinstance(got, BaseException):
                     out[i] = got
@@ -387,7 +417,7 @@ class DataNode:
                         # this query's alone
                         self._emit_segment(query, f"{len(segs)}-segments",
                                            wall_ms, cpu_ms, cached=False)
-                    out[i] = (got, served)
+                    settle(i, got, served, check)
                     continue
                 hit_parts, to_compute, qkey = cache_meta
                 per_seg = engines.split_partials_by_segment(got, to_compute)
@@ -397,8 +427,8 @@ class DataNode:
                                    wall_ms, cpu_ms, cached=False)
                 # hit parts first, computed parts after — the same order
                 # run_partials' cached path concatenates in
-                out[i] = (AggregatePartials.concat(hit_parts + per_seg),
-                          served)
+                settle(i, AggregatePartials.concat(hit_parts + per_seg),
+                       served, check)
         return out
 
     def run_rows(self, query: Query, segment_ids: Sequence[str]

@@ -29,7 +29,7 @@ from druid_tpu.data.segment import Segment, ValueType
 from druid_tpu.engine import batching
 from druid_tpu.engine.filters import host_mask
 from druid_tpu.engine.grouping import KeyDim, run_grouped_aggregate
-from druid_tpu.engine.merge import merge_partials
+from druid_tpu.engine.merge import merge_partials, merge_to_partial
 from druid_tpu.parallel import distributed
 from druid_tpu.query.model import (DefaultLimitSpec, DimensionSpec, GroupByQuery,
                                    ListFilteredDimensionSpec, ScanQuery,
@@ -233,7 +233,10 @@ def _make_partials(segs, intervals, query, kds_per_seg, vals_per_seg,
     multi-segment dispatches over shape-compatible segments (one jitted
     program per shape bucket with the per-segment body unrolled inside it —
     deliberately NOT vmapped, see engine/batching.py); else the per-segment
-    path. All variants merge host-side except the sharded one.
+    path. The sharded program merges on the device into one partial; the
+    others return a partial a segment, which the data node merges on its
+    host before it answers (`AggregatePartials.merged`) and a local run
+    merges in its finish step.
 
     `check` (cancel/timeout probe) runs at every dispatch boundary: between
     per-segment programs, between batched shape-bucket dispatches, and
@@ -272,14 +275,30 @@ class AggregatePartials:
     The unit shipped from data nodes to the broker: states are plain
     host arrays, dim_values are merged-dictionary string lists, spans are
     (min_time, max_time) data extents for bucket-coverage accounting.
-    Reference analog: the non-finalized per-segment sequences a historical
-    streams back before the broker's mergeResults."""
+    One partial stands for one segment as the engine produces them, for
+    many once merged (`merged()`, the sharded program): a data node answers
+    with ONE, and `spans` keeps an entry for every segment behind it.
+    Reference analog: the non-finalized sequence a historical streams back
+    after ServerManager merged its per-segment runners with the tool
+    chest's mergeResults, before the broker's own mergeResults."""
 
     def __init__(self, partials, dim_values, spans, intervals):
         self.partials = partials          # List[SegmentPartial]
         self.dim_values = dim_values      # parallel: List[List[List[str]]]
         self.spans = spans                # List[(min_ms, max_ms)]
         self.intervals = intervals        # intervals partials were built with
+
+    def merged(self) -> "AggregatePartials":
+        """These partials as ONE (`merge.merge_to_partial`) with its one
+        entry of dim_values, every span and the intervals kept: what a data
+        node sends instead of a partial a segment. `finish_*` of the result
+        is `finish_*` of `self`, bit for bit. Zero or one partial is `self`
+        — nothing to merge, as for a mesh node's sharded partial."""
+        if len(self.partials) < 2:
+            return self
+        partial, values = merge_to_partial(self.partials, self.dim_values)
+        return AggregatePartials([partial], [values], self.spans,
+                                 self.intervals)
 
     @staticmethod
     def concat(parts: Sequence["AggregatePartials"]) -> "AggregatePartials":

@@ -122,7 +122,10 @@ class GroupSpec:
 
 @dataclass
 class SegmentPartial:
-    """Per-segment partial aggregation result (host-side)."""
+    """Partial aggregation result (host-side): one segment's as the engine
+    produces it, many segments' once merged (`merge.merge_to_partial` on a
+    data node's host, the sharded program on a mesh) — `segment` is then
+    the first's."""
     segment: Segment
     spec: GroupSpec
     counts: np.ndarray                    # int64 [num_total]

@@ -6,9 +6,17 @@ merge, GroupBy RowBasedGrouperHelper). TPU-first design: partials are
 per-key state arrays; merging re-encodes every partial's keys into ONE
 merged key space (merged dictionaries play the DimensionMergerV9 role) and
 combines with the kernels' elementwise combine — vectorized, no per-row
-loop. `merge_partials` picks how the partials are ALIGNED in that space from
-what they show, and both alignments return the same groups in the same
-order with the same bits:
+loop. The merge has two halves. `merge_to_partial` aligns and combines and
+returns ONE `SegmentPartial` (host-keyed by the live merged keys, with the
+merged per-dimension value lists beside it) that stands for all its inputs
+and merges again like any of them: a data node answers with it
+(`AggregatePartials.merged`, as the reference's ServerManager merges its
+per-segment runners before anything is sent), so what crosses the wire and
+what the broker merges is a partial a NODE. `decode_merged` turns such a
+partial into the finish steps' arrays; `merge_partials` is the second
+applied to the first. The first half picks how the partials are ALIGNED in
+the merged space from what they show, and both alignments return the same
+groups in the same order with the same bits:
 
   dense   (`_merge_dense`) when the merged space — buckets × the product of
           the merged per-dimension value lists (the sorted union of the
@@ -37,7 +45,7 @@ from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
 import numpy as np
 
 from druid_tpu.data.dictionary import Dictionary, merge_dictionaries
-from druid_tpu.engine.grouping import (DENSE_GROUP_LIMIT, GroupSpec,
+from druid_tpu.engine.grouping import (DENSE_GROUP_LIMIT, GroupSpec, KeyDim,
                                        SegmentPartial)
 from druid_tpu.engine.kernels import AggKernel
 from druid_tpu.obs.trace import current_span
@@ -98,25 +106,68 @@ def merge_partials(partials: Sequence[SegmentPartial],
       dim_value_arrays: list of object arrays [G] of string values per dim
       counts: int64 [G]; states: merged state pytrees; kernels: from partial 0.
 
-    Groups come out live (count > 0) and in merged-key order. The innermost
-    open trace span (`broker/merge` under a broker) learns which alignment
-    ran (`mergePath`) and how many groups came out (`groups`).
+    Groups come out live (count > 0) and in merged-key order: this is
+    `decode_merged` of `merge_to_partial`. A partial that is itself a merge
+    (a data node's answer) goes through the same two steps; merging one
+    partial combines nothing, so its bits come out as they went in.
     """
+    return decode_merged(*merge_to_partial(partials, dim_values))
+
+
+def merge_to_partial(partials: Sequence[SegmentPartial],
+                     dim_values: Sequence[Sequence[Sequence[str]]]
+                     ) -> Tuple[SegmentPartial, List[list]]:
+    """The first half of `merge_partials`: align in the merged key space and
+    combine. Returns (merged partial, merged per-dimension value lists) — a
+    `SegmentPartial` that stands for all of `partials` and merges with
+    others like any of them: `key_mode` "host" with `host_unique` the live
+    merged keys in order, `dims` at the merged cardinalities, `counts` and
+    `states` compacted to the live groups in fresh arrays (nothing of the
+    inputs is written to), the kernels and segment of partial 0.
+
+    The innermost open trace span (`datanode/merge` on a data node,
+    `broker/merge` under a broker) learns which alignment ran (`mergePath`)
+    and how many groups came out (`groups`)."""
     assert partials
     space = _dense_space(partials, dim_values)
-    out = _merge_sorted(partials, dim_values) if space is None \
-        else _merge_dense(partials, space)
+    if space is None:
+        keys, values, counts, states = _merge_sorted(partials, dim_values)
+    else:
+        keys, values, counts, states = _merge_dense(partials, space)
     sp = current_span()
     if sp is not None:
         sp.attrs["mergePath"] = "sorted" if space is None else "dense"
-        sp.attrs["groups"] = int(len(out[2]))
-    return out
+        sp.attrs["groups"] = int(len(keys))
+    # the widest bucket axis: every merged key decodes inside it
+    spec0 = max((p.spec for p in partials), key=lambda s: s.num_buckets)
+    values = [v or [""] for v in values]
+    spec = GroupSpec(
+        bucket_starts=spec0.bucket_starts, bucket_mode=spec0.bucket_mode,
+        uniform_period=spec0.uniform_period,
+        uniform_first_offset=spec0.uniform_first_offset,
+        key_mode="host",
+        dims=tuple(KeyDim(d.column, len(v), None)
+                   for d, v in zip(partials[0].spec.dims, values)),
+        host_unique=keys, num_total=len(keys))
+    return SegmentPartial(partials[0].segment, spec, counts, states,
+                          partials[0].kernels), values
+
+
+def decode_merged(p: SegmentPartial, values: Sequence[Sequence]):
+    """The second half of `merge_partials`: a partial of `merge_to_partial`
+    and its value lists as (buckets, dim_value_arrays, counts, states,
+    kernels)."""
+    buckets, dim_ids = decode_keys(p, np.arange(len(p.spec.host_unique)))
+    dim_value_arrays = [np.asarray(v, dtype=object)[ids]
+                        for v, ids in zip(values, dim_ids)]
+    return buckets, dim_value_arrays, p.counts, p.states, p.kernels
 
 
 def _merge_sorted(partials: Sequence[SegmentPartial],
                   dim_values: Sequence[Sequence[Sequence[str]]]):
     """The alignment that needs no bound on the key space: live keys only,
-    np.unique + searchsorted."""
+    np.unique + searchsorted. Returns (live merged keys, merged value lists
+    — the values live somewhere, in merged order —, counts, states)."""
     kernels = partials[0].kernels
     n_dims = len(partials[0].spec.dims)
 
@@ -179,20 +230,9 @@ def _merge_sorted(partials: Sequence[SegmentPartial],
             states = {k.name: k.combine(states[k.name], aligned[k.name])
                       for k in kernels}
 
-    # 5. decode merged keys back to (bucket, values)
-    raw = uniq.copy()
-    dim_value_arrays: List[np.ndarray] = [None] * n_dims
-    for d in range(n_dims - 1, -1, -1):
-        ids = raw % cards[d]
-        raw = raw // cards[d]
-        vals = np.asarray(merged_values[d], dtype=object) if merged_values[d] \
-            else np.asarray([""], dtype=object)
-        dim_value_arrays[d] = vals[ids.astype(np.int64)]
-    buckets = raw
-
     if states is None:
         states = {k.name: k.empty_state(G) for k in kernels}
-    return buckets, dim_value_arrays, counts, states, kernels
+    return uniq, merged_values, counts, states
 
 
 # ---------------------------------------------------------------------------
@@ -361,8 +401,9 @@ def _state_like(state, ref):
 
 def _merge_dense(partials: Sequence[SegmentPartial], space: _DenseSpace):
     """Place partial 0 in the merged arrays, combine the others into them in
-    partial order (so float sums round as on the sorted path), compact and
-    decode once."""
+    partial order (so float sums round as on the sorted path), compact
+    once. Returns (live merged keys, the space's value lists, counts,
+    states); no array of a partial is written to."""
     kernels = partials[0].kernels
     size = space.size
     counts = np.zeros(size, dtype=np.int64)
@@ -391,14 +432,7 @@ def _merge_dense(partials: Sequence[SegmentPartial], space: _DenseSpace):
             state_scatter(states[k.name], lacked, k.combine(
                 state_select(states[k.name], lacked),
                 k.empty_state(len(lacked))))
-    raw = live
-    dim_value_arrays: List[np.ndarray] = [None] * len(space.cards)
-    for d in range(len(space.cards) - 1, -1, -1):
-        ids = raw % space.cards[d]
-        raw = raw // space.cards[d]
-        vals = np.asarray(space.values[d] or [""], dtype=object)
-        dim_value_arrays[d] = vals[ids]
-    return raw, dim_value_arrays, counts, states, kernels
+    return live, space.values, counts, states
 
 
 def finalize_states(kernels: Sequence[AggKernel], states: Dict[str, object],

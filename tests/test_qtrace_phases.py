@@ -149,7 +149,15 @@ def test_one_trace_holds_every_phase(cluster):
     assert read["attrs"]["bytes"] == decode["attrs"]["bytes"] \
         == enc["attrs"]["wireBytes"] > 0
     assert enc["attrs"]["logicalBytes"] > 0
-    assert enc["attrs"]["partials"] == decode["attrs"]["partials"] == 3
+    # the node merged its three partials before the wire (ISSUE 29)
+    assert enc["attrs"]["partials"] == decode["attrs"]["partials"] == 1
+    (mrg,) = _named(spans, "datanode/merge")
+    assert mrg["parentId"] == dn["spanId"] and mrg["service"] == "pnode"
+    assert mrg["attrs"] == {"partialsIn": 3, "groups": len(rows),
+                            "mergePath": "dense"}
+    (bm,) = _named(spans, "broker/merge")
+    assert bm["attrs"]["partials"] == 1
+    assert bm["attrs"]["groups"] == len(rows)
     assert isinstance(enc["attrs"]["compressed"], bool)
     # the encode starts after the node's root ended, and inside the read
     assert enc["startMs"] >= dn["startMs"] + dn["durationMs"] - 1.0
@@ -238,11 +246,32 @@ def test_broker_merge_says_dense_over_shared_dictionaries(cluster):
     (m,) = _named(cluster.trace(qid), "broker/merge")
     assert m["attrs"]["mergePath"] == "dense"
     assert m["attrs"]["groups"] == len(rows) == 70
-    assert m["attrs"]["partials"] == 3
+    # one node, one merged partial: the node aligned its three the same way
+    assert m["attrs"]["partials"] == 1
+    (n,) = _named(cluster.trace(qid), "datanode/merge")
+    assert n["attrs"] == {"partialsIn": 3, "groups": 70,
+                          "mergePath": "dense"}
     # untraced: the same rows, and no span anywhere to hang an attribute on
     off = f"phases-merge-off-{cluster.tag}"
     assert cluster.post(_groupby(off, trace=False)) == rows
     assert qtrace.trace_store().get(off) is None
+
+
+def test_no_node_merge_span_for_one_partial(cluster):
+    """One day is one segment and one partial: nothing to merge on the
+    node, so no `datanode/merge` span opens and the partial crosses as it
+    was produced."""
+    qid = f"phases-one-partial-{cluster.tag}"
+    q = _groupby(qid)
+    q["intervals"] = ["2026-03-02/2026-03-03"]
+    assert cluster.post(q)
+    spans = cluster.trace(qid)
+    assert len(_named(spans, "engine/segment")) \
+        + len(_named(spans, "engine/batch/dispatch")) == 1
+    assert not _named(spans, "datanode/merge")
+    (enc,) = _named(spans, "datanode/encode")
+    (m,) = _named(spans, "broker/merge")
+    assert enc["attrs"]["partials"] == m["attrs"]["partials"] == 1
 
 
 def test_broker_merge_says_sorted_for_a_host_key_query():
@@ -260,9 +289,13 @@ def test_broker_merge_says_sorted_for_a_host_key_query():
         q = _groupby("phases-merge-host")
         q["dimensions"] = ["dimX", "dimY"]
         rows = c.post(q)
-        (m,) = _named(c.trace("phases-merge-host"), "broker/merge")
-        assert m["attrs"]["mergePath"] == "sorted"
-        assert m["attrs"]["groups"] == len(rows) > 6000
+        spans = c.trace("phases-merge-host")
+        (m,) = _named(spans, "broker/merge")
+        (n,) = _named(spans, "datanode/merge")
+        assert m["attrs"]["mergePath"] == n["attrs"]["mergePath"] == "sorted"
+        assert m["attrs"]["groups"] == n["attrs"]["groups"] == len(rows) \
+            > 6000
+        assert (n["attrs"]["partialsIn"], m["attrs"]["partials"]) == (2, 1)
     finally:
         c.stop()
 
@@ -294,7 +327,7 @@ def test_late_span_header_on_the_wire(cluster):
     from druid_tpu.query.model import query_from_json
     ap, served = client.run_partials(query_from_json(q),
                                      sorted(cluster.node.served_segment_ids()))
-    assert len(ap.partials) == 3 and len(served) == 3
+    assert len(ap.partials) == 1 and len(ap.spans) == len(served) == 3
 
 
 def test_rows_path_carries_the_wire_spans(cluster):
