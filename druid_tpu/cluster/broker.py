@@ -308,6 +308,9 @@ class Broker:
             rkey = result_level_key(
                 query, [f"{d.id}" for d in segments])
             hit = self.cache.get("result", rkey)
+            sp = qtrace.current_span()
+            if sp is not None:        # `broker/query`: its plan span closed
+                sp.attrs["resultCacheHit"] = int(hit is not None)
             if hit is not None:
                 return hit
 

@@ -19,15 +19,21 @@ operator inputs across queries/segments — here:
      in a single dispatch;
   4. hand back ONE SegmentPartial per segment from that dispatch.
 
-Stragglers — ineligible segments and undersized buckets — fall back to the
-per-segment path. Parity is structural, not coincidental: the batched
-program runs the SAME traced body (grouping.traced_segment) over the same staged
-columns and post-processes states with the same host_post, so results are
-bit-identical to per-segment execution.
+Stragglers — ineligible segments, and what the K ladder leaves of a bucket
+— fall back to the per-segment path, through the plan already made for
+them. Each is COUNTED (`stats()` `fallbackSegments`: every segment of a
+batch-planned request that ran alone, whether or not a batch dispatched
+beside it) and the request's `engine/batch/plan` span says how many
+(`stragglers`) and why (`reason`, contracts.BATCH_FALLBACK_REASONS). Parity
+is structural, not coincidental: the batched program runs the SAME traced
+body (grouping.traced_segment) over the same staged columns and
+post-processes states with the same host_post, so results are bit-identical
+to per-segment execution.
 
 Observability: every dispatch records (segments, fillRatio) for the
 `query/batch/*` emitter metrics (BatchMetricsMonitor, wired by
-cluster/dataserver.py).
+cluster/dataserver.py) and carries `paddedRows` (K × R) and `realRows` on
+its `engine/batch/dispatch` span.
 """
 from __future__ import annotations
 
@@ -48,7 +54,8 @@ from druid_tpu.engine import grouping
 from druid_tpu.engine.contracts import (BATCH_MAX_SEGMENT_ROWS,
                                         BATCH_MAX_SEGMENTS,
                                         BATCH_MIN_SEGMENTS, BATCH_ROW_ALIGN,
-                                        named_program, program_name)
+                                        batch_fallback_reason, named_program,
+                                        program_name)
 from druid_tpu.engine.filters import ConstNode
 from druid_tpu.engine.grouping import (GroupPlan, GroupSpec, KeyDim,
                                        SegmentPartial, assemble_stacked_aux,
@@ -118,7 +125,13 @@ _JIT_CACHE_LOCK = threading.Lock()
 
 class BatchStats:
     """Aggregate counters + a bounded per-dispatch event queue the emitter
-    monitor drains."""
+    monitor drains. `batches` / `batched_segments` / `stacked_rows` /
+    `stacked_slots` count stacked dispatches, their segments, their real
+    rows and their padded rows (K × R); `fallback_segments` counts EVERY
+    segment of a batch-planned request that ran alone through the
+    per-segment path — a request none of whose segments batched counts all
+    of them. Segments of a request that was never batch-planned (batching
+    off, fewer than BATCH_MIN_SEGMENTS segments) are in neither."""
 
     EVENT_CAP = 4096
 
@@ -227,10 +240,19 @@ class _Plan:
     req: int = 0                     # owning request (multi-query split-back)
     #: False = straggler (runs per-segment, but still through this gplan)
     eligible: bool = False
+    #: why a plan no chunk takes runs alone (contracts.BATCH_FALLBACK_REASONS)
+    reason: str = ""
     rung: int = 0
     packs: Tuple = ()                # pack descriptor (data/packed.py)
     cascades: Tuple = ()             # cascade descriptor (data/cascade.py)
     digest: Tuple = None             # hashable shape-bucket prefilter
+
+
+def _alone(plan: _Plan, reason: str) -> _Plan:
+    """`plan` as a straggler for `reason` (one of the closed set)."""
+    plan.eligible = False
+    plan.reason = batch_fallback_reason(reason)
+    return plan
 
 
 def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
@@ -252,7 +274,7 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
     plan = _Plan(segment=segment, index=index, gplan=gplan,
                  intervals=tuple(intervals), granularity=granularity)
     if segment.n_rows > BATCH_MAX_SEGMENT_ROWS:
-        return plan
+        return _alone(plan, "rows_over_limit")
     if cascade.enabled() and cascade.run_domain_probe(
             segment, intervals, granularity, gplan.spec, gplan.kernels,
             flt, virtual_columns):
@@ -260,7 +282,7 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
         # fully over run metadata (run_grouped_aggregate's cascade hook) —
         # stacking it into a row program would decode what never needs
         # decoding
-        return plan
+        return _alone(plan, "code_domain")
     if any(d.host_ids is not None and d.ids_key is None for d in kds):
         # a derived id column with no stable cache identity cannot stage
         # through the pool — keep per-segment. Numeric/expression dims DO
@@ -268,10 +290,10 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
         # the query's segments (engines.unify_query_dims), so their plan
         # constants (cardinality, remap) are no longer segment-local —
         # the host-mask-era exclusion is gone.
-        return plan
+        return _alone(plan, "no_stable_id_column")
     spec, filter_node, kernels = gplan.spec, gplan.filter_node, gplan.kernels
     if spec.key_mode != "dense" or spec.bucket_mode not in ("all", "uniform"):
-        return plan
+        return _alone(plan, "key_or_bucket_mode")
     if spec.num_total > grouping.BLOCKED_GROUP_LIMIT:
         # bounded group spaces make select_strategy a pure function of
         # (num_total, kernels, dtypes) — identical for the batched rung and
@@ -280,11 +302,11 @@ def _plan_for(segment: Segment, kds: Sequence[KeyDim], index: int,
         # clustering (windowed/projection), which could diverge between
         # chunk-mates and reorder float accumulation; those segments are
         # also scatter-compute-bound, where dispatch amortization is noise
-        return plan
+        return _alone(plan, "group_space_over_limit")
     if isinstance(filter_node, ConstNode) and not filter_node.value:
         # constant-false: the per-segment path skips the device entirely —
         # batching it would only waste a stacked slot
-        return plan
+        return _alone(plan, "constant_false")
     columns = gplan.columns
     # complex (2-D) metric columns — HLL registers, sketch states — stack
     # like any other column now that the mask is in-program; their width is
@@ -359,6 +381,50 @@ def _pow2_chunks(group: List[_Plan]) -> Tuple[List[List[_Plan]], List[_Plan]]:
     return out, group[i:]
 
 
+def _plan_chunks(plans: Sequence[_Plan], plan_span) -> List[List[_Plan]]:
+    """The stacked dispatches of one planning pass, each a chunk of K
+    bucket-mates (a power of two): eligible plans grouped into shape
+    buckets, each bucket cut along the K ladder, each chunk's strategy
+    selected and written into its plans' specs. A plan no chunk takes
+    becomes a straggler with its reason; `plan_span` (the open
+    `engine/batch/plan` span, None untraced) is stamped with what was
+    decided."""
+    eligible = [p for p in plans if p.eligible]
+    buckets = _shape_buckets(eligible)
+    chunks: List[List[_Plan]] = []
+    for bucket in buckets:
+        cut, remainder = _pow2_chunks(bucket)
+        for mates in cut:
+            ref = mates[0].gplan
+            strategy, window = grouping.select_strategy(
+                ref.spec, ref.kernels, ref.col_dtypes, mates[0].rung,
+                lambda: common_window(
+                    windowed_window(p.segment, p.intervals, p.granularity,
+                                    p.gplan.spec) for p in mates))
+            if strategy == "projection":
+                # sorted projections are per-segment layouts a stacked
+                # program cannot share — and projection-grade segments are
+                # big enough that per-segment dispatch overhead is already
+                # amortized
+                for p in mates:
+                    _alone(p, "projection_layout")
+                continue
+            for p in mates:
+                p.gplan.spec.strategy, p.gplan.spec.window = strategy, window
+            chunks.append(mates)
+        for p in remainder:
+            _alone(p, "ladder_remainder")
+    if plan_span is not None:
+        reasons = collections.Counter(p.reason for p in plans
+                                      if not p.eligible)
+        plan_span.attrs.update(
+            eligible=len(eligible), buckets=len(buckets), chunks=len(chunks),
+            stragglers=sum(reasons.values()))
+        if reasons:
+            plan_span.attrs["reason"] = reasons.most_common(1)[0][0]
+    return chunks
+
+
 # ---------------------------------------------------------------------------
 # The batched device program
 # ---------------------------------------------------------------------------
@@ -386,30 +452,17 @@ def _build_batched_fn(spec: GroupSpec, filter_node,
                                                   spec.strategy)))
 
 
-def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
-    """Execute one shape bucket as a single dispatch; None = the bucket
-    cannot run stacked (projection-grade group space) and the caller falls
-    back per-segment. The chunk may mix plans from several queries
-    (run_multi_with_batching): every per-query origin — interval bounds,
-    bucket start — is derived from the plan's OWN intervals, so
-    cross-query mates produce exactly the partials their own serial run
-    would."""
+def _run_batch(chunk: List[_Plan]) -> List[SegmentPartial]:
+    """Execute one planned chunk (`_plan_chunks`: its strategy is in its
+    plans' specs) as a single dispatch. The chunk may mix
+    plans from several queries (run_multi_with_batching): every per-query
+    origin — interval bounds, bucket start — is derived from the plan's OWN
+    intervals, so cross-query mates produce exactly the partials their own
+    serial run would."""
     ref = chunk[0].gplan
+    strategy = ref.spec.strategy
     R = chunk[0].rung
     K = len(chunk)                  # a power of two by _pow2_chunks
-
-    strategy, window = grouping.select_strategy(
-        ref.spec, ref.kernels, ref.col_dtypes, R,
-        lambda: common_window(
-            windowed_window(p.segment, p.intervals, p.granularity,
-                            p.gplan.spec) for p in chunk))
-    if strategy == "projection":
-        # sorted projections are per-segment layouts a stacked program
-        # cannot share — and projection-grade segments are big enough that
-        # per-segment dispatch overhead is already amortized
-        return None
-    for p in chunk:
-        p.gplan.spec.strategy, p.gplan.spec.window = strategy, window
 
     blocks = [p.segment.device_block(list(ref.columns), row_align=R)
               for p in chunk]
@@ -457,8 +510,10 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
             _JIT_CACHE.move_to_end(sig)
 
     from druid_tpu.obs import dispatch as dispatch_mod
+    real_rows = sum(p.segment.n_rows for p in chunk)
     with trace_span("engine/batch/dispatch", strategy=strategy, segments=K,
-                    rows=R, compile=compiled,
+                    rows=R, paddedRows=K * R, realRows=real_rows,
+                    compile=compiled,
                     program=program_name("batch_agg", strategy)), \
             trace_span_when(compiled, "engine/compile", kind="batched",
                             strategy=strategy):
@@ -471,7 +526,7 @@ def _run_batch(chunk: List[_Plan]) -> Optional[List[SegmentPartial]]:
     out = fetch_partials(
         [(p.segment, p.gplan.spec, p.gplan.kernels) for p in chunk], outs,
         segments=K)
-    _STATS.record_batch(K, sum(p.segment.n_rows for p in chunk), K * R)
+    _STATS.record_batch(K, real_rows, K * R)
     return out
 
 
@@ -495,41 +550,26 @@ def run_with_batching(segs: Sequence[Segment], intervals: Sequence[Interval],
     if not query_enabled(context) or len(segs) < BATCH_MIN_SEGMENTS:
         return None
 
-    with trace_span("engine/batch/plan", segments=len(segs)):
+    with trace_span("engine/batch/plan", segments=len(segs)) as plan_span:
         plans = [_plan_for(s, kds, i, intervals, granularity, aggs, flt,
                            virtual_columns)
                  for i, (s, kds) in enumerate(zip(segs, kds_per_seg))]
-        buckets = _shape_buckets([p for p in plans if p.eligible])
-    if not any(len(b) >= BATCH_MIN_SEGMENTS for b in buckets):
-        # nothing batches — but the per-segment planning already happened:
-        # run the plain path HERE so the plans are executed, not rebuilt
-        return [_run_straggler(p, aggs, flt, virtual_columns, check,
-                               first=(i == 0))
-                for i, p in enumerate(plans)]
+        chunks = _plan_chunks(plans, plan_span)
 
     results: List[Optional[SegmentPartial]] = [None] * len(segs)
-    dispatched = 0
-    for bucket in buckets:
-        if len(bucket) < BATCH_MIN_SEGMENTS:
-            continue
-        chunks, _remainder = _pow2_chunks(bucket)
-        for chunk in chunks:
-            if check is not None and dispatched:
-                check()
-            partials = _run_batch(chunk)
-            if partials is None:
-                continue
-            dispatched += 1
-            for p, partial in zip(chunk, partials):
-                results[p.index] = partial
+    for n, chunk in enumerate(chunks):
+        if check is not None and n:
+            check()
+        for p, partial in zip(chunk, _run_batch(chunk)):
+            results[p.index] = partial
 
-    n_fallback = sum(1 for r in results if r is None)
-    if dispatched and n_fallback:
-        _STATS.record_fallback(n_fallback)
+    # the rest runs alone — the per-segment planning already happened, so
+    # the plans are executed HERE, not rebuilt by the caller
+    _STATS.record_fallback(sum(1 for r in results if r is None))
     for i, p in enumerate(plans):
         if results[i] is None:
             results[i] = _run_straggler(p, aggs, flt, virtual_columns, check,
-                                        first=not dispatched and i == 0)
+                                        first=not chunks and i == 0)
     return results
 
 
@@ -586,7 +626,7 @@ def run_multi_with_batching(work: Sequence[BatchWork],
     all_plans: List[List[_Plan]] = []
     with trace_span("engine/batch/plan",
                     queries=len(work),
-                    segments=sum(len(w.segs) for w in work)):
+                    segments=sum(len(w.segs) for w in work)) as plan_span:
         for r, w in enumerate(work):
             opted_out = not query_enabled(w.context)
             plans = []
@@ -595,11 +635,11 @@ def run_multi_with_batching(work: Sequence[BatchWork],
                               w.aggs, w.flt, w.virtual_columns)
                 p.req = r
                 if opted_out:
-                    p.eligible = False
+                    _alone(p, "opted_out")
                 plans.append(p)
             all_plans.append(plans)
-        buckets = _shape_buckets([p for plans in all_plans
-                                  for p in plans if p.eligible])
+        chunks = _plan_chunks([p for plans in all_plans for p in plans],
+                              plan_span)
 
     results: List[List[Optional[SegmentPartial]]] = \
         [[None] * len(plans) for plans in all_plans]
@@ -615,41 +655,32 @@ def run_multi_with_batching(work: Sequence[BatchWork],
                 dead[r] = e
 
     dispatched = 0
-    for bucket in buckets:
-        if len(bucket) < BATCH_MIN_SEGMENTS:
+    for live in chunks:
+        if dispatched:
+            _poll_checks()
+        if any(p.req in dead for p in live):
+            # a cancelled mate shrank the chunk below its pow2 size — K is
+            # a compile key, so dispatching the odd size would pay a
+            # one-off compile; survivors take the (cached) per-segment
+            # straggler path instead
             continue
-        chunks, _remainder = _pow2_chunks(bucket)
-        for chunk in chunks:
-            if dispatched:
-                _poll_checks()
-            live = [p for p in chunk if p.req not in dead]
-            if not live:
-                continue
-            if len(live) < len(chunk):
-                # a cancelled mate shrank the chunk below its pow2 size —
-                # K is a compile key, so dispatching the odd size would
-                # pay a one-off compile; survivors take the (cached)
-                # per-segment straggler path instead
-                continue
-            try:
-                partials = _run_batch(live)
-            except Exception:
-                # a batch-specific failure must not kill queries that
-                # would succeed serially: participants fall back to the
-                # per-segment straggler path below
-                logging.getLogger(__name__).exception(
-                    "batched dispatch failed; falling back per-segment")
-                continue
-            if partials is None:
-                continue
-            dispatched += 1
-            if on_batch is not None:
-                slots = len(live) * live[0].rung
-                rows = sum(p.segment.n_rows for p in live)
-                on_batch(len({p.req for p in live}), len(live),
-                         rows / slots if slots else 0.0)
-            for p, partial in zip(live, partials):
-                results[p.req][p.index] = partial
+        try:
+            partials = _run_batch(live)
+        except Exception:
+            # a batch-specific failure must not kill queries that would
+            # succeed serially: participants fall back to the per-segment
+            # straggler path below
+            logging.getLogger(__name__).exception(
+                "batched dispatch failed; falling back per-segment")
+            continue
+        dispatched += 1
+        if on_batch is not None:
+            slots = len(live) * live[0].rung
+            rows = sum(p.segment.n_rows for p in live)
+            on_batch(len({p.req for p in live}), len(live),
+                     rows / slots if slots else 0.0)
+        for p, partial in zip(live, partials):
+            results[p.req][p.index] = partial
 
     _poll_checks()
     out: List[object] = []
@@ -658,9 +689,7 @@ def run_multi_with_batching(work: Sequence[BatchWork],
             out.append(dead[r])
             continue
         res = results[r]
-        n_fallback = sum(1 for x in res if x is None)
-        if dispatched and n_fallback:
-            _STATS.record_fallback(n_fallback)
+        _STATS.record_fallback(sum(1 for x in res if x is None))
         try:
             for i, p in enumerate(plans):
                 if res[i] is None:

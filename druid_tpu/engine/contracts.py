@@ -290,6 +290,31 @@ def sharded_fallback_reason(reason: str) -> str:
     return reason
 
 
+#: THE closed set of reasons a segment of a batch-planned request runs
+#: alone through the per-segment path (engine/batching.py, one per exit of
+#: `_plan_for` and of the chunking): the `reason` attribute of an
+#: `engine/batch/plan` span whose `stragglers` is above 0 — the reason that
+#: holds most of them. tests/test_batch_served.py holds the source to it.
+BATCH_FALLBACK_REASONS = (
+    "rows_over_limit",             # more than BATCH_MAX_SEGMENT_ROWS rows
+    "code_domain",                 # answered over run metadata, undecoded
+    "no_stable_id_column",         # a derived id column the pool cannot key
+    "key_or_bucket_mode",          # not dense keys over all/uniform buckets
+    "group_space_over_limit",      # more than BLOCKED_GROUP_LIMIT groups
+    "constant_false",              # the filter folded to false: no device
+    "projection_layout",           # a sorted projection is one segment's own
+    "ladder_remainder",            # what the K ladder leaves: a lone segment
+    "opted_out",                   # its query's {"batchSegments": false}
+)
+
+
+def batch_fallback_reason(reason: str) -> str:
+    """`reason`, refused unless it is in BATCH_FALLBACK_REASONS."""
+    if reason not in BATCH_FALLBACK_REASONS:
+        raise ValueError(f"{reason!r} is not a documented fall-back reason")
+    return reason
+
+
 # ---- dtype lattice --------------------------------------------------------
 
 DTYPE_BYTES = {

@@ -842,10 +842,11 @@ def traced_segment(spec: GroupSpec, filter_node: Optional[FilterNode],
     elif spec.bucket_mode == "uniform":
         # int32 bucket math: offsets are int32 by construction and uniform
         # periods (≤ week) fit int32; 64-bit div would be limb-emulated on
-        # TPU
+        # TPU. The origin is (offset within a period, whole periods), so
+        # neither term leaves int32 however far back the first bucket lies
         period = next(it)
         nb = next(it)  # num buckets as device scalar
-        b = (t - bucket_off) // period
+        b = (t - bucket_off[0]) // period - bucket_off[1]
         mask = mask & (b >= 0) & (b < nb)
         key = b.astype(jnp.int32)
     else:
@@ -889,17 +890,22 @@ def stacked_origins(segments: Sequence[Segment],
     """Per-segment origins of the traced body, `[K]`-leading (K pads past
     the segments with zeros: no interval, so no row): each segment's start
     (`time0s`, int64), the query intervals RELATIVE to it (`iv_rel`, int32
-    `[K, n, 2]`) and its uniform bucket origin (`bucket_off`, int32). The
-    device program stays in int32 offset space (64-bit elementwise time
-    math is limb-emulated on TPU), so everything relative clips HERE, once,
-    to int32: a bound beyond ±24.8 days of the segment's start lies
-    outside every row offset anyway."""
+    `[K, n, 2]`) and its uniform bucket origin (`bucket_off`, int32
+    `[K, 2]`). The device program stays in int32 offset space (64-bit
+    elementwise time math is limb-emulated on TPU), so everything relative
+    is brought into int32 HERE, once. An interval bound clips: beyond ±24.8
+    days of the segment's start it lies outside every row offset anyway. A
+    bucket origin does not clip — a query's first bucket may start months
+    before a segment whose rows it still counts — it SPLITS into (offset
+    within a period, whole periods): row offset `t` falls in bucket
+    `(t - rest) // period - whole`, exact at any distance. Only `whole`
+    clips, and only where no row can pass the interval bounds."""
     K = len(segments) if K is None else K
     clip_lo, clip_hi = -(2**31) + 1, 2**31 - 1
     n_iv = max((len(ivs) for ivs in intervals_per_segment), default=0)
     time0s = np.zeros((K,), dtype=np.int64)
     iv_rel = np.zeros((K, max(n_iv, 1), 2), dtype=np.int32)
-    bucket_off = np.zeros((K,), dtype=np.int32)
+    bucket_off = np.zeros((K, 2), dtype=np.int32)
     for i, (s, ivs, spec) in enumerate(zip(segments, intervals_per_segment,
                                            specs)):
         t0 = time0s[i] = s.interval.start
@@ -907,8 +913,9 @@ def stacked_origins(segments: Sequence[Segment],
             iv_rel[i, j, 0] = min(max(ivl.start - t0, clip_lo), clip_hi)
             iv_rel[i, j, 1] = min(max(ivl.end - t0, clip_lo), clip_hi)
         if spec.bucket_mode == "uniform":
-            bucket_off[i] = min(max(int(spec.bucket_starts[0]) - t0,
-                                    clip_lo), clip_hi)
+            whole, rest = divmod(int(spec.bucket_starts[0]) - t0,
+                                 int(spec.uniform_period))
+            bucket_off[i] = rest, min(max(whole, clip_lo), clip_hi)
     return time0s, iv_rel, bucket_off
 
 

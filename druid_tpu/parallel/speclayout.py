@@ -73,7 +73,7 @@ class SpecLayout:
 
     def time0s(self):
         """Per-segment scalars [K]: time origins, delta-column firsts,
-        RLE and validity (prefix-mask) row counts, bucket offsets."""
+        RLE and validity (prefix-mask) row counts."""
         return _pspec()(self.seg_axis)
 
     def interval_bounds(self):
@@ -81,8 +81,9 @@ class SpecLayout:
         return _pspec()(self.seg_axis, None, None)
 
     def bucket_offsets(self):
-        """Per-segment uniform-granularity bucket origins [K]."""
-        return self.time0s()
+        """Per-segment uniform-granularity bucket origins [K, 2]: offset
+        within a period, whole periods (grouping.stacked_origins)."""
+        return _pspec()(self.seg_axis, None)
 
     def replicated(self):
         """Plan constants (aux arrays): replicated on every device."""

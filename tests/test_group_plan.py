@@ -9,8 +9,8 @@ most easily:
       and states on the same staged segment — and the counts the served
       path answers with;
   (b) `GroupPlan.columns` / `col_dtypes` are what the stage phase stages;
-  (c) `stacked_origins` clips at both ends of int32 and is `_assemble_aux`'s
-      head;
+  (c) `stacked_origins` clips bounds at both ends of int32, splits bucket
+      origins into (offset, whole periods), and is `_assemble_aux`'s head;
 and, by AST, that the planner's calls and the `engine/fetch` span have one
 home each.
 """
@@ -217,17 +217,22 @@ def test_stacked_origins_clip_at_both_ends_of_int32(granularity):
     assert iv_rel[0].tolist() == [[lo, hi]]
     assert iv_rel[1].tolist() == [[hi, hi]]
     assert not iv_rel[2:].any()         # padding: no interval, so no row
-    assert bucket_off.dtype == np.int32
+    # a bucket origin does not clip, it splits: (offset within a period,
+    # whole periods) — a year of hours before the segment, 92 days after
+    assert bucket_off.dtype == np.int32 and bucket_off.shape == (4, 2)
     if granularity == "all":
         assert not bucket_off.any()
     else:
-        assert bucket_off.tolist() == [lo, hi, 0, 0]
+        assert bucket_off.tolist() == [[0, -365 * 24], [0, 92 * 24],
+                                       [0, 0], [0, 0]]
     # an in-range origin is the number the group spec holds
     near = [Interval.of("2026-05-31", "2026-06-02")]
     spec = grouping.make_group_spec(seg, near, g, ())
     _, iv1, off1 = grouping.stacked_origins([seg], [near], [spec])
     assert iv1[0].tolist() == [[near[0].start - t0, near[0].end - t0]]
-    assert int(off1[0]) == spec.uniform_first_offset
+    rest, whole = off1[0].tolist()
+    assert 0 <= rest < max(spec.uniform_period, 1)
+    assert rest + whole * spec.uniform_period == spec.uniform_first_offset
 
 
 @pytest.mark.parametrize("granularity", ["all", "hour"])
