@@ -1091,7 +1091,7 @@ def _build_run_fn(dim_cols: Tuple, has_remap: Tuple, filter_node,
 def run_domain_probe(segment, intervals, granularity, spec, kernels,
                      flt, virtual_columns) -> bool:
     """Cheap eligibility-only check (batching._plan_for routes eligible
-    segments to the per-segment path so run_grouped_aggregate can take the
+    segments to the per-segment path so its enqueue can take the
     code-domain shortcut)."""
     return _plan_run_domain(segment, intervals, granularity, spec,
                             kernels, flt, virtual_columns) is not None
@@ -1102,7 +1102,7 @@ def _plan_run_domain(segment, intervals, granularity, spec, kernels,
     """None, or (dim structure, run filter node, run kernels, run columns,
     partition key) when the whole grouped aggregate can run over run
     metadata. Memoized on the (single-use — grouping.GroupPlan contract)
-    spec: batching's eligibility probe and run_grouped_aggregate's
+    spec: batching's eligibility probe and enqueue_grouped_aggregate's
     execution hook share one planning pass instead of re-planning the
     filter and kernels on the hot path."""
     cached = getattr(spec, "_cascade_run_plan", None)

@@ -431,7 +431,7 @@ class Segment:
         """Standing-query carry bridge (engine/standing.py): a live sink's
         snapshot is a FRESH Segment every generation, so the megakernel's
         per-segment donated carries would never be reused across ticks.
-        Naming the previous snapshot here lets run_grouped_aggregate's
+        Naming the previous snapshot here lets the per-segment enqueue's
         carry take fall back to the donor's parked grids. ONLY carries may
         bridge — they are content-free HBM allocations the kernel re-inits
         at grid step 0; staged data never transfers between segments.

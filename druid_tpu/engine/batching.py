@@ -60,9 +60,10 @@ from druid_tpu.engine.filters import ConstNode
 from druid_tpu.engine.grouping import (GroupPlan, GroupSpec, KeyDim,
                                        SegmentPartial, assemble_stacked_aux,
                                        aux_equal, common_window,
+                                       enqueue_grouped_aggregate,
                                        fetch_partials, keydims_equal,
                                        plan_grouped_aggregate,
-                                       run_grouped_aggregate,
+                                       run_grouped_aggregates,
                                        stacked_origins, traced_segment,
                                        windowed_window)
 from druid_tpu.engine.kernels import AggKernel
@@ -453,12 +454,22 @@ def _build_batched_fn(spec: GroupSpec, filter_node,
 
 
 def _run_batch(chunk: List[_Plan]) -> List[SegmentPartial]:
-    """Execute one planned chunk (`_plan_chunks`: its strategy is in its
-    plans' specs) as a single dispatch. The chunk may mix
-    plans from several queries (run_multi_with_batching): every per-query
-    origin — interval bounds, bucket start — is derived from the plan's OWN
-    intervals, so cross-query mates produce exactly the partials their own
-    serial run would."""
+    """One planned chunk, enqueued and fetched on its own: what the
+    cross-query entry runs, whose per-chunk failure fall-back needs each
+    chunk's result before the next."""
+    targets, outs = zip(*_enqueue_batch(chunk))
+    return fetch_partials(targets, outs, segments=len(chunk))
+
+
+def _enqueue_batch(chunk: List[_Plan]) -> List[Tuple]:
+    """ENQUEUE one planned chunk (`_plan_chunks`: its strategy is in its
+    plans' specs) as a single dispatch; returns an entry a segment for
+    `fetch_partials` / `run_grouped_aggregates`: ((segment, spec,
+    kernels), (counts, states)), the outputs still on their way. The chunk
+    may mix plans from several queries (run_multi_with_batching): every
+    per-query origin — interval bounds, bucket start — is derived from the
+    plan's OWN intervals, so cross-query mates produce exactly the partials
+    their own serial run would."""
     ref = chunk[0].gplan
     strategy = ref.spec.strategy
     R = chunk[0].rung
@@ -523,11 +534,9 @@ def _run_batch(chunk: List[_Plan]) -> List[SegmentPartial]:
     # falls back per-segment and must not double-bill the scoreboard
     dispatch_mod.record("batched")
 
-    out = fetch_partials(
-        [(p.segment, p.gplan.spec, p.gplan.kernels) for p in chunk], outs,
-        segments=K)
     _STATS.record_batch(K, real_rows, K * R)
-    return out
+    return [((p.segment, p.gplan.spec, p.gplan.kernels), out)
+            for p, out in zip(chunk, outs)]
 
 
 # ---------------------------------------------------------------------------
@@ -545,8 +554,11 @@ def run_with_batching(segs: Sequence[Segment], intervals: Sequence[Interval],
     batched dispatches for every shape bucket of ≥ BATCH_MIN_SEGMENTS
     compatible segments and the per-segment path for stragglers. Returns
     None when batching is off / inapplicable (caller runs plain
-    per-segment). `check` (optional cancel/timeout probe) fires between
-    dispatches — batch and straggler alike."""
+    per-segment). Every chunk is ENQUEUED, then every straggler, and the
+    request's results are fetched once (grouping.run_grouped_aggregates:
+    ONE `engine/fetch`, `programs` = chunks + stragglers that ran a
+    program). `check` (optional cancel/timeout probe) fires between
+    enqueues — batch and straggler alike — and before the fetch."""
     if not query_enabled(context) or len(segs) < BATCH_MIN_SEGMENTS:
         return None
 
@@ -556,30 +568,25 @@ def run_with_batching(segs: Sequence[Segment], intervals: Sequence[Interval],
                  for i, (s, kds) in enumerate(zip(segs, kds_per_seg))]
         chunks = _plan_chunks(plans, plan_span)
 
-    results: List[Optional[SegmentPartial]] = [None] * len(segs)
-    for n, chunk in enumerate(chunks):
-        if check is not None and n:
-            check()
-        for p, partial in zip(chunk, _run_batch(chunk)):
-            results[p.index] = partial
-
     # the rest runs alone — the per-segment planning already happened, so
     # the plans are executed HERE, not rebuilt by the caller
-    _STATS.record_fallback(sum(1 for r in results if r is None))
-    for i, p in enumerate(plans):
-        if results[i] is None:
-            results[i] = _run_straggler(p, aggs, flt, virtual_columns, check,
-                                        first=not chunks and i == 0)
+    stragglers = [p for p in plans if not p.eligible]
+    _STATS.record_fallback(len(stragglers))
+    work = [functools.partial(_enqueue_batch, chunk) for chunk in chunks]
+    work += [functools.partial(_enqueue_straggler, p, aggs, flt,
+                               virtual_columns) for p in stragglers]
+    order = [p.index for chunk in chunks for p in chunk]
+    order += [p.index for p in stragglers]
+    results: List[Optional[SegmentPartial]] = [None] * len(segs)
+    for i, partial in zip(order, run_grouped_aggregates(work, check)):
+        results[i] = partial
     return results
 
 
-def _run_straggler(p: _Plan, aggs, flt, virtual_columns, check,
-                   first: bool) -> SegmentPartial:
-    """Per-segment execution reusing the plan built for bucket grouping
-    (the ROADMAP's 'stragglers are planned twice' follow-on, closed)."""
-    if check is not None and not first:
-        check()
-    return run_grouped_aggregate(
+def _enqueue_straggler(p: _Plan, aggs, flt, virtual_columns):
+    """Per-segment enqueue reusing the plan built for bucket grouping (the
+    ROADMAP's 'stragglers are planned twice' follow-on, closed)."""
+    return enqueue_grouped_aggregate(
         p.segment, p.intervals, p.granularity, p.gplan.spec.dims, aggs, flt,
         virtual_columns=virtual_columns, plan=p.gplan)
 
@@ -689,15 +696,17 @@ def run_multi_with_batching(work: Sequence[BatchWork],
             out.append(dead[r])
             continue
         res = results[r]
-        _STATS.record_fallback(sum(1 for x in res if x is None))
+        alone = [p for i, p in enumerate(plans) if res[i] is None]
+        _STATS.record_fallback(len(alone))
         try:
-            for i, p in enumerate(plans):
-                if res[i] is None:
-                    res[i] = _run_straggler(
-                        p, w.aggs, w.flt, w.virtual_columns, w.check,
-                        first=not dispatched and i == 0)
+            partials = run_grouped_aggregates(
+                [functools.partial(_enqueue_straggler, p, w.aggs, w.flt,
+                                   w.virtual_columns) for p in alone],
+                w.check)
         except Exception as e:
             out.append(e)
             continue
+        for p, partial in zip(alone, partials):
+            res[p.index] = partial
         out.append(res)
     return out

@@ -177,6 +177,14 @@ FILTER_WORDS_PER_BLOCK = BLK_SMALL_W // FILTER_WORD_BITS
 #: DRUID_TPU_DEVICE_POOL_BYTES env var or DeviceSegmentPool.configure().
 DEVICE_POOL_BUDGET_BYTES = 4 * 1024 ** 3
 
+#: bound on the OUTPUT bytes of programs a request has enqueued and not
+#: fetched yet (grouping.run_grouped_aggregates): a request enqueues every
+#: program it needs and fetches once, so its un-fetched outputs stay in HBM
+#: outside the pool's budget until then. At the bound the pending programs
+#: are fetched and the enqueues go on. Twenty 100,000-group partials are
+#: ~52 MB and never reach it; 480 of them (1.25 GB) drain in five waves.
+PENDING_FETCH_BYTES = 256 * 1024 ** 2
+
 # ---- donation platform gate (donated carry buffers) -----------------------
 
 #: backends whose runtimes honor buffer donation. CPU *accepts*

@@ -16,6 +16,7 @@ epoch millis ints; the HTTP layer renders ISO strings).
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -28,7 +29,8 @@ import numpy as np
 from druid_tpu.data.segment import Segment, ValueType
 from druid_tpu.engine import batching
 from druid_tpu.engine.filters import host_mask
-from druid_tpu.engine.grouping import KeyDim, run_grouped_aggregate
+from druid_tpu.engine.grouping import (KeyDim, enqueue_grouped_aggregate,
+                                       run_grouped_aggregates)
 from druid_tpu.engine.merge import merge_partials, merge_to_partial
 from druid_tpu.parallel import distributed
 from druid_tpu.query.model import (DefaultLimitSpec, DimensionSpec, GroupByQuery,
@@ -238,9 +240,18 @@ def _make_partials(segs, intervals, query, kds_per_seg, vals_per_seg,
     host before it answers (`AggregatePartials.merged`) and a local run
     merges in its finish step.
 
+    A request ENQUEUES every program it needs and fetches once
+    (grouping.run_grouped_aggregates): its spans are one `engine/segment`
+    (or `engine/batch/dispatch`) a program, all host work, then ONE
+    `engine/fetch` under `engine/partials` whose `programs` counts them.
+
     `check` (cancel/timeout probe) runs at every dispatch boundary: between
-    per-segment programs, between batched shape-bucket dispatches, and
-    before the single sharded program."""
+    per-segment programs, between batched shape-bucket dispatches, before
+    the single sharded program, and once before the fetch. Nothing recalls
+    an enqueued program: a request cancelled after its last enqueue raises
+    at the check before the fetch, and at most its own queued kernels run
+    out on the device behind it (234 ms for twenty 5M-row segments —
+    PERF.md §5)."""
     from druid_tpu.obs.trace import span as trace_span
     if check is not None:
         check()
@@ -255,13 +266,12 @@ def _make_partials(segs, intervals, query, kds_per_seg, vals_per_seg,
             query.aggregations, query.filter, query.virtual_columns,
             context=query.context_map, check=check)
         if partials is None:
-            partials = []
-            for s, kds in zip(segs, kds_per_seg):
-                if check is not None and partials:
-                    check()
-                partials.append(run_grouped_aggregate(
-                    s, intervals, query.granularity, kds, query.aggregations,
-                    query.filter, virtual_columns=query.virtual_columns))
+            partials = run_grouped_aggregates(
+                [functools.partial(
+                    enqueue_grouped_aggregate, s, intervals,
+                    query.granularity, kds, query.aggregations, query.filter,
+                    virtual_columns=query.virtual_columns)
+                 for s, kds in zip(segs, kds_per_seg)], check)
         return partials, list(vals_per_seg)
 
 

@@ -55,7 +55,8 @@ from druid_tpu.data.segment import DEFAULT_ROW_ALIGN, Segment
 from druid_tpu.data.devicepool import entry_bytes
 from druid_tpu.engine import filters as filters_mod
 from druid_tpu.engine import megakernel, pallas_agg
-from druid_tpu.engine.contracts import named_program, program_name
+from druid_tpu.engine.contracts import (PENDING_FETCH_BYTES, named_program,
+                                        program_name)
 from druid_tpu.engine.filters import (ConstNode, FilterNode, plan_filter,
                                       simplify_node)
 from druid_tpu.obs import dispatch as dispatch_mod
@@ -1068,17 +1069,22 @@ def _host_post(kernel: AggKernel, state, segment: Segment):
 
 
 def fetch_partials(targets: Sequence[Tuple], outs: Sequence[Tuple],
-                   post=_host_post, **attrs) -> List[SegmentPartial]:
+                   post=_host_post, programs: int = 1,
+                   **attrs) -> List[SegmentPartial]:
     """Device results → host partials, under the ONE `engine/fetch`.
     `targets` are (segment, spec, kernels) and `outs` their (counts,
-    states); `post(kernel, state, segment)` is the one thing that differs
-    between the builders: a per-segment result takes the kernel's host_post
-    (the default), a mesh result that the collectives already merged its
-    host_from_device. This is where the host already blocks for the device
-    (`np.asarray` of an enqueued program's outputs), so the span adds no
-    sync: its duration is wait-for-device plus D2H plus the host
-    conversion. `bytes` is what comes back."""
-    with trace_span("engine/fetch", **attrs) as sp:
+    states) — of one program or of every program a request enqueued;
+    `post(kernel, state, segment)` is the one thing that differs between
+    the builders: a per-segment result takes the kernel's host_post (the
+    default), a mesh result that the collectives already merged its
+    host_from_device. This is where the host blocks for the device: ONE
+    `jax.device_get` brings the whole tree back (host arrays, as the
+    run-domain route leaves them, pass through), then `post` runs over
+    host arrays. So the span adds no sync: its duration is wait-for-device
+    plus D2H plus the host conversion. `bytes` is what comes back,
+    `programs` how many enqueued programs' outputs these are."""
+    import jax
+    with trace_span("engine/fetch", programs=programs, **attrs) as sp:
         if sp is not None:
             sp.attrs["bytes"] = entry_bytes(outs)
         return [SegmentPartial(
@@ -1088,7 +1094,73 @@ def fetch_partials(targets: Sequence[Tuple], outs: Sequence[Tuple],
                     for k, st in zip(kernels, states)},
             kernels=kernels)
             for (segment, spec, kernels), (counts, states)
-            in zip(targets, outs)]
+            in zip(targets, jax.device_get(outs))]
+
+
+def run_grouped_aggregates(work: Sequence, check=None
+                           ) -> List[SegmentPartial]:
+    """Run a request's device programs: ENQUEUE every one, then fetch ONCE.
+
+    `work` is a sequence of zero-argument enqueues, run in order. Each
+    returns what it left pending, a segment an entry: one entry
+    (`enqueue_grouped_aggregate`) or a list of them (a stacked chunk,
+    batching._enqueue_batch). An entry is a finished host partial
+    (`constFalse`) or `((segment, spec, kernels), (counts, states))`, what
+    `fetch_partials` takes. Returns the partials in the order of the
+    entries. `check` (cancel/timeout probe) runs between enqueues and once
+    before the fetch.
+
+    Nothing waits for the device between two enqueues: the device runs
+    program k while the host plans, stages and enqueues program k + 1, and
+    the queued programs run on while another request's thread holds the
+    interpreter lock. The outputs' copies to the host start at the enqueue
+    (`copy_to_host_async`, where an output has it) and the request's
+    results come back under ONE `engine/fetch` whose `programs` says how
+    many enqueued programs it collected. What is enqueued and not fetched
+    is bounded by contracts.PENDING_FETCH_BYTES of outputs: at the bound
+    the pending programs are fetched (one more `engine/fetch`) and the
+    enqueues go on. An enqueue that raises surfaces its error; the outputs
+    of the programs enqueued before it are dropped."""
+    import jax
+    results: List[Optional[SegmentPartial]] = []
+    pending: List[Tuple] = []   # (slot in results, target, out), un-fetched
+    programs = pending_bytes = 0
+
+    def fetch(programs):
+        if pending:
+            slots, targets, outs = zip(*pending)
+            for slot, partial in zip(slots, fetch_partials(
+                    targets, outs, programs=programs)):
+                results[slot] = partial
+            pending.clear()
+
+    for n, enqueue in enumerate(work):
+        if check is not None and n:
+            check()
+        entries = enqueue()
+        enqueued = []
+        for entry in entries if isinstance(entries, list) else [entries]:
+            if isinstance(entry, SegmentPartial):
+                results.append(entry)
+            else:
+                enqueued.append((len(results),) + entry)
+                results.append(None)
+        if enqueued:
+            pending += enqueued
+            programs += 1
+            for leaf in jax.tree_util.tree_leaves(
+                    [out for _, _, out in enqueued]):
+                pending_bytes += getattr(leaf, "nbytes", 0)
+                start_copy = getattr(leaf, "copy_to_host_async", None)
+                if start_copy is not None:
+                    start_copy()
+            if pending_bytes >= PENDING_FETCH_BYTES:
+                fetch(programs)
+                programs = pending_bytes = 0
+    if check is not None:
+        check()
+    fetch(programs)
+    return results
 
 
 def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
@@ -1098,24 +1170,47 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                           virtual_columns: Sequence = (),
                           plan: Optional[GroupPlan] = None) -> SegmentPartial:
     """Execute the grouped aggregation for one segment; returns host
-    partials. `plan` (a GroupPlan from plan_grouped_aggregate over the SAME
-    arguments) skips re-planning — the batched path's straggler fallback
-    passes the plan it already built for bucket grouping.
+    partials: `enqueue_grouped_aggregate` and the fetch of that one
+    program (`run_grouped_aggregates` over one enqueue). A request of many
+    segments hands all its enqueues to `run_grouped_aggregates` instead,
+    which fetches once."""
+    partial, = run_grouped_aggregates([functools.partial(
+        enqueue_grouped_aggregate, segment, intervals, granularity, dims,
+        aggs, flt, extra_columns=extra_columns,
+        virtual_columns=virtual_columns, plan=plan)])
+    return partial
+
+
+def enqueue_grouped_aggregate(segment: Segment,
+                              intervals: Sequence[Interval],
+                              granularity: Granularity,
+                              dims: Sequence[KeyDim],
+                              aggs: Sequence[AggregatorSpec],
+                              flt, extra_columns: Sequence[str] = (),
+                              virtual_columns: Sequence = (),
+                              plan: Optional[GroupPlan] = None):
+    """Plan, stage and ENQUEUE one segment's grouped aggregation; nothing
+    waits for the device. Returns an entry of `run_grouped_aggregates`: a
+    finished host partial when no program runs (`constFalse`), else
+    `((segment, spec, kernels), (counts, states))` with the outputs still
+    on their way. `plan` (a GroupPlan from plan_grouped_aggregate over the
+    SAME arguments) skips re-planning — the batched path's stragglers pass
+    the plan it already built for bucket grouping.
 
     The phases run one after another, each a function: `_plan_segment`,
-    `_stage_segment`, `_dispatch_segment` (the ENQUEUE: it returns what
-    `fetch_partials` takes) and the fetch. Traced, the segment's time lies
-    under one `engine/segment` span whose children are consecutive phases
-    (at most 8 spans a warm segment):
+    `_stage_segment`, `_dispatch_segment`. Traced, the segment's host time
+    lies under one `engine/segment` span whose children are consecutive
+    phases (at most 7 spans a warm segment):
     `engine/plan` (group spec, the code-domain probe, strategy, projection
     lookup — with an `engine/projection/build` child when the projection
     is built),
     `engine/filter/words` (megakernel conversion, then the staging of
     filter words; `built` = arrays built rather than found resident),
     `engine/stage` (columns and derived keys; `pool/h2d` nests here),
-    `engine/build` (aux, signature, program cache, the kernel build),
-    `engine/dispatch` (the ENQUEUE: dispatch is asynchronous) and
-    `engine/fetch` (see `fetch_partials`: the wait for the device)."""
+    `engine/build` (aux, signature, program cache, the kernel build) and
+    `engine/dispatch` (the ENQUEUE: dispatch is asynchronous). The wait
+    for the device is the request's `engine/fetch`, beside the segments
+    and not under one (see `fetch_partials`)."""
     with trace_span("engine/segment", rows=segment.n_rows) as seg_span:
         plan, route = _plan_segment(segment, intervals, granularity, dims,
                                     aggs, flt, extra_columns,
@@ -1123,7 +1218,7 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
         spec, kernels = plan.spec, plan.kernels
         if route == "constFalse":
             # nothing matches — skip the device
-            partial = SegmentPartial(
+            entry = SegmentPartial(
                 segment=segment, spec=spec,
                 counts=np.zeros(spec.num_total, dtype=np.int64),
                 states={k.name: k.empty_state(spec.num_total)
@@ -1138,11 +1233,11 @@ def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],
                 staged = _stage_segment(segment, plan)
                 out = _dispatch_segment(segment, intervals, plan, *staged)
                 route = spec.strategy
-            partial, = fetch_partials([(segment, spec, kernels)], [out])
+            entry = (segment, spec, kernels), out
         if seg_span is not None:
             # formatted only when traced: untraced segments pay no str()
             seg_span.attrs.update(segment=str(segment.id), strategy=route)
-        return partial
+        return entry
 
 
 def _plan_segment(segment, intervals, granularity, dims, aggs, flt,
@@ -1298,8 +1393,8 @@ def _dispatch_segment(segment: Segment, intervals: Sequence[Interval],
                       plan: GroupPlan, arrays: Dict, packs: Tuple,
                       cascades: Tuple) -> Tuple:
     """`engine/build` and `engine/dispatch`: the program found or built and
-    ENQUEUED — returns its device (counts, states), which `fetch_partials`
-    waits for."""
+    ENQUEUED — returns its device (counts, states), which the request's
+    `fetch_partials` waits for."""
     spec, filter_node, kernels = plan.spec, plan.filter_node, plan.kernels
     vc_plans, vc_luts = plan.vc_plans, plan.vc_luts
     col_dtypes = plan.col_dtypes
@@ -1404,8 +1499,8 @@ def _dispatch_segment(segment: Segment, intervals: Sequence[Interval],
                         else:
                             _build_kernel_program(fn, arrays, aux)
             # the ENQUEUE: dispatch is asynchronous, so this span closes
-            # when the program is queued; engine/fetch (below) times its
-            # finish, where the host blocks for the results anyway
+            # when the program is queued; the request's engine/fetch times
+            # its finish, where the host blocks for the results anyway
             with trace_span("engine/dispatch", strategy=spec.strategy,
                             rows=segment.n_rows, compile=compiled,
                             program=program), \
@@ -1463,7 +1558,7 @@ def _dispatch_segment(segment: Segment, intervals: Sequence[Interval],
 def _build_kernel_program(fn, *args) -> None:
     """Build a pallas-class jitted program for `args` WITHOUT running it:
     trace, Pallas lowering and the Mosaic compile happen here, so the
-    latch in run_grouped_aggregate catches exactly the failures of the
+    latch in _dispatch_segment catches exactly the failures of the
     BUILD (pallas_agg.KernelBuildError) and never one of the run. The jit
     call that follows reuses the executable this leaves in jit's own
     cache (nothing compiles twice); on a warm cache the call costs a

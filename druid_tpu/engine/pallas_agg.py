@@ -71,7 +71,7 @@ class KernelBuildError(Exception):
     """A pallas-class program failed to BUILD — trace, Pallas lowering or
     the Mosaic compile (grouping._build_kernel_program raises it with the
     original exception as __cause__). The one failure the strategy latch
-    in grouping.run_grouped_aggregate catches; nothing raised while a
+    in grouping._dispatch_segment catches; nothing raised while a
     built program RUNS is ever this."""
 
 

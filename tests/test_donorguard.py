@@ -690,6 +690,21 @@ def test_witness_install_is_reversible():
     assert after == before
 
 
+def test_witnessed_program_lowers_as_its_product_does(monkeypatch):
+    # grouping._build_kernel_program lowers a pallas-class program before
+    # it runs: a witnessed builder's product must still be lowerable, or
+    # every witnessed build would latch pallas off
+    import jax
+    from druid_tpu.engine import grouping
+    jitted = jax.jit(lambda arrays, aux, carries=(): (arrays + aux, carries))
+    monkeypatch.setattr(grouping, "_build_device_fn", lambda: jitted)
+    with DonorWitness("r"):
+        fn = grouping._build_device_fn()
+        assert fn is not jitted
+        grouping._build_kernel_program(fn, 1.0, 2.0)
+        assert fn(1.0, 2.0)[0] == 3.0
+
+
 def test_witness_end_to_end_on_singleton_pool(monkeypatch):
     # a fresh pool bound as the process singleton: real take/get_or_build
     # traffic is witnessed; other pool instances stay invisible

@@ -98,9 +98,10 @@ def segment():
 
 def _staged(segment, shape, monkeypatch):
     """Run the shape's query through the served engine once, keeping what
-    it handed `run_grouped_aggregate`; then plan and stage the same call
-    again, phase by phase: (call arguments, the served partial's counts,
-    plan, (arrays, packs, cascades))."""
+    it handed `enqueue_grouped_aggregate` (the enqueue of
+    `run_grouped_aggregates`, one call a segment); then plan and stage the
+    same call again, phase by phase: (call arguments, the counts the served
+    program left for the fetch, plan, (arrays, packs, cascades))."""
     query, arm, modes = SHAPES[shape]
     if arm is not None:
         arm(monkeypatch)
@@ -108,17 +109,17 @@ def _staged(segment, shape, monkeypatch):
     # route out of the way (its own parity is tests/test_cascade.py's)
     prev = cascade.set_run_domain_enabled(False)
     calls = []
-    real = grouping.run_grouped_aggregate
+    real = grouping.enqueue_grouped_aggregate
 
     def spy(seg, intervals, granularity, dims, aggs, flt, **kw):
-        partial = real(seg, intervals, granularity, dims, aggs, flt, **kw)
+        entry = real(seg, intervals, granularity, dims, aggs, flt, **kw)
         calls.append(((seg, intervals, granularity, dims, aggs, flt,
-                       kw.get("virtual_columns", ())), partial))
-        return partial
-    monkeypatch.setattr(engines, "run_grouped_aggregate", spy)
+                       kw.get("virtual_columns", ())), entry))
+        return entry
+    monkeypatch.setattr(engines, "enqueue_grouped_aggregate", spy)
     try:
         assert QueryExecutor([segment]).run_json(query)
-        (args, partial), = calls
+        (args, (_target, (counts, _states))), = calls
         seg, intervals, granularity, dims, aggs, flt, vcs = args
         plan, route = grouping._plan_segment(
             seg, intervals, granularity, dims, aggs, flt, (), vcs, None)
@@ -127,7 +128,7 @@ def _staged(segment, shape, monkeypatch):
     finally:
         cascade.set_run_domain_enabled(prev)
     assert (plan.spec.key_mode, plan.spec.bucket_mode) == modes
-    return args, partial, plan, staged
+    return args, np.asarray(counts, dtype=np.int64), plan, staged
 
 
 def _leaves(tree):
@@ -139,7 +140,7 @@ def _leaves(tree):
 def test_per_segment_program_equals_stacked_body_at_k1(segment, shape,
                                                        monkeypatch):
     import jax
-    (seg, intervals, _g, _d, _a, _f, _v), partial, plan, staged = _staged(
+    (seg, intervals, _g, _d, _a, _f, _v), served, plan, staged = _staged(
         segment, shape, monkeypatch)
     arrays, _packs, _cascades = staged
     spec, kernels = plan.spec, plan.kernels
@@ -169,14 +170,14 @@ def test_per_segment_program_equals_stacked_body_at_k1(segment, shape,
     for a, b in zip(_leaves(states), _leaves(k1_states)):
         assert a.dtype == b.dtype and np.array_equal(a, b, equal_nan=True)
     # and both are the program the served path ran
-    assert np.array_equal(np.asarray(counts, dtype=np.int64), partial.counts)
-    assert int(partial.counts.sum()) > 0
+    assert np.array_equal(np.asarray(counts, dtype=np.int64), served)
+    assert int(served.sum()) > 0
 
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))
 def test_plan_columns_and_dtypes_are_what_stages(segment, shape,
                                                  monkeypatch):
-    (seg, *_), _partial, plan, (arrays, _packs, _cascades) = _staged(
+    (seg, *_), _served, plan, (arrays, _packs, _cascades) = _staged(
         segment, shape, monkeypatch)
     block = seg.device_block(list(plan.columns), perm=plan.perm,
                              perm_key=plan.perm_key)
@@ -289,9 +290,10 @@ def test_stacked_builders_plan_only_through_the_one_planner(module):
 
 def test_the_planner_and_the_fetch_span_have_one_home():
     """In `engine/grouping.py` each planning call sits in
-    `plan_grouped_aggregate` alone, and of the three builder modules only
-    `fetch_partials` opens `engine/fetch`."""
-    fetch_sites, planner_sites = [], []
+    `plan_grouped_aggregate` alone; of the three builder modules only
+    `fetch_partials` opens `engine/fetch`, and only the request's helper,
+    the cross-query chunk and the sharded program call it."""
+    fetch_sites, fetch_callers, planner_sites = [], [], []
     for module in BUILDERS:
         for fn in _tree(module).body:
             if not isinstance(fn, ast.FunctionDef):
@@ -301,11 +303,17 @@ def test_the_planner_and_the_fetch_span_have_one_home():
                         and isinstance(node.args[0], ast.Constant) \
                         and node.args[0].value == "engine/fetch":
                     fetch_sites.append((module.__name__, fn.name))
+                if name == "fetch_partials":
+                    fetch_callers.append((module.__name__, fn.name))
                 if module is grouping and (
                         name in PLANNER_CALLS or name in ("needed_columns",
                                                           "make_group_spec")):
                     planner_sites.append((name, fn.name))
     assert fetch_sites == [("druid_tpu.engine.grouping", "fetch_partials")]
+    assert fetch_callers == [
+        ("druid_tpu.engine.grouping", "run_grouped_aggregates"),
+        ("druid_tpu.engine.batching", "_run_batch"),
+        ("druid_tpu.parallel.distributed", "_run_sharded")]
     assert {fn for _, fn in planner_sites} == {"plan_grouped_aggregate"}
     assert {name for name, _ in planner_sites} == PLANNER_CALLS | {
         "needed_columns", "make_group_spec"}

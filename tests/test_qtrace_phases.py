@@ -32,9 +32,11 @@ SCHEMA = (ColumnSpec("dimA", "string", cardinality=10),
           ColumnSpec("dimB", "string", cardinality=7),
           ColumnSpec("metLong", "long", low=0, high=100))
 
-#: the consecutive phases of one `engine/segment` (grouping.py)
+#: the consecutive phases of one `engine/segment` (grouping.py): the
+#: enqueue side of a segment. The wait for the device is the request's ONE
+#: `engine/fetch`, beside the segments under `engine/partials`
 SEGMENT_PHASES = {"engine/plan", "engine/filter/words", "engine/stage",
-                  "engine/build", "engine/dispatch", "engine/fetch"}
+                  "engine/build", "engine/dispatch"}
 
 
 def _segments(n=3, rows=2000, seed=7, days=DAYS):
@@ -175,27 +177,35 @@ def test_one_trace_holds_every_phase(cluster):
         assert seg["attrs"]["rows"] == 2000
         children = kids[seg["spanId"]]
         # one span a phase, filter words twice (the megakernel conversion
-        # before staging, the words after): 8 spans a segment with its own
+        # before staging, the words after): 7 spans a segment with its own,
+        # every one of them the host's — no segment waits for the device
         assert sorted(c["name"] for c in children) == sorted(
             list(SEGMENT_PHASES) + ["engine/filter/words"])
         (plan_span,) = [c for c in children if c["name"] == "engine/plan"]
         assert plan_span["attrs"]["runDomainMs"] >= 0
         (disp,) = [c for c in children if c["name"] == "engine/dispatch"]
         (build,) = [c for c in children if c["name"] == "engine/build"]
-        (fetch,) = [c for c in children if c["name"] == "engine/fetch"]
         assert seg["attrs"]["strategy"] == disp["attrs"]["strategy"]
         assert disp["attrs"]["program"] == build["attrs"]["program"] \
             == "seg_agg_" + disp["attrs"]["strategy"]
         assert disp["attrs"]["program"] in contracts.PROGRAM_NAMES
-        assert fetch["attrs"]["bytes"] > 0
         assert all(c["attrs"]["built"] == 0 for c in children
                    if c["name"] == "engine/filter/words")
         # consecutive phases: the children's sum stays under the parent
         assert sum(c["durationMs"] for c in children) \
             <= seg["durationMs"] + 0.5
-    assert sum(s["durationMs"] for s in segs) <= partials["durationMs"] + 0.5
-    assert len(_named(spans, "engine/fetch")) == dispatched \
+    # the request enqueued its three programs, then fetched ONCE: the fetch
+    # lies beside the segments, after the last of them, and says how many
+    # programs it collected
+    (fetch,) = _named(spans, "engine/fetch")
+    assert fetch["parentId"] == partials["spanId"]
+    assert fetch["attrs"]["programs"] == dispatched \
         == len(_named(spans, "engine/dispatch"))
+    assert fetch["attrs"]["bytes"] > 0
+    assert fetch["startMs"] >= max(
+        s["startMs"] + s["durationMs"] for s in segs) - 0.5
+    assert sum(s["durationMs"] for s in segs) + fetch["durationMs"] \
+        <= partials["durationMs"] + 0.5
 
     # (e) the answer's way out, under the root whose extent it lies beyond
     (respond,) = _named(spans, "http/respond")
@@ -620,7 +630,7 @@ def test_profiler_host_plane_holds_the_spans(tmp_path):
                 for e in line.events:
                     events[e.name] = events.get(e.name, 0) + 1
     assert events.get("engine/dispatch") == 2
-    assert events.get("engine/fetch") == 2
+    assert events.get("engine/fetch") == 1
     assert events.get("engine/segment") == 2
     assert events.get("query") == 1
 

@@ -101,7 +101,7 @@ def _count_per_segment(*a, **k):
 
 
 batching.run_with_batching = _count_batch
-engines.run_grouped_aggregate = _count_per_segment
+engines.enqueue_grouped_aggregate = _count_per_segment
 
 mesh = make_mesh(8)
 before = distributed.sharded_stats().snapshot()

@@ -121,6 +121,13 @@ def test_sharded_path_is_under_spans_and_the_second_request_hits(served):
                         "selected": plan["strategy"],
                         "strategy": spans["dispatch"][0]["strategy"]}
         assert spans["put"][0]["bytes"] > 0
+    # one program, one fetch: what a meshless request's fetch collects from
+    # many enqueued programs (`programs`) is 1 here
+    for qid in ("mesh4-s1", "mesh4-s2"):
+        fetch, = [s["attrs"] for s in trace.trace_store().spans(qid)
+                  if s["name"] == "engine/fetch"]
+        assert fetch["programs"] == 1 and fetch["segments"] == 8
+        assert fetch["bytes"] > 0
     rows = -(-ROWS // 1024) * 1024
     stack, = first["stack"]
     assert stack.pop("builtBytes") > SEGMENTS * rows       # > 1 B a row

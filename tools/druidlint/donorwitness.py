@@ -266,6 +266,11 @@ class DonorWitness:
                     witness._after_dispatch(carries)
                 return out
 
+            # a pallas-class program is BUILT before it runs
+            # (grouping._build_kernel_program lowers it): the wrapper lowers
+            # as its product does, or every witnessed build would latch
+            # pallas off
+            dispatched.lower = fn.lower
             return dispatched
 
         self._saved.append((grouping, "_build_device_fn", real_builder))
