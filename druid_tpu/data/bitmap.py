@@ -285,20 +285,69 @@ def to_words32(bm: AnyBitmap, padded_rows: int) -> np.ndarray:
     return np.packbits(mask, bitorder="little").view(np.uint32)
 
 
-def device_repr(bm: AnyBitmap, padded_rows: int):
-    """("sparse", int32 ids padded to a pow2 rung with `padded_rows` as the
-    out-of-range sentinel) when the id list is the smaller transfer, else
-    ("dense", uint32 words). The rung quantization bounds distinct device
-    shapes (compile keys) exactly like the batching row ladder."""
-    m = bm.cardinality()
-    rung = 8
-    while rung < m:
-        rung <<= 1
-    if rung * 4 < padded_rows // 8:
-        ids = np.full(rung, padded_rows, dtype=np.int32)
-        ids[:m] = np.sort(bm.to_indices())[:m]
+#: a sparse leaf ships `padded_rows // SPARSE_LEAF_SHARE` row ids — an eighth
+#: of the dense words' bytes — and a run-table leaf as many (end, match)
+#: pairs at most. ONE width each, not a ladder of them: every width is a
+#: fill program of its own (engine/filters.py keys on the blocks a wave
+#: ships), and a request that meets a new one compiles it while it waits.
+SPARSE_LEAF_SHARE = 256
+SPARSE_LEAF_FLOOR = 8
+
+
+def sparse_leaf_width(padded_rows: int) -> int:
+    """THE id-list width of a sparse leaf at this row count."""
+    return max(SPARSE_LEAF_FLOOR, padded_rows // SPARSE_LEAF_SHARE)
+
+
+def leaf_rows(bms: Sequence[AnyBitmap], padded_rows: int):
+    """The bitmaps of ONE leaf position of a fill wave, density-adaptively,
+    a row a bitmap: ("sparse", int32 [n, sparse_leaf_width] id lists padded
+    with `padded_rows`, the out-of-range sentinel) when EVERY bitmap fits
+    that width, else ("dense", uint32 [n, padded_rows / 32] words). One
+    width and one kind a position bound the distinct device shapes (compile
+    keys): a leaf's cardinality picks between two shapes, never a shape of
+    its own, and ids never ship beside words.
+
+    The dense `Bitmap`s (what a persisted segment's index returns) convert
+    TOGETHER, one numpy call a step for all of one row count: each call on
+    an array of this size drops the interpreter lock, and under eight
+    request threads every drop is a hand-over (PERF.md, PR 35)."""
+    width = sparse_leaf_width(padded_rows)
+    cards = np.zeros(len(bms), dtype=np.int64)
+    by_rows: Dict[int, List[int]] = {}
+    for i, bm in enumerate(bms):
+        if isinstance(bm, Bitmap):
+            by_rows.setdefault(bm.n_rows, []).append(i)
+        else:
+            cards[i] = bm.ids.shape[0]
+    stacks = []
+    for n_rows, at in by_rows.items():
+        words = np.stack([bms[i].words for i in at])
+        # bits past n_rows are zero in a Bitmap; a stray one could only
+        # overcount, which ships words where ids would have fitted
+        cards[at] = np.bitwise_count(words).sum(axis=1)
+        stacks.append((np.asarray(at), n_rows, words))
+    if (cards <= width).all():
+        ids = np.full((len(bms), width), padded_rows, dtype=np.int32)
+        for i, bm in enumerate(bms):
+            if not isinstance(bm, Bitmap):
+                ids[i, :cards[i]] = bm.ids
+        for at, n_rows, words in stacks:
+            # flat, then split: a 2-D nonzero is eight times the cost
+            row, col = np.divmod(np.flatnonzero(np.unpackbits(
+                words, axis=1, count=n_rows).view(bool)), n_rows)
+            first = np.searchsorted(row, np.arange(at.shape[0]))
+            ids[at[row], np.arange(row.shape[0]) - first[row]] = col
         return "sparse", ids
-    return "dense", to_words32(bm, padded_rows)
+    assert padded_rows % _word_bits() == 0
+    bits = np.zeros((len(bms), padded_rows), dtype=np.uint8)
+    for i, bm in enumerate(bms):
+        if not isinstance(bm, Bitmap):
+            bits[i, bm.ids] = 1
+    for at, n_rows, words in stacks:
+        bits[at, :n_rows] = np.unpackbits(words, axis=1, count=n_rows)
+    return "dense", np.packbits(bits, axis=1, bitorder="little") \
+        .view(np.uint32)
 
 
 class BitmapIndex:

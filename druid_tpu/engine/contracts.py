@@ -242,8 +242,7 @@ PROGRAM_NAMES = frozenset(
     [f"{family}_{strategy}" for family in AGG_PROGRAM_FAMILIES
      for strategy in STRATEGIES]
     + ["run_domain_agg",        # data/cascade.py code-domain program
-       "bitmap_fill",           # engine/filters.py one filter's fill
-       "bitmap_fill_wave"])     # engine/filters.py a staging wave's fill
+       "bitmap_fill_wave"])     # engine/filters.py a wave's fill (of 1 too)
 
 #: `pl.pallas_call` names (engine/pallas_agg.py grouped_reduce): the sorted
 #: projection's group reduce, and its megakernel variant that takes the

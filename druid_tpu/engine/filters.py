@@ -33,13 +33,15 @@ from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from druid_tpu.data.bitmap import (AnyBitmap, Bitmap, SparseBitmap,
-                                   bitmap_and, bitmap_or, device_repr)
+from druid_tpu.data.bitmap import (SPARSE_LEAF_SHARE, AnyBitmap, Bitmap,
+                                   SparseBitmap, bitmap_and, bitmap_or,
+                                   leaf_rows, sparse_leaf_width)
 from druid_tpu.data.dictionary import Dictionary, merge_dictionaries
 from druid_tpu.data.devicepool import thread_builds
 from druid_tpu.data.segment import Segment, ValueType
 from druid_tpu.engine.contracts import named_program
 from druid_tpu.obs.trace import span as trace_span
+from druid_tpu.obs.trace import span_when as trace_span_when
 from druid_tpu.query import filters as F
 from druid_tpu.utils.emitter import Monitor
 from druid_tpu.utils.expression import parse_expression
@@ -899,14 +901,24 @@ class FilterBitmapMonitor(Monitor):
                        s["builtBytes"] - last["builtBytes"])
 
 
-# Jitted bitmap-algebra fill programs, keyed on (structure, leaf reprs, Rw):
-# LRU-bounded + locked like grouping._JIT_CACHE (broker thread-pool fan-out).
-# Leaf reprs/rungs are pow2-quantized (device_repr), so the key space stays
-# coarse the way pack descriptors do.
+# Jitted fill programs, keyed on a wave's LAYOUT (per filter structure: its
+# slot rung and which leaf blocks ship — `_pack_wave`) and the row count:
+# LRU-bounded + locked like grouping._JIT_CACHE (broker thread-pool
+# fan-out). The key is a closed set: no leaf's size and no count of cold
+# segments is in it, so a dashboard's sliding window meets the programs its
+# first cold waves built.
 _FBMP_JIT_CACHE: "collections.OrderedDict[Tuple, object]" = \
     collections.OrderedDict()
 _FBMP_JIT_CACHE_CAP = 64
 _FBMP_JIT_CACHE_LOCK = threading.Lock()
+
+#: what `words_span` reports of the waves staged inside it, per thread:
+#: cold pairs, `jax.device_put` calls, bytes of the packed buffers
+_WAVE_TALLY = threading.local()
+
+
+def _wave_tally() -> Tuple[int, int, int]:
+    return getattr(_WAVE_TALLY, "n", (0, 0, 0))
 
 
 def combine_structure_words(structure, leaf_words, const_words):
@@ -933,66 +945,91 @@ def combine_structure_words(structure, leaf_words, const_words):
     return ev(structure)
 
 
-def _eval_structure(structure, kinds: Tuple, leaves: Tuple, Rw: int):
-    """Traced word-wise bitmap algebra: leaves arrive as device arrays
-    (dense uint32 words, sparse int32 id lists scattered into words
-    in-program — distinct ids set distinct bits, so scatter-add IS
-    bitwise-or; padding ids equal padded_rows and drop out of bounds — or
-    RLE run tables whose per-RUN match bit was decided host-side once per
-    run and expands to rows by a searchsorted over run ends), and
-    AND/OR/NOT/XOR combine word-wise on the VPU. Output: uint32 [Rw]."""
+def run_leaf_width(padded_rows: int) -> int:
+    """THE run-table length of a `runs` leaf at this row count (the run
+    limit `_run_leaf_payload` accepts, plus its sentinel run, on a pow2)."""
+    from druid_tpu.data.cascade import pad_pow2
+    return pad_pow2(padded_rows // SPARSE_LEAF_SHARE + 1)
+
+
+def _block_width(kind: str, padded_rows: int) -> int:
+    """int32 elements one leaf takes in a block of `kind`."""
+    if kind == "sparse":
+        return sparse_leaf_width(padded_rows)
+    if kind == "dense":
+        return padded_rows // 32
+    return 2 * run_leaf_width(padded_rows)
+
+
+def _block_words(kind: str, block, padded_rows: int):
+    """Traced: one block of a wave's buffer (int32 [K, width], a leaf a
+    row) as uint32 words [K, Rw]. Sparse rows are sorted id lists padded
+    with `padded_rows`, scattered into words in ONE scatter for the block —
+    distinct ids set distinct bits, so scatter-add IS bitwise-or, and the
+    padding drops out of bounds; dense rows are the words themselves; run
+    rows are (EXCLUSIVE run end, per-run match) tables with a 2^31-1-end,
+    match-0 sentinel run, expanded to rows by a searchsorted over the ends
+    (data/cascade.py run tables: 8 bytes a run instead of a bit a row)."""
+    import jax
     import jax.numpy as jnp
+    K, Rw = block.shape[0], padded_rows // 32
+    if kind == "dense":
+        return jax.lax.bitcast_convert_type(block, jnp.uint32)
+    if kind == "sparse":
+        bit = jnp.uint32(1) << (block & 31).astype(jnp.uint32)
+        rows = jnp.arange(K, dtype=jnp.int32)[:, None]
+        return jnp.zeros((K, Rw), jnp.uint32).at[rows, block >> 5].add(
+            bit, mode="drop")
+    iota = jnp.arange(padded_rows, dtype=jnp.int32)
 
-    def leaf_words(i):
-        if kinds[i][0] == "dense":
-            return leaves[i]
-        if kinds[i][0] == "runs":
-            # RLE-run-aware leaf (data/cascade.py run tables): column 0 =
-            # EXCLUSIVE run ends (+ a 2^31-1 sentinel run covering
-            # padding, match 0), column 1 = the per-run match decided ONCE
-            # per run. Ships 8 bytes/run instead of 1 bit/row.
-            ends = leaves[i][:, 0]
-            match = leaves[i][:, 1]
-            iota = jnp.arange(Rw * 32, dtype=jnp.int32)
-            idx = jnp.clip(jnp.searchsorted(ends, iota, side="right"),
-                           0, ends.shape[0] - 1)
-            bits = (match[idx] > 0).astype(jnp.uint32).reshape(-1, 32)
-            w = bits[:, 0]
-            for s in range(1, 32):
-                w = w | (bits[:, s] << jnp.uint32(s))
-            return w
-        ids = leaves[i]
-        bit = jnp.uint32(1) << (ids & 31).astype(jnp.uint32)
-        return jnp.zeros((Rw,), jnp.uint32).at[ids >> 5].add(bit, mode="drop")
+    def expand(table):
+        ends, match = table[:, 0], table[:, 1]
+        idx = jnp.clip(jnp.searchsorted(ends, iota, side="right"),
+                       0, ends.shape[0] - 1)
+        bits = (match[idx] > 0).astype(jnp.uint32).reshape(-1, 32)
+        w = bits[:, 0]
+        for s in range(1, 32):
+            w = w | (bits[:, s] << jnp.uint32(s))
+        return w
 
-    def const_words(value):
-        fill = np.uint32(0xFFFFFFFF) if value else np.uint32(0)
-        return jnp.full((Rw,), fill, jnp.uint32)
-
-    return combine_structure_words(structure, leaf_words, const_words)
+    # a slot at a time: batched, the search's temporaries are K times a
+    # slot's (3 GB at 64 slots of 262,144 rows, compiled for a v5e)
+    return jax.lax.map(expand, block.reshape(K, -1, 2))
 
 
-def _build_fill_fn(structure, kinds: Tuple, Rw: int):
-    """One filter's fill program (unit-testable single case)."""
+def _build_fill_wave(layout: Tuple, padded_rows: int):
+    """THE fill program: a wave's packed buffer (`_pack_wave`) in, its
+    words out — `K` uint32 [Rw] arrays a `(structure, K, blocks)` entry of
+    `layout`, in order, padding slots included (the caller drops them).
+    A leaf position's words are the OR of the blocks it ships; AND / OR /
+    NOT / XOR then combine word-wise over the [K, Rw] planes. Nothing in it
+    is unrolled over K: it costs the same to trace at 64 slots as at 1."""
     import jax
+    import jax.numpy as jnp
+    Rw = padded_rows // 32
 
-    def fn(leaves):
-        return _eval_structure(structure, kinds, leaves, Rw)
+    def fn(buf):
+        out, off = [], 0
+        for structure, K, blocks in layout:
+            leaves = []
+            for kinds in blocks:
+                words = None
+                for kind in kinds:
+                    n = K * _block_width(kind, padded_rows)
+                    w = _block_words(kind, buf[off:off + n].reshape(K, -1),
+                                     padded_rows)
+                    words = w if words is None else words | w
+                    off += n
+                leaves.append(words)
 
-    return jax.jit(named_program(fn, "bitmap_fill"))
+            def const_words(value, K=K):
+                fill = np.uint32(0xFFFFFFFF) if value else np.uint32(0)
+                return jnp.full((K, Rw), fill, jnp.uint32)
 
-
-def _build_fill_multi(structures: Tuple, kinds_per: Tuple, Rw: int):
-    """The BATCHED fill program: every cold (segment, filter) pair of a
-    staging wave computes its words inside ONE dispatch — the same
-    unroll-don't-loop discipline as engine/batching.py (a per-miss fill
-    dispatch would hand the host-mask path back its dispatch advantage on
-    cold dashboards)."""
-    import jax
-
-    def fn(leaves_per: Tuple):
-        return tuple(_eval_structure(s, k, l, Rw)
-                     for s, k, l in zip(structures, kinds_per, leaves_per))
+            combined = combine_structure_words(
+                structure, leaves.__getitem__, const_words)
+            out.extend(combined[i] for i in range(K))
+        return tuple(out)
 
     return jax.jit(named_program(fn, "bitmap_fill_wave"))
 
@@ -1015,61 +1052,141 @@ def _permuted_bitmap(segment: Segment, bm: AnyBitmap,
 
 def _run_leaf_payload(segment: Segment, dim: str, lut: np.ndarray,
                       padded_rows: int) -> Optional[np.ndarray]:
-    """RLE-run-aware leaf payload: int32 [Rpad, 2] of (EXCLUSIVE run end —
-    start-of-next-run index — and per-run match) when `dim` is run-compressible enough that the run
-    table undercuts both bitmap representations (data/cascade.py run
-    info), else None. The match bit is decided ONCE PER RUN (one LUT
-    gather over run values) instead of once per row; a 2^31-1-end
-    sentinel run covers padding rows with match 0."""
+    """RLE-run-aware leaf payload: int32 [run_leaf_width, 2] of (EXCLUSIVE
+    run end — start-of-next-run index — and per-run match) when `dim` is
+    run-compressible enough that the run table undercuts both bitmap
+    representations (data/cascade.py run info), else None. The match bit
+    is decided ONCE PER RUN (one LUT gather over run values) instead of
+    once per row; 2^31-1-end sentinel runs cover padding rows with match
+    0."""
     from druid_tpu.data import cascade as cascade_mod
     if not cascade_mod.enabled():
         return None
     # beat the dense words (padded_rows/32 uint32) with clear margin
-    info = cascade_mod.column_run_info(segment, dim,
-                                       max_runs=padded_rows // 256)
+    info = cascade_mod.column_run_info(
+        segment, dim, max_runs=padded_rows // SPARSE_LEAF_SHARE)
     if info is None:
         return None
     values, ends, nr = info
-    rpad = cascade_mod.pad_pow2(nr + 1)
-    payload = np.zeros((rpad, 2), dtype=np.int32)
+    payload = np.zeros((run_leaf_width(padded_rows), 2), dtype=np.int32)
     payload[:, 0] = 2**31 - 1            # sentinel tail (match 0)
     payload[:nr, 0] = ends
     payload[:nr, 1] = lut[values]
     return payload
 
 
-def _leaf_arrays(segment: Segment, node: DeviceBitmapNode,
-                 padded_rows: int, perm: Optional[np.ndarray] = None,
-                 perm_key=None) -> Tuple[Tuple, Tuple]:
-    """(kinds, device leaf payloads) for one node: leaf bitmaps come from
-    the host index and ship density-adaptively — RLE run tables when the
-    dim is run-compressed (match decided once per run, data/cascade.py),
-    else sparse ids or dense words — pool-resident per leaf.
-    `perm` reorders rows into a projection layout before packing; the
-    permutation digest keys those entries separately."""
+def _leaf_bitmap(segment: Segment, dim: str, lut: np.ndarray,
+                 perm: Optional[np.ndarray], perm_key) -> AnyBitmap:
+    """One leaf's row bitmap from the host index; `perm` reorders rows
+    into a projection layout."""
+    bm = segment.dims[dim].bitmap_index().union_of(np.flatnonzero(lut))
+    if perm is not None:
+        bm = _permuted_bitmap(segment, bm, perm, perm_key)
+    return bm
+
+
+def _block(K: int, kind: str, padded_rows: int, slots: List[int],
+           rows: np.ndarray) -> np.ndarray:
+    """One [K, width] block of a wave's buffer, flat: `rows` at `slots`,
+    every other slot what fills to zero words."""
+    if len(slots) == K:                  # every slot cold: no fill, no copy
+        return rows.view(np.int32).reshape(-1)
+    block = np.zeros((K, _block_width(kind, padded_rows)), dtype=np.int32)
+    if kind == "sparse":
+        block[:] = padded_rows
+    elif kind == "runs":
+        block[:, 0::2] = 2**31 - 1
+    block[slots] = rows.view(np.int32).reshape(len(slots), -1)
+    return block.reshape(-1)
+
+
+def _pack_wave(pairs: Sequence[Tuple[Segment, "DeviceBitmapNode"]],
+               padded_rows: int, census: Optional[Dict] = None,
+               perm: Optional[np.ndarray] = None, perm_key=None
+               ) -> Tuple[Tuple, np.ndarray, List[int]]:
+    """A wave's cold (segment, node) pairs as ONE host buffer: (layout,
+    int32 buffer, output index of every pair). Pairs group by structure;
+    a group takes `K` slots — `census[structure]`, how many nodes of that
+    structure the WAVE holds, resident or not, on a pow2 — so how many of
+    a chunk's segments happen to be cold picks no program. A leaf position
+    ships one [K, width] block a kind its leaves take: run tables where the
+    dim is run-compressed (original row order only), and for the rest
+    sparse ids when every bitmap fits the one width, else words
+    (`bitmap.leaf_rows`, which converts the position's bitmaps together)."""
+    groups: Dict[Tuple, List[int]] = {}
+    for p, (_, node) in enumerate(pairs):
+        groups.setdefault(node.structure, []).append(p)
+    layout, parts, index = [], [], [0] * len(pairs)
+    slot0 = 0
+    for structure in sorted(groups, key=repr):
+        members = groups[structure]
+        K = 1
+        while K < max(len(members), (census or {}).get(structure, 0)):
+            K <<= 1
+        blocks = []
+        for j in range(len(pairs[members[0]][1].leaves)):
+            tables: Dict[int, np.ndarray] = {}      # by slot
+            bitmaps: Dict[int, AnyBitmap] = {}
+            for slot, p in enumerate(members):
+                segment, node = pairs[p]
+                dim, lut = node.leaves[j]
+                table = None if perm is not None else \
+                    _run_leaf_payload(segment, dim, lut, padded_rows)
+                if table is not None:
+                    tables[slot] = table
+                else:
+                    bitmaps[slot] = _leaf_bitmap(segment, dim, lut, perm,
+                                                 perm_key)
+            kinds = []
+            if bitmaps:
+                kind, rows = leaf_rows(list(bitmaps.values()), padded_rows)
+                kinds.append(kind)
+                parts.append(_block(K, kind, padded_rows, list(bitmaps),
+                                    rows))
+            if tables:
+                kinds.append("runs")
+                parts.append(_block(K, "runs", padded_rows, list(tables),
+                                    np.stack(list(tables.values()))))
+            blocks.append(tuple(kinds))
+        for slot, p in enumerate(members):
+            index[p] = slot0 + slot
+        slot0 += K
+        layout.append((structure, K, tuple(blocks)))
+    buf = parts[0] if len(parts) == 1 else \
+        np.concatenate(parts) if parts else np.zeros(0, dtype=np.int32)
+    return tuple(layout), buf, index
+
+
+def _fill_wave(pairs: Sequence[Tuple[Segment, "DeviceBitmapNode"]],
+               padded_rows: int, census: Optional[Dict] = None,
+               perm: Optional[np.ndarray] = None, perm_key=None) -> List:
+    """The words of a wave's cold pairs, in order: ONE packed buffer, ONE
+    `jax.device_put`, ONE dispatch, whatever the pairs' count and kinds."""
     import jax
 
-    pdg = perm_digest(perm_key)
-    kinds: List[Tuple] = []
-    arrays = []
-    for dim, lut in node.leaves:
-        payload = None
-        if perm is None:
-            payload = _run_leaf_payload(segment, dim, lut, padded_rows)
-        if payload is not None:
-            kind = "runs"
+    from druid_tpu.obs import dispatch as dispatch_mod
+    layout, buf, index = _pack_wave(pairs, padded_rows, census, perm,
+                                    perm_key)
+    jkey = (layout, padded_rows)
+    with _FBMP_JIT_CACHE_LOCK:
+        fn = _FBMP_JIT_CACHE.get(jkey)
+        # the miss IS the compile event (jit traces/compiles on the first
+        # call below), as at the aggregation programs' cache sites
+        compiled = fn is None
+        if fn is None:
+            fn = _build_fill_wave(layout, padded_rows)
+            _FBMP_JIT_CACHE[jkey] = fn
+            while len(_FBMP_JIT_CACHE) > _FBMP_JIT_CACHE_CAP:
+                _FBMP_JIT_CACHE.popitem(last=False)
         else:
-            col = segment.dims[dim]
-            bm = col.bitmap_index().union_of(np.flatnonzero(lut))
-            if perm is not None:
-                bm = _permuted_bitmap(segment, bm, perm, perm_key)
-            kind, payload = device_repr(bm, padded_rows)
-        kinds.append((kind, payload.shape[0]))
-        lkey = ("fbmpleaf", dim, _leaf_digest(lut), padded_rows, kind,
-                payload.shape[0], pdg)
-        arrays.append(segment.device_cached(
-            lkey, lambda p=payload: jax.device_put(p)))
-    return tuple(kinds), tuple(arrays)
+            _FBMP_JIT_CACHE.move_to_end(jkey)
+    pending, handovers, leaf_bytes = _wave_tally()
+    _WAVE_TALLY.n = (pending + len(pairs), handovers + 1,
+                     leaf_bytes + buf.nbytes)
+    with trace_span_when(compiled, "engine/compile", kind="filterFill"):
+        words = fn(jax.device_put(buf))
+    dispatch_mod.record("filterFill")    # successful dispatches only
+    return [words[i] for i in index]
 
 
 def _item_nodes(filter_node: Optional[FilterNode],
@@ -1088,12 +1205,18 @@ def words_span(**attrs):
     """The `engine/filter/words` span every execution path opens around
     its staging of filter words (and grouping around the megakernel
     conversion that decides WHICH words stage). `built` = pool entries
-    this thread built inside it rather than found resident."""
+    this thread built inside it rather than found resident; `pending` =
+    cold (segment, filter) pairs its waves filled, `handovers` = the
+    `jax.device_put` calls that took them to the device (one a wave with a
+    cold pair, whatever `pending` is), `leafBytes` = bytes those carried."""
     with trace_span("engine/filter/words", **attrs) as sp:
-        built0 = thread_builds()
+        built0, tally0 = thread_builds(), _wave_tally()
         yield
         if sp is not None:
             sp.attrs["built"] = thread_builds() - built0
+            for name, now, was in zip(("pending", "handovers", "leafBytes"),
+                                      _wave_tally(), tally0):
+                sp.attrs[name] = now - was
 
 
 def stage_device_bitmaps_multi(items: Sequence[Tuple],
@@ -1106,17 +1229,20 @@ def stage_device_bitmaps_multi(items: Sequence[Tuple],
     keyed (filter structural signature, aux digest, padded rows,
     permutation digest) per segment — warm probes skip leaf
     materialization AND the algebra (query/filter/deviceBitmapHits); ALL
-    of the wave's cold misses fill in a single batched dispatch."""
+    of the wave's cold misses cross to the device as one buffer and fill
+    in a single batched dispatch (`_fill_wave`)."""
     out: List[Dict[str, object]] = [{} for _ in items]
     pending = []          # (slot, segment, node, pool key)
     # identical (segment, key) pairs within one wave — N fused copies of
     # the same dashboard query — build ONCE and fan out (the duplicates
     # count as hits: they are served without leaf work or algebra)
     wave_dups: Dict[Tuple, List[Tuple[int, str]]] = {}
+    census: Dict[Tuple, int] = {}       # the wave's nodes by structure
     for i, item in enumerate(items):
         segment, filter_node = item[0], item[1]
         kernels = item[2] if len(item) > 2 else ()
         for node in _item_nodes(filter_node, kernels):
+            census[node.structure] = census.get(node.structure, 0) + 1
             key = bitmap_pool_key(node, padded_rows)
             wkey = (id(segment), key)
             if wkey in wave_dups:
@@ -1137,26 +1263,8 @@ def stage_device_bitmaps_multi(items: Sequence[Tuple],
     if not pending:
         return out
 
-    from druid_tpu.obs import dispatch as dispatch_mod
-    Rw = padded_rows // 32
-    kinds_per, leaves_per = [], []
-    for _, segment, node, _ in pending:
-        kinds, arrays = _leaf_arrays(segment, node, padded_rows)
-        kinds_per.append(kinds)
-        leaves_per.append(arrays)
-    structures = tuple(node.structure for _, _, node, _ in pending)
-    jkey = (structures, tuple(kinds_per), Rw)
-    with _FBMP_JIT_CACHE_LOCK:
-        fn = _FBMP_JIT_CACHE.get(jkey)
-        if fn is None:
-            fn = _build_fill_multi(structures, tuple(kinds_per), Rw)
-            _FBMP_JIT_CACHE[jkey] = fn
-            while len(_FBMP_JIT_CACHE) > _FBMP_JIT_CACHE_CAP:
-                _FBMP_JIT_CACHE.popitem(last=False)
-        else:
-            _FBMP_JIT_CACHE.move_to_end(jkey)
-    words_per = fn(tuple(leaves_per))
-    dispatch_mod.record("filterFill")    # successful dispatches only
+    words_per = _fill_wave([(segment, node) for _, segment, node, _
+                            in pending], padded_rows, census)
     for (i, segment, node, key), words in zip(pending, words_per):
         resident = segment.device_cached(key, lambda w=words: w)
         out[i][node.col] = resident
@@ -1170,23 +1278,10 @@ def _fill_single(segment: Segment, node: DeviceBitmapNode,
                  perm_key=None):
     """One (segment, filter) fill — the pool-miss build path when a probe
     said hit but the entry was evicted before device_cached re-read it,
-    and the permuted-layout (projection) staging path."""
-    from druid_tpu.obs import dispatch as dispatch_mod
-    kinds, arrays = _leaf_arrays(segment, node, padded_rows, perm=perm,
-                                 perm_key=perm_key)
-    key = (node.structure, kinds, padded_rows // 32)
-    with _FBMP_JIT_CACHE_LOCK:
-        fn = _FBMP_JIT_CACHE.get(key)
-        if fn is None:
-            fn = _build_fill_fn(node.structure, kinds, padded_rows // 32)
-            _FBMP_JIT_CACHE[key] = fn
-            while len(_FBMP_JIT_CACHE) > _FBMP_JIT_CACHE_CAP:
-                _FBMP_JIT_CACHE.popitem(last=False)
-        else:
-            _FBMP_JIT_CACHE.move_to_end(key)
-    words = fn(arrays)
-    dispatch_mod.record("filterFill")    # successful dispatches only
-    return words
+    and the permuted-layout (projection) staging path: the wave of one
+    pair."""
+    return _fill_wave([(segment, node)], padded_rows, perm=perm,
+                      perm_key=perm_key)[0]
 
 
 def stage_device_bitmaps(segment: Segment,

@@ -558,14 +558,15 @@ def _build_stack(mesh, segments: Sequence[Segment], columns: Tuple[str, ...],
         [cascade_mod.PrefixMaskColumn(np.asarray(s.n_rows, dtype=np.int32), R)
          for s in segments], K)
 
-    # resident filter-bitmap words: stage per segment through the pooled
-    # wave path (query/filter/* accounting included), then stack each
-    # `__fbmpN` slot; padding segments keep zero words (no row passes)
+    # resident filter-bitmap words: the segments stage as ONE pooled wave
+    # (query/filter/* accounting included; one hand-over and one fill for
+    # the stack's cold segments), then each `__fbmpN` slot is stacked;
+    # padding segments keep zero words (no row passes)
     bitmap_cols: Dict[str, np.ndarray] = {}
     with filters_mod.words_span(segments=K):
-        for i, (s, fn_s, ks) in enumerate(zip(segments, seg_filters,
-                                              seg_kernels)):
-            words = filters_mod.stage_device_bitmaps(s, fn_s, R, kernels=ks)
+        staged = filters_mod.stage_device_bitmaps_multi(
+            list(zip(segments, seg_filters, seg_kernels)), R)
+        for i, words in enumerate(staged):
             for col, w in words.items():
                 host = np.asarray(w)
                 slot = bitmap_cols.get(col)

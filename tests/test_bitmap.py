@@ -8,7 +8,8 @@ import pytest
 import druid_tpu.engine  # noqa: F401  (x64 on before jax numerics)
 from druid_tpu.data.bitmap import (Bitmap, BitmapIndex, SparseBitmap,
                                    bitmap_and, bitmap_or, bitmap_xor,
-                                   device_repr, sparse_if_small, to_words32)
+                                   leaf_rows, sparse_if_small,
+                                   sparse_leaf_width, to_words32)
 from druid_tpu.data.generator import ColumnSpec, DataGenerator
 from druid_tpu.engine.filters import (bitmap_of, estimate_selectivity,
                                       filter_cardinality)
@@ -171,18 +172,63 @@ def test_words32_round_trip_lsb_first():
     assert not bits[n:].any()       # padding rows stay clear
 
 
-def test_device_repr_density_split():
+def test_leaf_rows_density_split():
     n = 4096
-    kind, payload = device_repr(
-        SparseBitmap(np.array([1, 2, 3], np.int32), n), n)
-    assert kind == "sparse"
-    assert payload.dtype == np.int32
-    # pow2 rung, padded with the out-of-range sentinel
-    assert payload.shape[0] == 8 and (payload[3:] == n).all()
+    width = sparse_leaf_width(n)
+    assert width == n // 256 and sparse_leaf_width(1024) == 8   # the floor
+    kind, rows = leaf_rows([SparseBitmap(np.array([1, 2, 3], np.int32), n)],
+                           n)
+    assert kind == "sparse" and rows.dtype == np.int32
+    # THE sparse width, padded with the out-of-range sentinel
+    assert rows.shape == (1, width) and (rows[0, 3:] == n).all()
+    assert rows[0, :3].tolist() == [1, 2, 3]
     dense_bm = Bitmap.from_indices(np.arange(0, n, 3), n)
-    kind, payload = device_repr(dense_bm, n)
-    assert kind == "dense" and payload.dtype == np.uint32
-    assert np.array_equal(payload, to_words32(dense_bm, n))
+    kind, rows = leaf_rows([dense_bm], n)
+    assert kind == "dense" and rows.dtype == np.uint32
+    assert np.array_equal(rows[0], to_words32(dense_bm, n))
+
+
+@pytest.mark.parametrize("m", [0, 1, 15, 16, 17, 64])
+@pytest.mark.parametrize("dense_type", [False, True])
+def test_leaf_rows_has_one_sparse_shape(m, dense_type):
+    """A leaf's cardinality picks between TWO shapes — ids at the one
+    width, or words — never a shape of its own: each shape is a program.
+    Whether the host index hands out an id list or packed words (a
+    persisted segment's) does not change the row."""
+    n, rows = 4096, 4000
+    ids = np.arange(m, dtype=np.int32) * 7
+    bm = Bitmap.from_indices(ids, rows) if dense_type \
+        else SparseBitmap(ids, rows)
+    kind, got = leaf_rows([bm], n)
+    if m <= sparse_leaf_width(n):
+        assert kind == "sparse" and got.shape == (1, sparse_leaf_width(n))
+        assert np.array_equal(got[0, :m], ids) and (got[0, m:] == n).all()
+    else:
+        assert kind == "dense"
+        assert np.array_equal(got[0], to_words32(bm, n))
+
+
+def test_leaf_rows_converts_a_position_together():
+    """Bitmaps of both host types and of three row counts in one call:
+    every row is what the bitmap alone gives; one that does not fit the
+    width ships the whole position as words."""
+    n = 2048
+    rng = np.random.default_rng(5)
+    small = [np.sort(rng.permutation(r)[:k]).astype(np.int32)
+             for r, k in ((2000, 3), (2000, 8), (1999, 0), (1500, 5))]
+    bms = [Bitmap.from_indices(small[0], 2000), SparseBitmap(small[1], 2000),
+           Bitmap.from_indices(small[2], 1999),
+           Bitmap.from_indices(small[3], 1500)]
+    kind, got = leaf_rows(bms, n)
+    assert kind == "sparse" and got.shape == (4, 8)
+    for row, ids in zip(got, small):
+        assert np.array_equal(row[:ids.shape[0]], ids)
+        assert (row[ids.shape[0]:] == n).all()
+    big = Bitmap.from_bool(rng.random(2000) < 0.4)
+    kind, got = leaf_rows(bms + [big], n)
+    assert kind == "dense" and got.shape == (5, n // 32)
+    for row, bm in zip(got, bms + [big]):
+        assert np.array_equal(row, to_words32(bm, n))
 
 
 def test_union_of_stays_sparse_and_exact():

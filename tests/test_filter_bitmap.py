@@ -210,33 +210,42 @@ def test_opt_out_plans_column_path(fb_segments):
 
 
 def test_fill_program_sparse_scatter_and_xor(fb_segments):
-    """The word-wise algebra program directly: sparse id lists scatter into
-    words on device, dense words pass through, AND/OR/NOT/XOR combine
-    word-wise — against the numpy truth."""
+    """The word-wise algebra program directly: a block of sparse id lists
+    scatters into words on device, a block of dense words passes through,
+    AND/OR/NOT/XOR combine word-wise — against the numpy truth, in a
+    two-slot layout whose second slot is padding."""
     import jax
-    from druid_tpu.data.bitmap import Bitmap, device_repr
-    from druid_tpu.engine.filters import _build_fill_fn
+    from druid_tpu.data.bitmap import Bitmap, leaf_rows
+    from druid_tpu.engine.filters import _build_fill_wave
     padded = 2048
     rng = np.random.default_rng(4)
-    a = rng.random(padded) < 0.004                  # sparse
+    a = rng.random(padded) < 0.003                  # sparse
     b = rng.random(padded) < 0.5                    # dense
-    ka, pa = device_repr(SparseBitmap(
-        np.flatnonzero(a).astype(np.int32), padded), padded)
-    kb, pb = device_repr(Bitmap.from_bool(b), padded)
+    ka, pa = leaf_rows([SparseBitmap(
+        np.flatnonzero(a).astype(np.int32), padded)], padded)
+    kb, pb = leaf_rows([Bitmap.from_bool(b)], padded)
     assert (ka, kb) == ("sparse", "dense")
+    ids = np.full((2, pa.shape[1]), padded, dtype=np.int32)
+    ids[0] = pa[0]
+    dense = np.zeros((2, padded // 32), dtype=np.int32)
+    dense[0] = pb[0].view(np.int32)
     for op, truth in (("and", a & b), ("or", a | b), ("xor", a ^ b),
                       ("not", ~a)):
         structure = ("not", ("leaf", 0)) if op == "not" \
             else (op, (("leaf", 0), ("leaf", 1)))
-        kinds = ((ka, pa.shape[0]),) if op == "not" \
-            else ((ka, pa.shape[0]), (kb, pb.shape[0]))
-        leaves = (jax.device_put(pa),) if op == "not" \
-            else (jax.device_put(pa), jax.device_put(pb))
-        words = np.asarray(_build_fill_fn(structure, kinds, padded // 32)(
-            leaves))
+        blocks = (("sparse",),) if op == "not" \
+            else (("sparse",), ("dense",))
+        buf = ids.reshape(-1) if op == "not" \
+            else np.concatenate([ids.reshape(-1), dense.reshape(-1)])
+        words, padding = _build_fill_wave(
+            ((structure, 2, blocks),), padded)(jax.device_put(buf))
+        words = np.asarray(words)
         rows = np.arange(padded)
         bits = (words[rows // 32] >> (rows % 32).astype(np.uint32)) & 1
         assert np.array_equal(bits.astype(bool), truth), op
+        # a padding slot's leaves are empty: all-zero (all-one under NOT)
+        assert set(np.asarray(padding).tolist()) \
+            == ({0xFFFFFFFF} if op == "not" else {0}), op
 
 
 # ---------------------------------------------------------------------------

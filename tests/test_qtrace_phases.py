@@ -371,6 +371,60 @@ def test_late_span_unit():
 
 
 # ---------------------------------------------------------------------------
+# the filter-word wave: its counters, and its compile span (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def cluster4():
+    c = _Cluster(_segments(4, seed=35), own_store=True)
+    c.tag = "4seg"
+    yield c
+    c.stop()
+
+
+def test_filter_words_span_counts_the_wave_and_its_compile(cluster4):
+    """A batched request with a selector stages its four cold segments in
+    ONE wave: `engine/filter/words` says `pending` 4, `handovers` 1 and the
+    packed buffer's `leafBytes`; the fill program's first use opens
+    `engine/compile` (kind `filterFill`), a warm one does not; an
+    all-resident wave hands nothing over."""
+    from druid_tpu.engine import filters as filters_mod
+    cluster = cluster4
+    with filters_mod._FBMP_JIT_CACHE_LOCK:
+        filters_mod._FBMP_JIT_CACHE.clear()
+
+    def run(k, value):
+        qid = f"words-{k}-{cluster.tag}"
+        q = _groupby(qid)
+        q["filter"] = {"type": "selector", "dimension": "dimB",
+                       "value": value}
+        assert cluster.post(q)
+        spans = cluster.trace(qid)
+        words = _named(spans, "engine/filter/words")
+        assert words and all(
+            {"built", "pending", "handovers", "leafBytes"} <= set(w["attrs"])
+            for w in words)
+        fills = [s for s in _named(spans, "engine/compile")
+                 if s["attrs"]["kind"] == "filterFill"]
+        by_id, _ = _tree(spans)
+        assert all(by_id[f["parentId"]]["name"] == "engine/filter/words"
+                   for f in fills)
+        return {a: sum(w["attrs"][a] for w in words)
+                for a in ("built", "pending", "handovers", "leafBytes")}, \
+            len(fills)
+
+    cold, cold_compiles = run(1, "v00000003")
+    assert cold["pending"] == cold["built"] == 4 and cold["handovers"] == 1
+    assert cold["leafBytes"] > 0 and cold_compiles == 1
+    warm, warm_compiles = run(2, "v00000005")    # another literal: cold words
+    assert warm["pending"] == 4 and warm["handovers"] == 1
+    assert warm["leafBytes"] == cold["leafBytes"] and warm_compiles == 0
+    resident, compiles = run(3, "v00000005")
+    assert resident == {"built": 0, "pending": 0, "handovers": 0,
+                        "leafBytes": 0} and compiles == 0
+
+
+# ---------------------------------------------------------------------------
 # (d) compiles counted where they happen
 # ---------------------------------------------------------------------------
 
@@ -433,7 +487,12 @@ def test_program_name_set_is_closed():
         contracts.program_name("seg_agg", "projection")
     with pytest.raises(ValueError):
         contracts.named_program(lambda: None, "fn")
-    assert len(contracts.PROGRAM_NAMES) == 3 * 6 + 3
+    assert len(contracts.PROGRAM_NAMES) == 3 * 6 + 2
+    # ONE fill program since PR 35: the single-pair fill (the permuted
+    # layout, the evicted-after-probe race) is the wave's program at one
+    # slot, so the per-filter name left the closed set with its builder
+    assert {n for n in contracts.PROGRAM_NAMES if n.startswith("bitmap_")} \
+        == {"bitmap_fill_wave"}
     # no shape, segment id or digest in a name
     assert all(n.replace("_", "").isalpha() for n in contracts.PROGRAM_NAMES)
     assert all(n.replace("_", "").isalpha()
@@ -547,7 +606,7 @@ def test_lowered_module_names_are_documented(jit_recorder, monkeypatch):
         was = megakernel.set_enabled(False)
         try:
             q["context"]["queryId"] = "names-fill-4"
-            ex.run_json(q)                         # bitmap_fill
+            ex.run_json(q)                         # a wave of one pair
         finally:
             megakernel.set_enabled(was)
     finally:
@@ -575,7 +634,7 @@ def test_lowered_module_names_are_documented(jit_recorder, monkeypatch):
     families = {n.rsplit("_", 1)[0] for n in names}
     assert {"seg_agg", "batch_agg", "sharded_agg"} <= families, names
     assert {"seg_agg_pallas", "seg_agg_megakernel", "run_domain_agg",
-            "bitmap_fill", "bitmap_fill_wave"} <= names, names
+            "bitmap_fill_wave"} <= names, names
 
 
 def test_pallas_kernel_is_named_in_the_lowered_program():
