@@ -469,56 +469,70 @@ def _enqueue_batch(chunk: List[_Plan]) -> List[Tuple]:
     may mix plans from several queries (run_multi_with_batching): every
     per-query origin — interval bounds, bucket start — is derived from the
     plan's OWN intervals, so cross-query mates produce exactly the partials
-    their own serial run would."""
+    their own serial run would.
+
+    The host work around the one dispatch is under names (PR 36):
+    `engine/batch/blocks` (the K pool probes for the blocks and the
+    unified id columns), `engine/filter/words`, `engine/batch/assemble`
+    (origins, aux, the structure signature — `sigBytes` — and the program
+    cache's probe), `engine/batch/dispatch`: four spans a chunk, none a
+    segment."""
     ref = chunk[0].gplan
     strategy = ref.spec.strategy
     R = chunk[0].rung
     K = len(chunk)                  # a power of two by _pow2_chunks
 
-    blocks = [p.segment.device_block(list(ref.columns), row_align=R)
-              for p in chunk]
-    assert all(b.padded_rows == R for b in blocks), \
-        "ladder rung must equal the staged row count"
     # per-segment derived inputs ride the mapped arrays, not aux: query-time
     # dictionary id columns (unified id spaces — engines.unify_query_dims)
     # and resident filter-bitmap words (engine/filters.py device-bitmap
     # path; each plan stages ITS OWN words — query filter AND filtered
     # aggregators — so chunk-mates from different queries may carry
     # entirely different bitmap filters under one shared program structure)
+    with trace_span("engine/batch/blocks", segments=K, rows=R):
+        blocks = [p.segment.device_block(list(ref.columns), row_align=R)
+                  for p in chunk]
+        assert all(b.padded_rows == R for b in blocks), \
+            "ladder rung must equal the staged row count"
+        arrs_per_slot = []
+        for p, b in zip(chunk, blocks):
+            arrs = dict(b.arrays)
+            for d in p.gplan.spec.dims:
+                if d.host_ids is not None:
+                    arrs[d.column] = grouping._pad_device_cached(
+                        p.segment, d.ids_key, d.host_ids, R, 0)
+            arrs_per_slot.append(arrs)
     with filters_mod.words_span(segments=K):
         bmp_per_slot = filters_mod.stage_device_bitmaps_multi(
             [(p.segment, p.gplan.filter_node, p.gplan.kernels)
              for p in chunk], R)
-    arrs_per_slot = []
-    for p, b, bmp in zip(chunk, blocks, bmp_per_slot):
-        arrs = dict(b.arrays)
-        for d in p.gplan.spec.dims:
-            if d.host_ids is not None:
-                arrs[d.column] = grouping._pad_device_cached(
-                    p.segment, d.ids_key, d.host_ids, R, 0)
+    for arrs, bmp in zip(arrs_per_slot, bmp_per_slot):
         arrs.update(bmp)
-        arrs_per_slot.append(arrs)
 
-    time0s, iv_rel, bucket_off = stacked_origins(
-        [p.segment for p in chunk], [p.intervals for p in chunk],
-        [p.gplan.spec for p in chunk])
-    aux = assemble_stacked_aux(ref.spec, ref.f_aux, ref.k_aux, ref.vc_luts)
-    sig = "batched|" + grouping._structure_sig(
-        ref.spec, len(chunk[0].intervals), ref.filter_node, ref.kernels,
-        ref.vc_plans, chunk[0].packs, chunk[0].cascades) + f"|K={K}|R={R}"
-    with _JIT_CACHE_LOCK:
-        fn = _JIT_CACHE.get(sig)
-        # the miss IS the compile event (jit traces/compiles on the first
-        # call below) — timing stays at the existing dispatch boundary
-        compiled = fn is None
-        if fn is None:
-            fn = _build_batched_fn(ref.spec, ref.filter_node, ref.kernels,
-                                   ref.vc_plans, K)
-            _JIT_CACHE[sig] = fn
-            while len(_JIT_CACHE) > _JIT_CACHE_CAP:
-                _JIT_CACHE.popitem(last=False)
-        else:
-            _JIT_CACHE.move_to_end(sig)
+    with trace_span("engine/batch/assemble") as asm_span:
+        time0s, iv_rel, bucket_off = stacked_origins(
+            [p.segment for p in chunk], [p.intervals for p in chunk],
+            [p.gplan.spec for p in chunk])
+        aux = assemble_stacked_aux(ref.spec, ref.f_aux, ref.k_aux,
+                                   ref.vc_luts)
+        sig = "batched|" + grouping._structure_sig(
+            ref.spec, len(chunk[0].intervals), ref.filter_node, ref.kernels,
+            ref.vc_plans, chunk[0].packs, chunk[0].cascades) \
+            + f"|K={K}|R={R}"
+        if asm_span is not None:
+            asm_span.attrs["sigBytes"] = len(sig)
+        with _JIT_CACHE_LOCK:
+            fn = _JIT_CACHE.get(sig)
+            # the miss IS the compile event (jit traces/compiles on the
+            # first call below) — timing stays at the dispatch boundary
+            compiled = fn is None
+            if fn is None:
+                fn = _build_batched_fn(ref.spec, ref.filter_node,
+                                       ref.kernels, ref.vc_plans, K)
+                _JIT_CACHE[sig] = fn
+                while len(_JIT_CACHE) > _JIT_CACHE_CAP:
+                    _JIT_CACHE.popitem(last=False)
+            else:
+                _JIT_CACHE.move_to_end(sig)
 
     from druid_tpu.obs import dispatch as dispatch_mod
     real_rows = sum(p.segment.n_rows for p in chunk)

@@ -1077,24 +1077,34 @@ def fetch_partials(targets: Sequence[Tuple], outs: Sequence[Tuple],
     `post(kernel, state, segment)` is the one thing that differs between
     the builders: a per-segment result takes the kernel's host_post (the
     default), a mesh result that the collectives already merged its
-    host_from_device. This is where the host blocks for the device: ONE
-    `jax.device_get` brings the whole tree back (host arrays, as the
-    run-domain route leaves them, pass through), then `post` runs over
-    host arrays. So the span adds no sync: its duration is wait-for-device
-    plus D2H plus the host conversion. `bytes` is what comes back,
+    host_from_device. This is where the host blocks for the device, and
+    the span's three children say for what: `engine/fetch/wait` is the
+    sync — `jax.block_until_ready` over the whole tree, the wait the
+    `device_get` behind it would have made for the same programs (host
+    arrays, as the run-domain route leaves them, pass through both) —
+    `engine/fetch/d2h` the ONE `jax.device_get` (what is left of the
+    copies each enqueue started, and the numpy materialisation),
+    `engine/fetch/post` the partials' construction (`post` over host
+    arrays). Three spans a fetch, none a segment, and nothing of the fetch
+    outside them. `bytes` is what came back (counted under `d2h`),
     `programs` how many enqueued programs' outputs these are."""
     import jax
     with trace_span("engine/fetch", programs=programs, **attrs) as sp:
-        if sp is not None:
-            sp.attrs["bytes"] = entry_bytes(outs)
-        return [SegmentPartial(
-            segment=segment, spec=spec,
-            counts=np.asarray(counts, dtype=np.int64),
-            states={k.name: post(k, st, segment)
-                    for k, st in zip(kernels, states)},
-            kernels=kernels)
-            for (segment, spec, kernels), (counts, states)
-            in zip(targets, jax.device_get(outs))]
+        with trace_span("engine/fetch/wait"):
+            jax.block_until_ready(outs)
+        with trace_span("engine/fetch/d2h"):
+            host_outs = jax.device_get(outs)
+            if sp is not None:
+                sp.attrs["bytes"] = entry_bytes(host_outs)
+        with trace_span("engine/fetch/post"):
+            return [SegmentPartial(
+                segment=segment, spec=spec,
+                counts=np.asarray(counts, dtype=np.int64),
+                states={k.name: post(k, st, segment)
+                        for k, st in zip(kernels, states)},
+                kernels=kernels)
+                for (segment, spec, kernels), (counts, states)
+                in zip(targets, host_outs)]
 
 
 def run_grouped_aggregates(work: Sequence, check=None
@@ -1114,46 +1124,60 @@ def run_grouped_aggregates(work: Sequence, check=None
     program k while the host plans, stages and enqueues program k + 1, and
     the queued programs run on while another request's thread holds the
     interpreter lock. The outputs' copies to the host start at the enqueue
-    (`copy_to_host_async`, where an output has it) and the request's
+    (`copy_to_host_async`, where an output has it; the walk over a
+    program's output leaves that starts them is `engine/fetch/start`, one
+    span a program enqueued, with `leaves` and `bytes`) and the request's
     results come back under ONE `engine/fetch` whose `programs` says how
     many enqueued programs it collected. What is enqueued and not fetched
     is bounded by contracts.PENDING_FETCH_BYTES of outputs: at the bound
     the pending programs are fetched (one more `engine/fetch`) and the
-    enqueues go on. An enqueue that raises surfaces its error; the outputs
-    of the programs enqueued before it are dropped."""
-    import jax
+    enqueues go on. After each fetch the device outputs are RELEASED under
+    `engine/fetch/release` (segments): this function's `pending` is their
+    one owner, so that is where their destructors run — a leaf at a time.
+    An enqueue that raises surfaces its error; the outputs of the programs
+    enqueued before it are dropped."""
     results: List[Optional[SegmentPartial]] = []
     pending: List[Tuple] = []   # (slot in results, target, out), un-fetched
     programs = pending_bytes = 0
 
-    def fetch(programs):
-        if pending:
-            slots, targets, outs = zip(*pending)
-            for slot, partial in zip(slots, fetch_partials(
-                    targets, outs, programs=programs)):
-                results[slot] = partial
-            pending.clear()
-
-    for n, enqueue in enumerate(work):
-        if check is not None and n:
-            check()
+    def enqueue_next(enqueue) -> Optional[int]:
+        """One enqueue's entries into `results` / `pending`; returns the
+        bytes of the outputs it left pending, None where no program ran.
+        A function of its own: none of its locals keeps an output alive
+        past the fetch, so `pending` is the outputs' one owner."""
         entries = enqueue()
-        enqueued = []
+        first = len(pending)
         for entry in entries if isinstance(entries, list) else [entries]:
             if isinstance(entry, SegmentPartial):
                 results.append(entry)
             else:
-                enqueued.append((len(results),) + entry)
+                pending.append((len(results),) + entry)
                 results.append(None)
-        if enqueued:
-            pending += enqueued
+        if len(pending) == first:
+            return None
+        return _start_host_copies([out for _, _, out in pending[first:]])
+
+    def fetch(programs):
+        if pending:
+            slots, targets, outs = zip(*pending)
+            pending.clear()
+            for slot, partial in zip(slots, fetch_partials(
+                    targets, outs, programs=programs)):
+                results[slot] = partial
+            # the device outputs' last reference goes HERE, under a name:
+            # one destructor a leaf, each of which hands the interpreter
+            # lock over — with eight request threads ~1 ms of wall a leaf,
+            # 100 ms of a 24-segment request (PERF.md §5, PR 36)
+            with trace_span("engine/fetch/release", segments=len(slots)):
+                del outs
+
+    for n, enqueue in enumerate(work):
+        if check is not None and n:
+            check()
+        started = enqueue_next(enqueue)
+        if started is not None:
             programs += 1
-            for leaf in jax.tree_util.tree_leaves(
-                    [out for _, _, out in enqueued]):
-                pending_bytes += getattr(leaf, "nbytes", 0)
-                start_copy = getattr(leaf, "copy_to_host_async", None)
-                if start_copy is not None:
-                    start_copy()
+            pending_bytes += started
             if pending_bytes >= PENDING_FETCH_BYTES:
                 fetch(programs)
                 programs = pending_bytes = 0
@@ -1161,6 +1185,24 @@ def run_grouped_aggregates(work: Sequence, check=None
         check()
     fetch(programs)
     return results
+
+
+def _start_host_copies(outs: Sequence[Tuple]) -> int:
+    """Start the copies to the host of one enqueued program's outputs
+    (`copy_to_host_async`, where a leaf has it) under `engine/fetch/start`
+    (leaves, bytes); returns the bytes."""
+    import jax
+    with trace_span("engine/fetch/start") as sp:
+        leaves = jax.tree_util.tree_leaves(outs)
+        nbytes = 0
+        for leaf in leaves:
+            nbytes += getattr(leaf, "nbytes", 0)
+            start_copy = getattr(leaf, "copy_to_host_async", None)
+            if start_copy is not None:
+                start_copy()
+        if sp is not None:
+            sp.attrs.update(leaves=len(leaves), bytes=nbytes)
+    return nbytes
 
 
 def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],

@@ -1,7 +1,9 @@
 """Observability: distributed query tracing (qtrace), the metrics catalog,
-and the Prometheus exposition sink. See trace.py for the span model and
-propagation contract, dispatch.py for the dispatch and backend-compile
-counters, catalog.py for the declared metric names the druidlint
+and the Prometheus exposition sink. See trace.py for the span model (wall
+and thread-CPU time a span: `durationMs`, `attrs.cpuMs`), the propagation
+contract and the one cap policy of store and collector, dispatch.py for the
+dispatch and backend-compile counters (and qtrace's dropped-span count
+beside them), catalog.py for the declared metric names the druidlint
 `metric-name` rule enforces, prometheus.py for /metrics. PERF.md §3 maps
 every span and counter to the metric or operator use it is for."""
 from druid_tpu.obs.catalog import METRICS, render_table

@@ -27,7 +27,7 @@ from __future__ import annotations
 import threading
 from typing import Dict, Optional
 
-from druid_tpu.obs.trace import current_span
+from druid_tpu.obs.trace import current_span, dropped_spans
 from druid_tpu.utils.emitter import Monitor
 
 #: jax 0.9.0: fired around `compile_or_get_cached`, i.e. once per executable
@@ -42,7 +42,11 @@ class DispatchStats:
     beside them the executables this process built: `backend_compiles`
     (every build), `backend_compile_ms` (their wall time) and
     `cache_retrievals` (the builds JAX's persistent cache answered —
-    set-up pays for both kinds)."""
+    set-up pays for both kinds). The snapshot also carries
+    `trace_dropped_spans`, qtrace's process-wide count of spans dropped at
+    a cap (kept by obs/trace.py, which this module imports and never the
+    reverse): it rides here because this scoreboard is what a reader of
+    program counters already reads."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -77,7 +81,8 @@ class DispatchStats:
             out["backend_compiles"] = self._backend_compiles
             out["backend_compile_ms"] = self._backend_compile_ms
             out["cache_retrievals"] = self._cache_retrievals
-            return out
+        out["trace_dropped_spans"] = dropped_spans()
+        return out
 
 
 _STATS = DispatchStats()

@@ -7,10 +7,18 @@ Reference analogs:
 
 One trace per query: the trace id IS the queryId (a fresh id when the query
 carries none), spans are (name, service, start, duration, attrs) nodes in a
-parent tree. Spans cost two monotonic clock reads and a dict — no device
-syncs, no locks on the hot path (the store append takes the store lock once
-per finished span) — and the whole subsystem no-ops unless a ROOT span is
-open on the current thread, so untraced paths pay one thread-local read.
+parent tree. Spans cost two monotonic and two thread-CPU clock reads and a
+dict — no device syncs, no system call that releases the interpreter lock,
+no locks on the hot path (the store append takes the store lock once per
+finished span) — and the whole subsystem no-ops unless a ROOT span is open
+on the current thread, so untraced paths pay one thread-local read.
+
+Work against wait: every finished span carries `attrs["cpuMs"]`, the CPU
+time its thread burned between open and close (`time.thread_time()`: numpy
+and XLA-client C code included; 0 while the thread waits for the
+interpreter lock, the device or a socket). `durationMs - cpuMs` is the
+span's WAIT — the device's under `engine/fetch/wait`, the socket's under
+`broker/node/read`, the interpreter lock's everywhere else.
 
 Propagation:
   * thread-local span stack: `span(name)` children nest under the current
@@ -38,7 +46,10 @@ the qtrace phases per thread, on the profiler's clock, above `XLA Ops`
 a broker-only process imports it without.
 
 Storage: a bounded per-process ring buffer (TraceStore) serves
-GET /druid/v2/trace/<queryId> on any node type.
+GET /druid/v2/trace/<queryId> on any node type. A trace in the store and a
+root's response collector follow ONE cap policy (`_SpanBuffer`): past the
+cap leaves are dropped and counted, ancestors kept, so a capped trace still
+has its root; `dropped_spans()` is the process-wide count.
 """
 from __future__ import annotations
 
@@ -88,15 +99,38 @@ def _new_id() -> str:
 
 class Span:
     """One timed phase. Mutated only by the thread that opened it; finished
-    spans are immutable JSON dicts in the store/collector."""
+    spans are immutable JSON dicts in the store/collector.
+
+    A span is opened and closed by ONE thread (`attach` re-activates a span
+    on a worker so that the worker's spans nest under it, and never
+    finishes it), so `cpuMs` — that thread's CPU clock at close minus at
+    open — is well defined. `time.thread_time()` is
+    `clock_gettime(CLOCK_THREAD_CPUTIME_ID)`, made with the interpreter
+    lock HELD (CPython's `_PyTime_GetThreadTimeWithInfo` has no
+    `Py_BEGIN_ALLOW_THREADS`): unlike the `uuid4` a span that PR 24 took
+    out, reading it hands the lock to nobody (measured: a loop of it keeps
+    a Python loop's share of the lock beside four spinning threads, where
+    `os.urandom` keeps a third of it — PERF.md §6, PR 36). Its resolution
+    is the kernel's: nanoseconds where the clock is read in user space,
+    but the benchmark's chip host accounts CPU by 10 ms ticks and takes
+    ~6 us a read, so THERE a span's `cpuMs` is a multiple of 10 — a sample
+    that is unbiased over many spans (compare sums and means over
+    requests, not one short span) and may pass a short span's duration.
+
+    `is_root` marks the span `root_span` opened: at its finish it stamps
+    `droppedSpans` — what its collector (else its store, for this trace)
+    has dropped so far — when that is not 0, so a capped trace says so on
+    the one span the cap always keeps."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "service",
-                 "start_ms", "duration_ms", "attrs", "_t0", "_store",
-                 "_collector")
+                 "start_ms", "duration_ms", "attrs", "_t0", "_c0", "_store",
+                 "_collector", "is_root")
 
     def __init__(self, trace_id: str, span_id: str, parent_id: Optional[str],
                  name: str, service: str, attrs: Optional[dict] = None,
-                 store: Optional["TraceStore"] = None, collector=None):
+                 store: Optional["TraceStore"] = None,
+                 collector: Optional["_SpanBuffer"] = None,
+                 is_root: bool = False):
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
@@ -105,9 +139,11 @@ class Span:
         self.start_ms = time.time() * 1000.0
         self.duration_ms: Optional[float] = None
         self.attrs = dict(attrs or {})
-        self._t0 = time.monotonic()
         self._store = store
         self._collector = collector
+        self.is_root = is_root
+        self._t0 = time.monotonic()
+        self._c0 = time.thread_time()
 
     def to_json(self) -> dict:
         return {"traceId": self.trace_id, "spanId": self.span_id,
@@ -121,17 +157,26 @@ class Span:
     def finish(self) -> None:
         if self.duration_ms is not None:
             return                       # idempotent (double __exit__)
+        cpu_ms = (time.thread_time() - self._c0) * 1000.0
         self.duration_ms = (time.monotonic() - self._t0) * 1000.0
+        self.attrs["cpuMs"] = round(cpu_ms, 3)
+        if self.is_root:
+            dropped = self._collector.dropped \
+                if self._collector is not None \
+                else self._store.dropped(self.trace_id)
+            if dropped:
+                self.attrs["droppedSpans"] = dropped
         j = self.to_json()
         if self._store is not None:
             self._store.add_json(j)
         if self._collector is not None:
-            self._collector.append(j)
+            self._collector.add(j)
 
     def collected(self) -> List[dict]:
         """Finished spans of this span's request-local collector (the data
         node's response payload); empty unless opened with collect=True."""
-        return list(self._collector) if self._collector is not None else []
+        return self._collector.snapshot() \
+            if self._collector is not None else []
 
 
 # ---------------------------------------------------------------------------
@@ -304,13 +349,13 @@ def root_span(name: str, query=None, service: str = "", store=None,
         attrs.setdefault("queryType", getattr(query, "query_type", ""))
         attrs.setdefault("dataSource", getattr(query, "datasource", ""))
     st = store if store is not None else trace_store()
-    # the collector rides back in the response payload — bound it like the
+    # the collector rides back in the response payload — bound it AS the
     # store bounds a trace, or a span-heavy query bloats every reply
     return _SpanCtx(Span(
         trace_id=trace_id, span_id=_new_id(), parent_id=parent_id,
         name=name, service=service, attrs=attrs, store=st,
-        collector=collections.deque(maxlen=st.max_spans_per_trace)
-        if collect else None))
+        collector=_SpanBuffer(st.max_spans_per_trace) if collect else None,
+        is_root=True))
 
 
 def with_traceparent(query, s: Span):
@@ -326,41 +371,124 @@ def with_traceparent(query, s: Span):
 # TraceStore: bounded per-process ring buffer of assembled traces
 # ---------------------------------------------------------------------------
 
+#: what one trace of the ring adds to the store's TOTAL span budget: the
+#: per-trace cap it had before PR 36, so 256 traces still hold at most
+#: 256 x 2,048 spans together
+SPANS_PER_SLOT = 2048
+
+_DROPPED_LOCK = threading.Lock()
+_DROPPED = 0
+
+
+def dropped_spans() -> int:
+    """Spans this process dropped at a cap since it started — by a store
+    or by a collector, a drop each (a node's collector and the store it
+    shares a process with count the same leaf once each).
+    `obs.dispatch.DispatchStats.snapshot()` carries it as
+    `trace_dropped_spans`: above 0 over a window, every metric that sums
+    spans under-reads there."""
+    with _DROPPED_LOCK:
+        return _DROPPED
+
+
+class _SpanBuffer:
+    """The finished spans of ONE trace under the ONE cap policy — a trace
+    in the store, a `collect=True` root's response collector.
+
+    Up to `cap` spans are kept as they arrive. Past it a LEAF is dropped
+    and counted (`dropped`, and the process-wide `dropped_spans()`); a span
+    that has children is kept. Spans arrive as they close, children before
+    parents, so "has children" is: an earlier arrival, kept or dropped,
+    named its id as `parentId` (`parents`, which stops growing at `cap`
+    ids: with the `cap` spans kept before it, a buffer holds at most
+    2 x cap whatever a runaway producer sends). So the spans a capped
+    trace loses are leaves that closed late, and every ancestor up to the
+    root — `query`, `datanode/query`, `engine/partials` — is there with
+    its whole duration. Span ids dedupe among the spans kept."""
+
+    __slots__ = ("cap", "spans", "ids", "parents", "dropped", "_lock")
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self.spans: List[dict] = []
+        self.ids: set = set()
+        self.parents: set = set()
+        self.dropped = 0
+        self._lock = threading.Lock()
+
+    def add(self, j: dict) -> int:
+        """Returns how many spans the buffer grew by: 1 kept, 0 a duplicate
+        or a dropped leaf."""
+        global _DROPPED
+        sid = j.get("spanId")
+        with self._lock:
+            if sid in self.ids:
+                return 0
+            parent = j.get("parentId")
+            if parent is not None and len(self.parents) < self.cap:
+                self.parents.add(parent)
+            if len(self.spans) < self.cap or sid in self.parents:
+                self.ids.add(sid)
+                self.spans.append(j)
+                return 1
+            self.dropped += 1
+        with _DROPPED_LOCK:
+            _DROPPED += 1
+        return 0
+
+    def snapshot(self) -> List[dict]:
+        with self._lock:
+            return list(self.spans)
+
+
 class TraceStore:
-    """trace id -> span list, LRU-by-creation ring: the oldest trace is
-    evicted when `max_traces` is exceeded; spans beyond
-    `max_spans_per_trace` are counted, not kept (a runaway span producer
-    must not eat the process). Span ids dedupe — a data node sharing this
-    process with the broker (in-process tests) records spans locally AND
-    ships them back in the response; both paths land once."""
+    """trace id -> spans, LRU-by-creation ring: the oldest trace is evicted
+    when `max_traces` is exceeded, or when the traces together hold more
+    than `max_total_spans` = max_traces x min(max_spans_per_trace,
+    SPANS_PER_SLOT). A trace follows `_SpanBuffer`'s cap policy: past
+    `max_spans_per_trace` leaves are counted, not kept, ancestors kept (a
+    runaway span producer must not eat the process, and a capped trace
+    must not lose its root).
+
+    The numbers: 8,192 spans a trace, so that the largest request a
+    supported deployment makes is WHOLE — 480 per-segment enqueues x 9
+    spans + five fetch waves x 4 + the request's own ~30 is ~4,400 (the
+    request whose missing root refused PR 33 at the old 2,048); 256 traces
+    and 524,288 spans in all, what 256 x 2,048 was. A span's dict is
+    ~1.1 KB with its strings (measured, six attrs), so the default store's
+    worst case is ~0.6 GB, as before — reached only by 64 or more traces
+    at the per-trace cap; 256 `analyst-groupby` traces (~180 spans) are
+    ~50 MB.
+
+    Span ids dedupe — a data node sharing this process with the broker
+    (in-process tests) records spans locally AND ships them back in the
+    response; both paths land once."""
 
     def __init__(self, max_traces: int = 256,
-                 max_spans_per_trace: int = 2048):
+                 max_spans_per_trace: int = 8192):
         self.max_traces = max_traces
         self.max_spans_per_trace = max_spans_per_trace
+        self.max_total_spans = max_traces * min(max_spans_per_trace,
+                                                SPANS_PER_SLOT)
         self._lock = threading.Lock()
-        self._traces: "collections.OrderedDict[str, dict]" = \
+        self._traces: "collections.OrderedDict[str, _SpanBuffer]" = \
             collections.OrderedDict()
+        self._total = 0
 
     def add_json(self, j: dict) -> None:
         tid = j.get("traceId")
-        sid = j.get("spanId")
-        if not tid or not sid:
+        if not tid or not j.get("spanId"):
             return
         with self._lock:
             t = self._traces.get(tid)
             if t is None:
-                t = self._traces[tid] = {"spans": [], "ids": set(),
-                                         "dropped": 0}
-                while len(self._traces) > self.max_traces:
-                    self._traces.popitem(last=False)
-            if sid in t["ids"]:
-                return
-            if len(t["spans"]) >= self.max_spans_per_trace:
-                t["dropped"] += 1
-                return
-            t["ids"].add(sid)
-            t["spans"].append(j)
+                t = self._traces[tid] = _SpanBuffer(self.max_spans_per_trace)
+            self._total += t.add(j)
+            while len(self._traces) > self.max_traces or (
+                    self._total > self.max_total_spans
+                    and len(self._traces) > 1):
+                _, old = self._traces.popitem(last=False)
+                self._total -= len(old.spans)
 
     def ingest(self, spans) -> None:
         """Add remote span dicts (a data node's response payload)."""
@@ -370,19 +498,27 @@ class TraceStore:
 
     def get(self, trace_id: str) -> Optional[dict]:
         """The assembled trace, spans sorted by start time; None when the
-        id is unknown (or already evicted)."""
+        id is unknown (or already evicted). The list is copied under the
+        lock and sorted outside it: every `Span.finish` of every request
+        thread waits on that lock."""
         with self._lock:
             t = self._traces.get(trace_id)
             if t is None:
                 return None
-            spans = sorted(t["spans"],
-                           key=lambda s: (s.get("startMs") or 0.0))
-            return {"traceId": trace_id, "spanCount": len(spans),
-                    "droppedSpans": t["dropped"], "spans": spans}
+            spans, dropped = list(t.spans), t.dropped
+        spans.sort(key=lambda s: (s.get("startMs") or 0.0))
+        return {"traceId": trace_id, "spanCount": len(spans),
+                "droppedSpans": dropped, "spans": spans}
 
     def spans(self, trace_id: str) -> List[dict]:
         got = self.get(trace_id)
         return got["spans"] if got else []
+
+    def dropped(self, trace_id: str) -> int:
+        """Leaves this store has dropped of the trace so far."""
+        with self._lock:
+            t = self._traces.get(trace_id)
+            return t.dropped if t is not None else 0
 
     def trace_ids(self) -> List[str]:
         with self._lock:
@@ -420,14 +556,21 @@ def spans_under(spans, root_span_id: Optional[str]) -> List[dict]:
 
 
 def phase_breakdown(spans) -> Dict[str, float]:
-    """Total duration per span name — the slow-query log's payload.
-    Wire-ingested span dicts are unvalidated: nameless ones are skipped."""
+    """Total duration per span name and, under `<name>:cpu`, the CPU its
+    threads burned in it (`cpuMs` summed; absent where no span of the name
+    carries one, as a node of an older build sends them) — the slow-query
+    log's payload: which phase lasted, and which WORKED. Wire-ingested
+    span dicts are unvalidated: nameless ones are skipped."""
     out: Dict[str, float] = {}
     for s in spans:
         d = s.get("durationMs")
         name = s.get("name")
         if d is not None and name:
             out[name] = round(out.get(name, 0.0) + d, 3)
+            cpu = (s.get("attrs") or {}).get("cpuMs")
+            if isinstance(cpu, (int, float)):
+                key = f"{name}:cpu"
+                out[key] = round(out.get(key, 0.0) + cpu, 3)
     return out
 
 

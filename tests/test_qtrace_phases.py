@@ -156,7 +156,8 @@ def test_one_trace_holds_every_phase(cluster):
     (mrg,) = _named(spans, "datanode/merge")
     assert mrg["parentId"] == dn["spanId"] and mrg["service"] == "pnode"
     assert mrg["attrs"] == {"partialsIn": 3, "groups": len(rows),
-                            "mergePath": "dense"}
+                            "mergePath": "dense",
+                            "cpuMs": mrg["attrs"]["cpuMs"]}
     (bm,) = _named(spans, "broker/merge")
     assert bm["attrs"]["partials"] == 1
     assert bm["attrs"]["groups"] == len(rows)
@@ -260,7 +261,7 @@ def test_broker_merge_says_dense_over_shared_dictionaries(cluster):
     assert m["attrs"]["partials"] == 1
     (n,) = _named(cluster.trace(qid), "datanode/merge")
     assert n["attrs"] == {"partialsIn": 3, "groups": 70,
-                          "mergePath": "dense"}
+                          "mergePath": "dense", "cpuMs": n["attrs"]["cpuMs"]}
     # untraced: the same rows, and no span anywhere to hang an attribute on
     off = f"phases-merge-off-{cluster.tag}"
     assert cluster.post(_groupby(off, trace=False)) == rows
@@ -467,14 +468,18 @@ def test_retrace_under_a_cached_fn_is_counted_and_named():
 def test_dispatch_snapshot_keys():
     snap = dispatch_mod.stats().snapshot()
     assert {"total", "backend_compiles", "backend_compile_ms",
-            "cache_retrievals"} <= set(snap)
+            "cache_retrievals", "trace_dropped_spans"} <= set(snap)
     assert all(isinstance(v, (int, float)) for v in snap.values())
     stats = dispatch_mod.DispatchStats()
     stats.record_backend_compile(0.25)
     stats.record_cache_retrieval()
+    # qtrace's count of spans dropped at a cap is process-wide: it rides on
+    # every snapshot, a fresh scoreboard's too
     assert stats.snapshot() == {"total": 0, "backend_compiles": 1,
                                 "backend_compile_ms": 250.0,
-                                "cache_retrievals": 1}
+                                "cache_retrievals": 1,
+                                "trace_dropped_spans":
+                                    qtrace.dropped_spans()}
 
 
 # ---------------------------------------------------------------------------

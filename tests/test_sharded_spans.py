@@ -38,11 +38,15 @@ def _post(port: int, query: dict):
 
 
 def _sharded_spans(trace_id: str) -> dict:
-    """{span name minus the prefix: [attrs]} of one request's trace."""
+    """{span name minus the prefix: [attrs]} of one request's trace: the
+    attributes a site stamps, without the `cpuMs` every span carries (a
+    clock reading, checked to be there)."""
     out: dict = {}
     for s in trace.trace_store().spans(trace_id):
         if s["name"].startswith(SHARDED):
-            out.setdefault(s["name"][len(SHARDED):], []).append(s["attrs"])
+            attrs = dict(s["attrs"])
+            assert 0 <= attrs.pop("cpuMs") <= s["durationMs"] + 1.0
+            out.setdefault(s["name"][len(SHARDED):], []).append(attrs)
     return out
 
 
