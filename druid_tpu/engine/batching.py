@@ -17,7 +17,9 @@ operator inputs across queries/segments — here:
      program — HBM-resident blocks feed the program directly, no
      re-staging, and XLA schedules the K independent reduction subgraphs
      in a single dispatch;
-  4. hand back ONE SegmentPartial per segment from that dispatch.
+  4. hand back ONE array a leaf of (counts, states), K on its leading
+     axis, from that dispatch; the fetch cuts it into ONE SegmentPartial
+     per segment, row views of the one host copy.
 
 Stragglers — ineligible segments, and what the K ladder leaves of a bucket
 — fall back to the per-segment path, through the plan already made for
@@ -438,16 +440,22 @@ def _build_batched_fn(spec: GroupSpec, filter_node,
     constants ride aux. Unrolling (not vmap) is deliberate: XLA schedules K
     independent reduction subgraphs better than one batched-axis program —
     measured ~3.6x faster than the vmapped equivalent and ~1.5x faster than
-    K separate dispatches on the CPU backend — and per-segment partials
-    fall out without a stacked-axis slice."""
+    K separate dispatches on the CPU backend. The K bodies' outputs leave
+    as ONE `[K, ...]` array a leaf of (counts, states), in their own
+    dtypes: every body is the same traced function over the same spec, so
+    they share one tree and shapes, and a chunk hands the host one array
+    a leaf to copy and free, not K (PR 37) — the fetch takes slot i's
+    partial as row i of the host copy."""
     import jax
+    import jax.numpy as jnp
 
     body = functools.partial(traced_segment, spec, filter_node, kernels,
                              vc_plans)
 
     def fn(blocks, time0s, iv_rel, bucket_off, aux):
-        return tuple(body(blocks[i], time0s[i], iv_rel[i], bucket_off[i], aux)
-                     for i in range(K))
+        bodies = [body(blocks[i], time0s[i], iv_rel[i], bucket_off[i], aux)
+                  for i in range(K)]
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *bodies)
 
     return jax.jit(named_program(fn, program_name("batch_agg",
                                                   spec.strategy)))
@@ -465,8 +473,10 @@ def _enqueue_batch(chunk: List[_Plan]) -> List[Tuple]:
     """ENQUEUE one planned chunk (`_plan_chunks`: its strategy is in its
     plans' specs) as a single dispatch; returns an entry a segment for
     `fetch_partials` / `run_grouped_aggregates`: ((segment, spec,
-    kernels), (counts, states)), the outputs still on their way. The chunk
-    may mix plans from several queries (run_multi_with_batching): every
+    kernels), StackedSlot(out, i)), where `out` is the chunk's ONE
+    (counts, states) of `[K, ...]` leaves, still on its way, and `i` the
+    segment's slot in it. The chunk may mix plans from several queries
+    (run_multi_with_batching): every
     per-query origin — interval bounds, bucket start — is derived from the
     plan's OWN intervals, so cross-query mates produce exactly the partials
     their own serial run would.
@@ -549,8 +559,9 @@ def _enqueue_batch(chunk: List[_Plan]) -> List[Tuple]:
     dispatch_mod.record("batched")
 
     _STATS.record_batch(K, real_rows, K * R)
-    return [((p.segment, p.gplan.spec, p.gplan.kernels), out)
-            for p, out in zip(chunk, outs)]
+    return [((p.segment, p.gplan.spec, p.gplan.kernels),
+             grouping.StackedSlot(outs, i))
+            for i, p in enumerate(chunk)]
 
 
 # ---------------------------------------------------------------------------

@@ -45,7 +45,7 @@ import math
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -1068,43 +1068,74 @@ def _host_post(kernel: AggKernel, state, segment: Segment):
     return kernel.host_post(state, segment)
 
 
-def fetch_partials(targets: Sequence[Tuple], outs: Sequence[Tuple],
+class StackedSlot(NamedTuple):
+    """Slot `index` of a stacked program's ONE output tree `out`, (counts,
+    states) with every leaf `[K, ...]`, as a chunk's entries name it
+    (batching._enqueue_batch). The K entries of a chunk share `out` by
+    identity: it is started, waited for, fetched and released once, and
+    the fetch hands each entry row `index` of the one host copy."""
+    out: Tuple
+    index: int
+
+
+def _distinct_outputs(outs: Sequence) -> List:
+    """Each distinct output tree of `outs` once, by identity, in order: a
+    plain (counts, states) is its own tree, a chunk's K StackedSlots name
+    one."""
+    trees = (o.out if isinstance(o, StackedSlot) else o for o in outs)
+    return list({id(t): t for t in trees}.values())
+
+
+def fetch_partials(targets: Sequence[Tuple], outs: Sequence,
                    post=_host_post, programs: int = 1,
                    **attrs) -> List[SegmentPartial]:
     """Device results → host partials, under the ONE `engine/fetch`.
     `targets` are (segment, spec, kernels) and `outs` their (counts,
-    states) — of one program or of every program a request enqueued;
+    states), or a StackedSlot of a stacked program's one output — of one
+    program or of every program a request enqueued;
     `post(kernel, state, segment)` is the one thing that differs between
     the builders: a per-segment result takes the kernel's host_post (the
     default), a mesh result that the collectives already merged its
     host_from_device. This is where the host blocks for the device, and
     the span's three children say for what: `engine/fetch/wait` is the
-    sync — `jax.block_until_ready` over the whole tree, the wait the
-    `device_get` behind it would have made for the same programs (host
-    arrays, as the run-domain route leaves them, pass through both) —
-    `engine/fetch/d2h` the ONE `jax.device_get` (what is left of the
-    copies each enqueue started, and the numpy materialisation),
-    `engine/fetch/post` the partials' construction (`post` over host
-    arrays). Three spans a fetch, none a segment, and nothing of the fetch
-    outside them. `bytes` is what came back (counted under `d2h`),
-    `programs` how many enqueued programs' outputs these are."""
+    sync — `jax.block_until_ready` over each distinct output tree once,
+    the wait the `device_get` behind it would have made for the same
+    programs (host arrays, as the run-domain route leaves them, pass
+    through both) — `engine/fetch/d2h` the ONE `jax.device_get` (what is
+    left of the copies each enqueue started, and the numpy
+    materialisation), `engine/fetch/post` the partials' construction
+    (`post` over host arrays; a stacked slot's leaves are row views of its
+    tree's one host copy). Three spans a fetch, none a segment, and
+    nothing of the fetch outside them. `bytes` is what came back (counted
+    under `d2h`, a stacked tree once), `programs` how many enqueued
+    programs' outputs these are."""
     import jax
     with trace_span("engine/fetch", programs=programs, **attrs) as sp:
         with trace_span("engine/fetch/wait"):
-            jax.block_until_ready(outs)
+            trees = _distinct_outputs(outs)
+            jax.block_until_ready(trees)
         with trace_span("engine/fetch/d2h"):
-            host_outs = jax.device_get(outs)
+            host_trees = jax.device_get(trees)
             if sp is not None:
-                sp.attrs["bytes"] = entry_bytes(host_outs)
+                sp.attrs["bytes"] = entry_bytes(host_trees)
         with trace_span("engine/fetch/post"):
-            return [SegmentPartial(
-                segment=segment, spec=spec,
-                counts=np.asarray(counts, dtype=np.int64),
-                states={k.name: post(k, st, segment)
-                        for k, st in zip(kernels, states)},
-                kernels=kernels)
-                for (segment, spec, kernels), (counts, states)
-                in zip(targets, host_outs)]
+            host = {id(t): h for t, h in zip(trees, host_trees)}
+
+            def host_of(out):
+                if isinstance(out, StackedSlot):
+                    return jax.tree.map(lambda x: x[out.index],
+                                        host[id(out.out)])
+                return host[id(out)]
+            partials = []
+            for (segment, spec, kernels), out in zip(targets, outs):
+                counts, states = host_of(out)
+                partials.append(SegmentPartial(
+                    segment=segment, spec=spec,
+                    counts=np.asarray(counts, dtype=np.int64),
+                    states={k.name: post(k, st, segment)
+                            for k, st in zip(kernels, states)},
+                    kernels=kernels))
+            return partials
 
 
 def run_grouped_aggregates(work: Sequence, check=None
@@ -1115,25 +1146,29 @@ def run_grouped_aggregates(work: Sequence, check=None
     returns what it left pending, a segment an entry: one entry
     (`enqueue_grouped_aggregate`) or a list of them (a stacked chunk,
     batching._enqueue_batch). An entry is a finished host partial
-    (`constFalse`) or `((segment, spec, kernels), (counts, states))`, what
-    `fetch_partials` takes. Returns the partials in the order of the
-    entries. `check` (cancel/timeout probe) runs between enqueues and once
-    before the fetch.
+    (`constFalse`) or `((segment, spec, kernels), out)`, what
+    `fetch_partials` takes: `out` a segment's own `(counts, states)` or a
+    StackedSlot of its chunk's one output. Returns the partials in the
+    order of the entries. `check` (cancel/timeout probe) runs between
+    enqueues and once before the fetch.
 
     Nothing waits for the device between two enqueues: the device runs
     program k while the host plans, stages and enqueues program k + 1, and
     the queued programs run on while another request's thread holds the
     interpreter lock. The outputs' copies to the host start at the enqueue
     (`copy_to_host_async`, where an output has it; the walk over a
-    program's output leaves that starts them is `engine/fetch/start`, one
-    span a program enqueued, with `leaves` and `bytes`) and the request's
-    results come back under ONE `engine/fetch` whose `programs` says how
+    program's distinct output leaves that starts them is
+    `engine/fetch/start`, one span a program enqueued, with `leaves` and
+    `bytes`) and the request's results come back under ONE
+    `engine/fetch` whose `programs` says how
     many enqueued programs it collected. What is enqueued and not fetched
     is bounded by contracts.PENDING_FETCH_BYTES of outputs: at the bound
     the pending programs are fetched (one more `engine/fetch`) and the
     enqueues go on. After each fetch the device outputs are RELEASED under
-    `engine/fetch/release` (segments): this function's `pending` is their
-    one owner, so that is where their destructors run — a leaf at a time.
+    `engine/fetch/release` (segments, and traced `arrays`: the distinct
+    device arrays dropped): this function's `pending` is their one owner,
+    so that is where their destructors run — one a distinct leaf, which
+    for a stacked chunk is one a leaf of the chunk, not of each segment.
     An enqueue that raises surfaces its error; the outputs of the programs
     enqueued before it are dropped."""
     results: List[Optional[SegmentPartial]] = []
@@ -1165,10 +1200,14 @@ def run_grouped_aggregates(work: Sequence, check=None
                     targets, outs, programs=programs)):
                 results[slot] = partial
             # the device outputs' last reference goes HERE, under a name:
-            # one destructor a leaf, each of which hands the interpreter
-            # lock over — with eight request threads ~1 ms of wall a leaf,
-            # 100 ms of a 24-segment request (PERF.md §5, PR 36)
-            with trace_span("engine/fetch/release", segments=len(slots)):
+            # one destructor a distinct leaf, each of which hands the
+            # interpreter lock over — with eight request threads ~1 ms of
+            # wall a leaf (PERF.md §5, PR 36); a stacked chunk's outputs
+            # are one array a leaf, not K (PR 37)
+            with trace_span("engine/fetch/release",
+                            segments=len(slots)) as sp:
+                if sp is not None:
+                    sp.attrs["arrays"] = _device_arrays(outs)
                 del outs
 
     for n, enqueue in enumerate(work):
@@ -1187,13 +1226,14 @@ def run_grouped_aggregates(work: Sequence, check=None
     return results
 
 
-def _start_host_copies(outs: Sequence[Tuple]) -> int:
+def _start_host_copies(outs: Sequence) -> int:
     """Start the copies to the host of one enqueued program's outputs
     (`copy_to_host_async`, where a leaf has it) under `engine/fetch/start`
-    (leaves, bytes); returns the bytes."""
+    (leaves, bytes: of the distinct output trees, so a chunk's stacked
+    leaves count once); returns the bytes."""
     import jax
     with trace_span("engine/fetch/start") as sp:
-        leaves = jax.tree_util.tree_leaves(outs)
+        leaves = jax.tree_util.tree_leaves(_distinct_outputs(outs))
         nbytes = 0
         for leaf in leaves:
             nbytes += getattr(leaf, "nbytes", 0)
@@ -1203,6 +1243,13 @@ def _start_host_copies(outs: Sequence[Tuple]) -> int:
         if sp is not None:
             sp.attrs.update(leaves=len(leaves), bytes=nbytes)
     return nbytes
+
+
+def _device_arrays(outs: Sequence) -> int:
+    """How many distinct device arrays `outs` hold."""
+    import jax
+    return sum(isinstance(leaf, jax.Array) for leaf in
+               jax.tree_util.tree_leaves(_distinct_outputs(outs)))
 
 
 def run_grouped_aggregate(segment: Segment, intervals: Sequence[Interval],

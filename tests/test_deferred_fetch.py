@@ -4,11 +4,14 @@
 
 Counts and bits on the CPU, never a rate: the helper's partials equal
 one-at-a-time `run_grouped_aggregate` bit for bit and in order over every
-route a segment can take; a batched request (chunks AND stragglers) and a
-per-segment one carry one `engine/fetch` whose `programs` counts what was
-enqueued; the un-fetched-bytes bound drains in waves and answers the same;
-a `check` or an enqueue that raises surfaces its own error, fetches nothing
-and leaves no megakernel carry taken and not parked.
+route a segment can take, stacked chunks of K = 8, 4 and 2 among them, and
+so do chunks whose mates come from two queries; a batched request (chunks
+AND stragglers) and a per-segment one carry one `engine/fetch` whose
+`programs` counts what was enqueued; a chunk's ONE stacked output (ISSUE
+37) is started, waited for, fetched, counted and released once; the
+un-fetched-bytes bound drains in waves and answers the same; a `check` or
+an enqueue that raises surfaces its own error, fetches nothing and leaves
+no megakernel carry taken and not parked.
 """
 import functools
 import sys
@@ -44,6 +47,9 @@ SUMS = [{"type": "count", "name": "n"},
         {"type": "longSum", "name": "ls", "fieldName": "metLong"}]
 IN_FILTER = {"type": "in", "dimension": "dimB",
              "values": [f"v{i:08d}" for i in range(0, 40, 2)]}
+#: tests/test_batch_served.py's first 14 segments: one shape bucket, which
+#: the K ladder cuts into chunks of 8, 4 and 2
+STACKED = 14
 
 
 def _groupby(**over):
@@ -69,7 +75,24 @@ ROUTES = {
                    None, None),
     "runDomain": (dict(RUN_GROUPBY, context={"batchSegments": False}),
                   None, None),
+    # the stacked chunks of batching._enqueue_batch, a kind each
+    **{f"batched-{kind}": (_small_query(kind, f"df-k-{kind}", end=STACKED),
+                           None, "blocked")
+       for kind in ("topN", "timeseries", "groupBy")},
 }
+
+
+@functools.lru_cache(maxsize=None)
+def _small_segments():
+    return tuple(_segment(i, _raw(i, rows)) for i, rows in enumerate(ROWS))
+
+
+def _route_segments(route, segments):
+    if route == "runDomain":
+        return rollup_segments(2, rows=4096)
+    if route.startswith("batched-"):
+        return list(_small_segments()[:STACKED])
+    return segments
 
 
 @pytest.fixture(scope="module")
@@ -99,10 +122,10 @@ def _forced(strategy, enqueue):
 
 def _calls(route, segments, monkeypatch):
     """The arguments the served engine hands `enqueue_grouped_aggregate` for
-    `route`'s query, a call a segment."""
+    `route`'s query run a program a segment, a call a segment."""
     query, strategy, _ = ROUTES[route]
-    segs = rollup_segments(2, rows=4096) if route == "runDomain" \
-        else segments
+    query = dict(query, context=dict(query["context"], batchSegments=False))
+    segs = _route_segments(route, segments)
     calls = []
     real = grouping.enqueue_grouped_aggregate
 
@@ -135,11 +158,39 @@ def _one_at_a_time(work):
     return [grouping.run_grouped_aggregates([w])[0] for w in work]
 
 
-def _work(route, segments, monkeypatch):
+def _chunks(route, segments, monkeypatch):
+    """The chunk enqueues `batching.run_with_batching` hands the helper for
+    `route`'s query: (enqueue, the indices of its segments)."""
+    seen = []
+    real = batching.run_grouped_aggregates
+
+    def spy(work, check=None):
+        seen.extend(work)
+        return real(work, check)
+    with monkeypatch.context() as m:
+        m.setattr(batching, "run_grouped_aggregates", spy)
+        query = ROUTES[route][0]
+        assert QueryExecutor(_route_segments(route, segments)).run_json(
+            query) is not None
+    assert all(w.func is batching._enqueue_batch for w in seen)
+    return [(w, [p.index for p in w.args[0]]) for w in seen]
+
+
+def _pairs(route, segments, monkeypatch):
+    """`route`'s request as the helper takes it, beside the same segments
+    a program each: (enqueue, the per-segment enqueues it stands for)."""
     strategy = ROUTES[route][1]
-    return [_forced(strategy, functools.partial(
+    alone = [_forced(strategy, functools.partial(
         grouping.enqueue_grouped_aggregate, *args, **kw))
         for args, kw in _calls(route, segments, monkeypatch)]
+    if not route.startswith("batched-"):
+        return [(w, [w]) for w in alone]
+    return [(w, [alone[i] for i in index])
+            for w, index in _chunks(route, segments, monkeypatch)]
+
+
+def _work(route, segments, monkeypatch):
+    return [w for w, _ in _pairs(route, segments, monkeypatch)]
 
 
 # ---------------------------------------------------------------------------
@@ -149,19 +200,24 @@ def _work(route, segments, monkeypatch):
 @pytest.mark.parametrize("route", sorted(ROUTES))
 def test_each_route_equals_one_at_a_time(route, segments, kernel_build,
                                          monkeypatch):
-    work = _work(route, segments, monkeypatch)
+    pairs = _pairs(route, segments, monkeypatch)
+    work = [w for w, _ in pairs]
     hits = cascade.code_domain_stats().snapshot()["hits"]
     before = dispatch_mod.count()
     got = grouping.run_grouped_aggregates(work)
     dispatched = dispatch_mod.count() - before
-    want = _one_at_a_time(work)
-    assert len(got) == len(want) == 2
+    want = _one_at_a_time([a for _, alone in pairs for a in alone])
+    assert len(got) == len(want) \
+        == len(_route_segments(route, segments))
     for a, b in zip(got, want):
         _same(a, b)
+    if route.startswith("batched-"):
+        assert [len(alone) for _, alone in pairs] == [8, 4, 2]
     strategy = ROUTES[route][2]
     if strategy is not None:
         assert {p.spec.strategy for p in got} == {strategy}
-        assert dispatched == 2 and sum(int(p.counts.sum()) for p in got) > 0
+        assert dispatched == len(work) \
+            and sum(int(p.counts.sum()) for p in got) > 0
     elif route == "constFalse":
         assert dispatched == 0 and not any(p.counts.any() for p in got)
     else:
@@ -170,10 +226,12 @@ def test_each_route_equals_one_at_a_time(route, segments, kernel_build,
 
 def test_a_request_that_mixes_every_route_keeps_bits_and_order(
         segments, kernel_build, monkeypatch):
-    work = [w for route in sorted(ROUTES)
-            for w in _work(route, segments, monkeypatch)]
-    # interleave the routes, so neighbours in the queue differ
-    work = work[0::2] + work[1::2]
+    pairs = [pair for route in sorted(ROUTES)
+             for pair in _pairs(route, segments, monkeypatch)]
+    # interleave the routes, so neighbours in the queue differ: stacked
+    # chunks between per-segment programs
+    pairs = pairs[0::2] + pairs[1::2]
+    work = [w for w, _ in pairs]
     fetches = []
     real = grouping.fetch_partials
 
@@ -182,14 +240,38 @@ def test_a_request_that_mixes_every_route_keeps_bits_and_order(
         return real(targets, outs, **attrs)
     monkeypatch.setattr(grouping, "fetch_partials", spy)
     got = grouping.run_grouped_aggregates(work)
-    # ONE fetch: every route but constFalse left a program's outputs pending
-    assert fetches == [(12, {"programs": 12})]
-    want = _one_at_a_time(work)
-    assert len(got) == len(want) == 14
+    # ONE fetch: every route but constFalse left a program's outputs
+    # pending, a segment's or a chunk's of 8, 4 or 2
+    assert fetches == [(12 + 3 * STACKED, {"programs": 12 + 3 * 3})]
+    want = _one_at_a_time([a for _, alone in pairs for a in alone])
+    assert len(got) == len(want) == 14 + 3 * STACKED
     for a, b in zip(got, want):
         _same(a, b)
     assert {p.spec.strategy for p in got} >= {
         "mixed", "blocked", "mm", "pallas", "megakernel"}
+
+
+@pytest.mark.parametrize("kind", ["topN", "groupBy"])
+def test_cross_query_chunk_mates_equal_one_at_a_time(kind):
+    """`run_multi_with_batching` over two requests whose days overlap: the
+    mates of one stacked chunk come from both, and each request's partials
+    equal its own run a program a segment, bit for bit and in order."""
+    segs = list(_small_segments()[:STACKED])
+    asked = [_small_query(kind, f"df-x-{kind}-{n}", first=first, end=end)
+             for n, (first, end) in enumerate([(0, 8), (4, STACKED)])]
+    queries = [query_from_json(q) for q in asked]
+    mates = []
+    fused = engines.make_aggregate_partials_multi(
+        [(q, segs, None) for q in queries],
+        on_batch=lambda n_queries, *_: mates.append(n_queries))
+    assert 2 in mates
+    assert [len(got.partials) for got in fused] == [8, 10]
+    for q, got in zip(asked, fused):
+        alone = engines.make_aggregate_partials(query_from_json(dict(
+            q, context=dict(q["context"], batchSegments=False))), segs)
+        assert len(alone.partials) == len(got.partials)
+        for a, b in zip(got.partials, alone.partials):
+            _same(a, b)
 
 
 def test_run_grouped_aggregate_is_the_helper_over_one(segments, monkeypatch):
@@ -218,7 +300,7 @@ def _named(spans, name):
 def small_segments():
     """tests/test_batch_served.py's 29 segments: two shape buckets, a
     16 + 8 + 2 chunking of the first, a 2 of the second, one straggler."""
-    return [_segment(i, _raw(i, rows)) for i, rows in enumerate(ROWS)]
+    return list(_small_segments())
 
 
 @pytest.mark.parametrize("kind", ["topN", "timeseries", "groupBy"])
@@ -276,16 +358,41 @@ def test_the_byte_bound_drains_in_waves(monkeypatch, bound, waves):
     assert len(_named(spans, "engine/segment")) == 5
 
 
-def test_the_bound_counts_a_chunks_outputs_too(small_segments, monkeypatch):
+@pytest.mark.parametrize("share", [0, 0.3, 0.6, 1, 2])
+def test_the_bound_counts_a_chunks_outputs_too(small_segments, monkeypatch,
+                                               share):
+    """A chunk's pending bytes are its ONE stacked output's, K segments'
+    worth counted once: a bound of `share` of the request's bytes drains in
+    the waves those bytes say, and answers the same."""
     ex = QueryExecutor(small_segments)
-    want, _ = _traced(ex, _small_query("groupBy", "df-chunk-free"),
-                      "df-chunk-free")
-    monkeypatch.setattr(grouping, "PENDING_FETCH_BYTES", 1)
-    got, spans = _traced(ex, _small_query("groupBy", "df-chunk-bound"),
-                         "df-chunk-bound")
+    qid = f"df-chunk-free-{share}"
+    want, spans = _traced(ex, _small_query("groupBy", qid), qid)
+    starts = sorted(_named(spans, "engine/fetch/start"),
+                    key=lambda s: s["startMs"])
+    sizes = [s["attrs"]["bytes"] for s in starts]
+    fetch, = _named(spans, "engine/fetch")
+    assert sum(sizes) == fetch["attrs"]["bytes"]
+    # chunks of 16, 8 and 2 of one bucket: bytes in proportion to K
+    assert sizes[0] == 2 * sizes[1] == 8 * sizes[2] > 0
+    bound = max(1, int(share * sum(sizes)))
+    waves, drained, n, pending = [], [], 0, 0
+    for size in sizes:
+        n, pending = n + 1, pending + size
+        if pending >= bound:
+            waves.append(n)
+            drained.append(pending)
+            n = pending = 0
+    if n:
+        waves.append(n)
+        drained.append(pending)
+    monkeypatch.setattr(grouping, "PENDING_FETCH_BYTES", bound)
+    got, spans = _traced(ex, _small_query("groupBy", f"df-chunk-{share}"),
+                         f"df-chunk-{share}")
     assert got == want and want
-    fetches = _named(spans, "engine/fetch")
-    assert [s["attrs"]["programs"] for s in fetches] == [1] * 5
+    fetches = sorted(_named(spans, "engine/fetch"),
+                     key=lambda s: s["startMs"])
+    assert [s["attrs"]["programs"] for s in fetches] == waves
+    assert [s["attrs"]["bytes"] for s in fetches] == drained
 
 
 def test_the_bound_is_the_one_constant():
@@ -458,3 +565,61 @@ def test_copies_start_at_the_enqueue_and_host_outputs_pass(segments,
     assert seen == [[1] * len(wrapped)]
     _same(a, want)
     _same(b, want)
+
+
+def test_a_stacked_output_is_started_waited_fetched_and_counted_once(
+        segments, monkeypatch):
+    """The K StackedSlots of a chunk name ONE output tree: each of its
+    leaves has its copy started once at the enqueue, is handed to the wait
+    and to the `device_get` once and counted in `bytes` once, and slot i
+    comes back as row i."""
+    import jax
+    (args, kw), _ = _calls("blocked", segments, monkeypatch)
+    target, out = grouping.enqueue_grouped_aggregate(*args, **kw)
+    want = grouping.run_grouped_aggregate(*args, **kw)
+    counts, states = jax.device_get(out)
+    K = 4
+    rows = [counts] + jax.tree.leaves(states)
+    # slot i holds segment's own values + i: a row from another slot differs
+    wrapped = [_Output(np.stack([x + i for i in range(K)])) for x in rows]
+    stacked = (wrapped[0], jax.tree.unflatten(jax.tree.structure(states),
+                                              wrapped[1:]))
+    handed = {}
+    for name in ("block_until_ready", "device_get"):
+        def spy(x, real=getattr(jax, name), name=name):
+            handed.setdefault(name, []).extend(
+                id(leaf) for leaf in jax.tree_util.tree_leaves(x))
+            return real(x)
+        monkeypatch.setattr(jax, name, spy)
+    seen = []
+
+    def chunk():
+        return [(target, grouping.StackedSlot(stacked, i)) for i in range(K)]
+
+    def probe():
+        seen.append([o.copies for o in wrapped])
+        return want                          # a finished partial
+
+    store = qtrace.TraceStore()
+    with qtrace.root_span("stacked", store=store) as root:
+        got = grouping.run_grouped_aggregates([chunk, probe])
+    assert seen == [[1] * len(wrapped)]
+    ids = sorted(id(o) for o in wrapped)
+    assert sorted(handed["block_until_ready"]) == ids \
+        == sorted(handed["device_get"])
+    spans = store.spans(root.trace_id)
+    nbytes = sum(o.nbytes for o in wrapped)
+    start, = _named(spans, "engine/fetch/start")
+    assert start["attrs"]["leaves"] == len(wrapped)
+    assert start["attrs"]["bytes"] == nbytes
+    fetch, = _named(spans, "engine/fetch")
+    assert fetch["attrs"]["bytes"] == nbytes
+    assert fetch["attrs"]["programs"] == 1
+    release, = _named(spans, "engine/fetch/release")
+    assert release["attrs"]["segments"] == K
+    assert release["attrs"]["arrays"] == 0     # host stand-ins, not jax.Array
+    assert got[K] is want and len(got) == K + 1
+    for i, partial in enumerate(got[:K]):
+        assert np.array_equal(partial.counts, want.counts + i)
+        for x, y in zip(_leaves(partial)[1:], _leaves(want)[1:]):
+            assert np.array_equal(x, y + i)

@@ -534,6 +534,12 @@ def test_a_batched_request_carries_exactly_the_new_spans(small_segments,
     assert len(new) == 2 * 4 + 5 + 3 + 1
     release, = _named(spans, "engine/fetch/release")
     assert release["attrs"]["segments"] == 29
+    # a chunk's start counts the leaves of ONE body (its outputs are one
+    # [K, ...] array a leaf), as the straggler's does: counts and a state a
+    # kernel; the release drops that many a program, not a segment
+    leaves = 1 + len(_small_query(kind, qid)["aggregations"])
+    assert [s["attrs"]["leaves"] for s in starts] == [leaves] * 5
+    assert release["attrs"]["arrays"] == leaves * 5
 
 
 def test_a_per_segment_request_carries_exactly_the_new_spans(
@@ -547,6 +553,12 @@ def test_a_per_segment_request_carries_exactly_the_new_spans(
     assert len(_named(spans, "engine/fetch/start")) == 29 \
         == len(_named(spans, "engine/dispatch"))
     _check_fetches(spans, 1)
+    # a program a segment: its counts and a state a kernel, all dropped
+    leaves = 1 + len(query["aggregations"])
+    assert {s["attrs"]["leaves"]
+            for s in _named(spans, "engine/fetch/start")} == {leaves}
+    release, = _named(spans, "engine/fetch/release")
+    assert release["attrs"]["arrays"] == 29 * leaves
     # past the byte bound a wave: three parts each, the same rows
     monkeypatch.setattr(grouping, "PENDING_FETCH_BYTES", 1)
     query["context"]["queryId"] = "ww-alone-waves"
@@ -560,16 +572,17 @@ def test_the_release_span_is_where_the_outputs_die(small_segments,
                                                    monkeypatch):
     """`run_grouped_aggregates` owns the device outputs alone: every one is
     alive when `engine/fetch/release` opens and gone when it closes, so the
-    span times their destructors and nothing frees them later, unnamed."""
+    span times their destructors and nothing frees them later, unnamed.
+    Its `arrays` counts them: a chunk's stacked leaf once, not K times."""
     import contextlib
     import weakref
 
     import jax
-    refs, alive = [], {}
+    refs, alive = {}, {}
     real_fetch, real_span = grouping.fetch_partials, grouping.trace_span
 
     def spy(targets, outs, **kw):
-        refs.extend(weakref.ref(leaf)
+        refs.update((id(leaf), weakref.ref(leaf))
                     for leaf in jax.tree_util.tree_leaves(outs)
                     if isinstance(leaf, jax.Array))
         return real_fetch(targets, outs, **kw)
@@ -578,17 +591,19 @@ def test_the_release_span_is_where_the_outputs_die(small_segments,
     def span(name, **attrs):
         with real_span(name, **attrs) as sp:
             if name == "engine/fetch/release":
-                alive["open"] = sum(r() is not None for r in refs)
+                alive["open"] = sum(r() is not None for r in refs.values())
             yield sp
         if name == "engine/fetch/release":
-            alive["close"] = sum(r() is not None for r in refs)
+            alive["close"] = sum(r() is not None for r in refs.values())
+            alive["arrays"] = sp.attrs["arrays"]
     monkeypatch.setattr(grouping, "fetch_partials", spy)
     monkeypatch.setattr(grouping, "trace_span", span)
     ex = QueryExecutor(small_segments)
     rows, _ = _traced(ex, _small_query("topN", "ww-release"), "ww-release")
     assert rows
-    assert len(refs) >= 29 * 2          # counts and a state a segment
-    assert alive == {"open": len(refs), "close": 0}
+    # counts and two states a program: four chunks and the straggler
+    assert len(refs) == 5 * 3
+    assert alive == {"open": len(refs), "close": 0, "arrays": len(refs)}
 
 
 def test_a_parents_cpu_holds_its_childrens(small_segments):
